@@ -1,10 +1,9 @@
 """HealthWatch: trend rules over the gauge time-series rings.
 
-The r4/r5 TPU-round operational lesson is that this system degrades
-measurably before it fails — pull latency 349→747 ms and compile 66→106 s
-across nominally healthy runs, with "rising latency means stop launching
-now" the heuristic that kept the relay alive. This module productizes
-that heuristic for the serving plane: a small rule engine that ticks
+A serving node degrades measurably before it fails — latency and queue
+depth rise across nominally healthy windows — and "rising means act now"
+is the heuristic an operator applies by eye. This module productizes that
+heuristic for the serving plane: a small rule engine that ticks
 beside the :class:`~rio_tpu.load.LoadMonitor`, evaluates trends over the
 node's :class:`~rio_tpu.timeseries.GaugeSeries` window, and raises
 alarms while the node is still serving — not after it stops.

@@ -15,6 +15,7 @@ back to the pure-Python paths, which are wire-compatible.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -28,9 +29,11 @@ log = logging.getLogger("rio_tpu.native")
 _SRC_DIR = Path(__file__).resolve().parent.parent.parent / "native"
 _SRC = _SRC_DIR / "rio_native.cc"
 _SO = _SRC_DIR / "librio_native.so"
+_SO_DIGEST = _SRC_DIR / "librio_native.so.sha256"  # of the source it was built from
 
 _lock = threading.Lock()
 _lib: "NativeLib | None | bool" = False  # False = not attempted yet
+_status = "absent: not attempted"  # outcome of the one load attempt, see status()
 
 
 class RnEvent(ctypes.Structure):
@@ -52,31 +55,54 @@ _U32 = ctypes.c_uint32
 _U32P = ctypes.POINTER(ctypes.c_uint32)
 
 
-def _ensure_built() -> Path | None:
-    """Compile the shared library if missing or stale; None on failure."""
+def _ensure_built() -> tuple[Path | None, str]:
+    """Locate or compile the shared library: ``(path, status)``.
+
+    ``status`` is ``"loaded"`` (an existing library built from this exact
+    source, or an env-pinned one), ``"built"`` (compiled just now) or
+    ``"absent: <why>"``. Freshness is by CONTENT: the library is trusted
+    only when the sha256 recorded beside it equals that of
+    ``rio_native.cc`` — file mtimes do not survive a copied or archived
+    tree, and ``librio_native.so`` is not under version control.
+    """
     env_lib = os.environ.get("RIO_TPU_NATIVE_LIB")
     if env_lib:
-        return Path(env_lib) if Path(env_lib).exists() else None
+        if Path(env_lib).exists():
+            return Path(env_lib), "loaded"
+        return None, f"absent: RIO_TPU_NATIVE_LIB={env_lib} does not exist"
     if not _SRC.exists():
-        return _SO if _SO.exists() else None
-    if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
+        # Installed without the source tree: nothing to build or compare.
+        if _SO.exists():
+            return _SO, "loaded"
+        return None, f"absent: no source at {_SRC}"
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()
+    try:
+        if _SO.exists() and _SO_DIGEST.read_text().strip() == digest:
+            return _SO, "loaded"
+    except OSError:
+        pass  # no digest recorded: the library's origin is unknown, rebuild
+    # Build beside the target and rename: workers of one ShardedServer
+    # may all find the library stale at once.
+    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
             [
                 os.environ.get("CXX", "g++"),
                 "-O2", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-                "-shared", "-o", str(_SO), str(_SRC),
+                "-shared", "-o", str(tmp), str(_SRC),
             ],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, _SO)
+        _SO_DIGEST.write_text(digest + "\n")
     except (OSError, subprocess.SubprocessError) as e:
-        detail = getattr(e, "stderr", b"")
+        detail = getattr(e, "stderr", b"") or b""
         log.warning("native build failed: %s %s", e, detail)
-        return None
-    return _SO
+        tmp.unlink(missing_ok=True)
+        return None, f"absent: build failed ({type(e).__name__}: {e})"
+    return _SO, "built"
 
 
 class NativeLib:
@@ -465,17 +491,20 @@ def engine_profitable() -> bool:
 
 
 def get() -> NativeLib | None:
-    """Load (building on demand) the native library; None when unavailable."""
-    global _lib
+    """Load (building on demand) the native library; None when unavailable.
+
+    A failed build or load still degrades to the wire-compatible Python
+    codec, but not invisibly: :func:`status` says what happened."""
+    global _lib, _status
     if _lib is not False:
         return _lib  # type: ignore[return-value]
     with _lock:
         if _lib is not False:
             return _lib  # type: ignore[return-value]
         if os.environ.get("RIO_TPU_NATIVE", "1") == "0":
-            _lib = None
+            _lib, _status = None, "absent: RIO_TPU_NATIVE=0"
             return None
-        path = _ensure_built()
+        path, _status = _ensure_built()
         if path is None:
             _lib = None
             return None
@@ -483,5 +512,14 @@ def get() -> NativeLib | None:
             _lib = NativeLib(ctypes.CDLL(str(path)))
         except OSError as e:
             log.warning("failed to load %s: %s", path, e)
-            _lib = None
+            _lib, _status = None, f"absent: load failed ({e})"
     return _lib
+
+
+def status() -> str:
+    """Outcome of :func:`get`: ``"built"`` (compiled from the tree's
+    ``rio_native.cc`` by this process), ``"loaded"`` (an existing library
+    whose recorded source hash matches, or an env-pinned one) or
+    ``"absent: <why>"``."""
+    get()
+    return _status

@@ -15,9 +15,9 @@ flash-attention accumulation pattern). The final grid step materializes the
 new ``g``. Net effect: half the HBM traffic of the unfused solve, which is
 a ~2x iteration speedup where it matters.
 
-Falls back to interpreter mode off-TPU so the CPU test mesh exercises the
-same code path. Semantics match :func:`rio_tpu.ops.sinkhorn.sinkhorn`
-(same math, same -inf conventions for padding rows / dead nodes).
+Tests run it with ``interpret=True`` on the CPU mesh. Semantics match
+:func:`rio_tpu.ops.sinkhorn.sinkhorn` (same math, same -inf conventions
+for padding rows / dead nodes).
 """
 
 from __future__ import annotations
@@ -143,10 +143,6 @@ def fused_iteration(
     return f.reshape(n), g_new.reshape(m)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def pallas_sinkhorn(
     cost: jax.Array,
     row_mass: jax.Array,
@@ -155,7 +151,7 @@ def pallas_sinkhorn(
     eps: float = 0.05,
     n_iters: int = 50,
     block_rows: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> SinkhornResult:
     """Drop-in for :func:`rio_tpu.ops.sinkhorn.sinkhorn` using the fused
     Pallas kernel (single HBM sweep of the cost matrix per iteration).
@@ -163,10 +159,9 @@ def pallas_sinkhorn(
     Pads the object axis to a ``block_rows`` multiple with zero-mass rows and
     the node axis to a 128 multiple with zero-capacity columns; padding never
     influences live potentials (-inf marginals contribute nothing to either
-    log-sum-exp) and is sliced off the result.
+    log-sum-exp) and is sliced off the result. ``interpret=True`` runs the
+    Pallas interpreter (tests); it is never chosen for the caller.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     n, m = cost.shape
     cost = cost.astype(jnp.float32)
     a, b = normalize_marginals(row_mass, col_capacity)

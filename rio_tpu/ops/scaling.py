@@ -278,7 +278,7 @@ def pallas_scaling_core(
     n_iters: int = 50,
     kernel_dtype=jnp.bfloat16,
     block_rows: int = 1024,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Fused-kernel drop-in for :func:`scaling_core`: ``(u, v, K, shift)``.
 
@@ -287,9 +287,11 @@ def pallas_scaling_core(
     iteration is one HBM sweep of ``K`` instead of two. Promoted after the
     r5 slope head-to-head on TPU v5e measured 1.297 ms/iter fused vs 1.548
     XLA at 262144x1024 (PALLAS_TPU.json, ``pallas_vs_xla: 1.19``).
+
+    ``interpret=True`` runs the kernel in the Pallas interpreter (tests,
+    CPU rehearsal). It is never chosen for the caller: without it, a
+    backend that cannot compile the kernel raises.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, m = cost.shape
     cost = cost.astype(jnp.float32)
     a, b = normalize_marginals(row_mass, col_capacity)
@@ -381,7 +383,7 @@ def pallas_scaling_sinkhorn(
     n_iters: int = 50,
     kernel_dtype=jnp.bfloat16,
     block_rows: int = 1024,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> SinkhornResult:
     """Fused-kernel scaling Sinkhorn: one HBM sweep of K per iteration.
 

@@ -21,17 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map to the top-level namespace
-    from jax import shard_map
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x installs
-    from jax.experimental.shard_map import shard_map
-
-if hasattr(lax, "pcast"):
-    _pcast = lax.pcast
-else:  # pragma: no cover - jax < 0.9: shard_map does not track manual-axis
-    # variance through scan, so the explicit marking is simply unnecessary.
-    def _pcast(x, axes, to="varying"):  # noqa: ARG001 - match lax.pcast
-        return x
+from jax import shard_map
 
 __all__ = [
     "HierarchicalResult",
@@ -144,8 +134,8 @@ def sharded_sinkhorn(
 
         # Mark the carry as varying over its mesh axis up front (JAX >= 0.9
         # shard_map tracks manual-axis variance through scan).
-        f0 = _pcast(jnp.zeros(c.shape[0], jnp.float32), ("obj",), to="varying")
-        g0 = _pcast(jnp.zeros(c.shape[1], jnp.float32), ("node",), to="varying")
+        f0 = lax.pcast(jnp.zeros(c.shape[0], jnp.float32), ("obj",), to="varying")
+        g0 = lax.pcast(jnp.zeros(c.shape[1], jnp.float32), ("node",), to="varying")
         (f, g), _ = lax.scan(body, (f0, g0), None, length=n_iters)
         return f, g
 
@@ -205,8 +195,8 @@ def sharded_scaling_sinkhorn(
             v = jnp.where(b > 0, b / jnp.maximum(KTu, 1e-30), 0.0)
             return (u, v), None
 
-        u0 = _pcast(jnp.zeros(c.shape[0], jnp.float32), ("obj",), to="varying")
-        v0 = _pcast(jnp.ones(c.shape[1], jnp.float32), ("node",), to="varying")
+        u0 = lax.pcast(jnp.zeros(c.shape[0], jnp.float32), ("obj",), to="varying")
+        v0 = lax.pcast(jnp.ones(c.shape[1], jnp.float32), ("node",), to="varying")
         (u, v), _ = lax.scan(body, (u0, v0), None, length=n_iters)
         f = jnp.where(
             u > 0, eps * jnp.log(jnp.maximum(u, 1e-30)) + shift[:, 0], -jnp.inf

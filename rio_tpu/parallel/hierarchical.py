@@ -41,6 +41,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.scaling import scaling_sinkhorn
@@ -238,20 +239,6 @@ def _hierarchical_assign_impl(
 
 hierarchical_assign = jax.jit(_hierarchical_assign_impl, static_argnames=_HIER_STATIC)
 
-# Donation twin for the host-looped timed paths: each chunk's feature slab
-# is freshly sliced/built there, so its device buffer can back the result
-# instead of doubling residency for the dispatch. Only engaged off-CPU —
-# the CPU runtime ignores donation with a per-call warning, and the timed
-# twins' bit-parity pins run on CPU against the non-donated executable.
-_hierarchical_assign_donated = jax.jit(
-    _hierarchical_assign_impl, static_argnames=_HIER_STATIC, donate_argnums=(0,)
-)
-
-
-def _donation_profitable(donate: bool) -> bool:
-    return donate and jax.default_backend() != "cpu"
-
-
 @functools.partial(jax.jit, static_argnames=("n_groups", "n_chunks", "bucket", "eps", "coarse_iters", "fine_iters"))
 def chunked_hierarchical_assign(
     obj_feat: jax.Array,
@@ -267,9 +254,9 @@ def chunked_hierarchical_assign(
     """Single-chip scale-out: the sharded solve's design, run temporally.
 
     The TPU backend's compile time for :func:`hierarchical_assign` is
-    superlinear in the object count (measured on v5e: 50 s at 655k,
-    599 s at 2.6M — while CPU XLA stays flat at ~7 s), so giant flat
-    shapes price a full re-solve out of any watchdog budget. This wrapper
+    superlinear in the object count (r5 capture on v5e, not re-measured:
+    50 s at 655k, 599 s at 2.6M — while CPU XLA stays flat at ~7 s), so
+    giant flat shapes price a full re-solve out of reach. This wrapper
     reuses the exact per-shard independence `sharded_hierarchical_assign`
     rides (each shard solves its slice against ``1/n_shards`` of every
     node's capacity; marginal normalization spreads each slice across the
@@ -311,7 +298,6 @@ def chunked_hierarchical_assign_timed(
     n_groups: int,
     n_chunks: int,
     coarse_g_init: jax.Array | None = None,
-    donate: bool = True,
     **kw,
 ) -> tuple[HierarchicalResult, list[float]]:
     """:func:`chunked_hierarchical_assign` with per-chunk host timings.
@@ -325,13 +311,7 @@ def chunked_hierarchical_assign_timed(
     exactly (``tests/test_hierarchical.py`` pins the parity); the first
     chunk's timing includes the one-time compile, which is exactly the
     compile-vs-execute signal SolveStats wants. The sync per chunk is a
-    single ``block_until_ready`` on a chained jit result — the pattern
-    CLAUDE.md's r4 wedge notes mark safe (sub-ms, unlike eager pulls).
-
-    ``donate`` releases each chunk's feature slab into its own solve
-    (``donate_argnums`` on the chunk body) — the slab is a fresh slice per
-    iteration, so off-CPU this halves the chunk's device residency; on CPU
-    it is a no-op (see ``_hierarchical_assign_donated``).
+    single ``block_until_ready`` on the jit result, never a value pull.
 
     Returns ``(result, chunk_ms)`` with one wall-ms entry per chunk.
     """
@@ -339,11 +319,6 @@ def chunked_hierarchical_assign_timed(
 
     n = obj_feat.shape[0]
     assert n % n_chunks == 0, (n, n_chunks)
-    solve = (
-        _hierarchical_assign_donated
-        if _donation_profitable(donate)
-        else hierarchical_assign
-    )
     of = jnp.asarray(obj_feat).reshape(n_chunks, n // n_chunks, obj_feat.shape[1])
     # Sync staged inputs BEFORE the timed loop: dispatch is async, so a
     # still-pending producer chain (e.g. feature generation, O(N) in total
@@ -357,7 +332,7 @@ def chunked_hierarchical_assign_timed(
     res = None
     for c in range(n_chunks):
         t0 = _time.perf_counter()
-        res = solve(
+        res = hierarchical_assign(
             of[c], node_feat, node_capacity / n_chunks, alive,
             n_groups=n_groups, coarse_g_init=coarse_g_init, **kw,
         )
@@ -376,22 +351,6 @@ def chunked_hierarchical_assign_timed(
         ),
         chunk_ms,
     )
-
-
-def _shard_map_check_kw():
-    """Resolve shard_map plus its replication-check kwarg, disabled.
-
-    The kwarg was renamed across jax versions (check_rep -> check_vma);
-    return ``(shard_map, {that_kwarg: False})`` for whichever this install
-    understands.
-    """
-    import inspect
-
-    from . import shard_map  # version-gated import (top-level vs experimental)
-
-    params = inspect.signature(shard_map).parameters
-    check_kw = next((k for k in ("check_vma", "check_rep") if k in params), None)
-    return shard_map, ({check_kw: False} if check_kw else {})
 
 
 def _mesh_inputs(
@@ -450,7 +409,6 @@ def sharded_hierarchical_assign(
     and the coarse potentials/residual are pmean'd to a replicated warm
     seed (``coarse_g_init`` threads the previous one back in).
     """
-    shard_map, check = _shard_map_check_kw()
     axes = mesh.axis_names
     obj_feat, node_feat, node_capacity, alive, coarse_g_init = _mesh_inputs(
         mesh, obj_feat, node_feat, node_capacity, alive, coarse_g_init, n_groups
@@ -473,7 +431,7 @@ def sharded_hierarchical_assign(
         mesh=mesh,
         in_specs=(P(axes, None), P(), P(), P(), P()),
         out_specs=_hier_out_specs(axes),
-        **check,
+        check_vma=False,
     )
     return fn(obj_feat, node_feat, node_capacity, alive, coarse_g_init)
 
@@ -494,7 +452,7 @@ def mesh_chunked_hierarchical_assign(
 
     :func:`sharded_hierarchical_assign` divides N by the device count but
     still compiles one flat body per shard — at TPU-backend compile costs
-    superlinear in the row count (CLAUDE.md r5) that hits the same wall
+    superlinear in the row count (CLAUDE.md) that hits the same wall
     one octave later. This composition runs the ``lax.map``-chunked body
     *inside* each shard: every (device, chunk) cell solves
     ``N / (n_shards * n_chunks)`` rows against ``1 / (n_shards *
@@ -505,7 +463,6 @@ def mesh_chunked_hierarchical_assign(
     matching :func:`chunked_hierarchical_assign`) into a replicated warm
     seed.
     """
-    shard_map, check = _shard_map_check_kw()
     axes = mesh.axis_names
     n_shards = int(mesh.devices.size)
     n = obj_feat.shape[0]
@@ -541,7 +498,7 @@ def mesh_chunked_hierarchical_assign(
         mesh=mesh,
         in_specs=(P(axes, None), P(), P(), P(), P()),
         out_specs=_hier_out_specs(axes),
-        **check,
+        check_vma=False,
     )
     return fn(obj_feat, node_feat, node_capacity, alive, coarse_g_init)
 
@@ -555,7 +512,6 @@ def _mesh_cell_solver(mesh: Mesh, scale: int, n_groups: int, kw_key: tuple):
     layout) is what pins compile cost to the first chunk of the first
     solve at a given cell shape, across chunks AND across rebalances.
     """
-    shard_map, check = _shard_map_check_kw()
     axes = mesh.axis_names
     kw = dict(kw_key)
 
@@ -577,7 +533,7 @@ def _mesh_cell_solver(mesh: Mesh, scale: int, n_groups: int, kw_key: tuple):
         mesh=mesh,
         in_specs=(P(axes, None), P(), P(), P(), P()),
         out_specs=_hier_out_specs(axes),
-        **check,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -619,8 +575,11 @@ def mesh_chunked_hierarchical_assign_timed(
     )
     # (shard, chunk, cell, d) view: slab c = every shard's chunk-c cell,
     # laid out shard-major so P(axes) sharding hands each device its own
-    # cell — the exact row->cell mapping of the lax.map form.
-    of = jnp.asarray(obj_feat).reshape(n_shards, n_chunks, cell, d)
+    # cell — the exact row->cell mapping of the lax.map form. A host
+    # (numpy) block stays on the host here: each slab is then cut on the
+    # host and put straight onto its devices, where jnp.asarray would
+    # commit the whole block to device 0 first and reshard from there.
+    of = obj_feat.reshape(n_shards, n_chunks, cell, d)
     # Sync staged inputs BEFORE the timed loop (same reason as the chunked
     # twin): an async pending producer chain behind obj_feat is O(N) in
     # TOTAL rows and would drain inside chunk 0's timer, inflating the

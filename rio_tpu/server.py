@@ -760,7 +760,7 @@ class Server:
            marks would lose the re-seats from durable storage.
         5. Exit the serve loop — guaranteed by the ``finally`` even if a
            provider surprises us with an exception (a failed drain must
-           degrade to an exit, never to a wedged server).
+           degrade to an exit, never to a hung server).
         """
         placement = self.object_placement
         try:

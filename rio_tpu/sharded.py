@@ -302,15 +302,17 @@ class ShardedServer:
     def _child_env(self) -> dict:
         if self.env_override is not None:
             return dict(self.env_override)
-        # Clean environment, the multihost-test discipline: an ambient
-        # sitecustomize (e.g. an accelerator plugin registration) must not
-        # leak into data-plane workers; they pin CPU unless told otherwise.
+        # Clean environment, and always the CPU platform: a chip belongs
+        # to one process, the one that owns the directory. Data-plane
+        # workers seat through SqliteObjectPlacement and never import jax;
+        # a parent launched with JAX_PLATFORMS=tpu must not hand N workers
+        # the chip it holds.
         repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         return {
             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
             "HOME": os.environ.get("HOME", "/tmp"),
             "PYTHONPATH": repo_root,
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+            "JAX_PLATFORMS": "cpu",
         }
 
     async def wait_ready(self, timeout: float = 60.0) -> None:

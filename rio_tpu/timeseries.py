@@ -96,7 +96,9 @@ class GaugeSeries:
         self._head = 0  # next slot to write
         self._seq = 0  # last seq handed out (== total sampled)
         self.dropped = 0  # samples overwritten before anyone read them
-        self._last_mono = 0.0  # rate-limits ticks faster than `interval`
+        # Never sampled: time.monotonic() counts from boot, so a 0.0 here
+        # would swallow the first tick on a host up for less than `interval`.
+        self._last_mono = float("-inf")
 
     # -- write side (one dict copy per interval) -----------------------------
 

@@ -1,6 +1,6 @@
 """Affinity payoff: hierarchical + AffinityTracker must EARN its complexity.
 
-VERDICT r3 item 5: the affinity loop was fully wired (dispatch-observed
+r3 review, item 5: the affinity loop was fully wired (dispatch-observed
 tracker, tracker-carrying provider) but nothing demonstrated that it
 produces *better placements* than flat greedy on any workload metric.
 

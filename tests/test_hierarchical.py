@@ -105,7 +105,7 @@ def test_sharded_hierarchical_on_mesh():
 
 
 def test_fine_stage_sentinel_spill_routes_to_live_member():
-    """ADVICE r4: a real row seated on the padding-sentinel column (quota
+    """r4 review: a real row seated on the padding-sentinel column (quota
     drift, or the repair's refill clip spilling into the last column) must
     NOT be clamped by take_along_axis onto member s-1 — it routes to the
     group's highest-capacity member, like the overflow fallback. The guard
@@ -153,7 +153,7 @@ def test_hierarchical_dead_members_excluded_under_extreme_skew():
 )
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8-device mesh")
 def test_sharded_hierarchical_1m_x_1024_on_mesh():
-    """VERDICT r4 item 4: prove the sharding/memory math at the BASELINE
+    """r4 review, item 4: prove the sharding/memory math at the BASELINE
     row-5 node scale (1M objects x 1024 nodes, 32 groups) on the virtual
     mesh — four orders above the dryrun's phase-1 512 objects. Peak memory
     per shard stays O(N/8 x (G + S + d)) ~ 100 MB; a flat cost matrix

@@ -1,6 +1,6 @@
 """Churn-driven placement: the Server-owned daemon re-solves with ZERO app code.
 
-VERDICT r2 #3 / SURVEY §7.3: the reference recovers lazily inside the
+r2 review #3 / SURVEY §7.3: the reference recovers lazily inside the
 request path (``rio-rs/src/service.rs:227-298``); rio-tpu additionally
 re-seats displaced objects *proactively* — gossip marks a node dead, the
 ``PlacementDaemon`` feeds liveness to ``JaxObjectPlacement.sync_members``

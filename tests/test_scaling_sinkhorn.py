@@ -57,7 +57,7 @@ def test_scaling_matches_log_domain_offset_costs():
         ),
         lambda: pallas_scaling_sinkhorn(
             cost, mass, cap, eps=0.08, n_iters=25,
-            kernel_dtype=jnp.float32, block_rows=16,
+            kernel_dtype=jnp.float32, block_rows=16, interpret=True,
         ),
     ):
         out = solver()
