@@ -5,9 +5,9 @@ What it drives (defaults; every size is an argument):
 * one process that owns the chip: a ``JaxObjectPlacement(mode="auto")`` —
   which must resolve to ``sinkhorn`` because the backend is ``tpu`` — shared
   by 8 real ``Server`` instances on loopback TCP with ``placement_daemon=True``,
-  wired as ``examples/tpu_placement.py`` wires them, except that their load
-  monitors are off (see ``_run``: servers that share the directory's event
-  loop would read its bookkeeping as their own load);
+  wired as ``examples/tpu_placement.py`` wires them, every other option at
+  its default (so their load monitors run, and the directory's capacities
+  move with the load they measure);
 * a directory of 1,024 nodes (the 8 live servers plus 1,016 directory-only
   members in the membership storage the daemons read) and 1,048,576 objects
   seated through ``assign_batch`` (four 262,144-row device chunks);
@@ -20,7 +20,7 @@ What it drives (defaults; every size is an argument):
   ``mode="hierarchical"`` re-solve (chunked two-level route);
 * after each committed solve, host arithmetic on the directory mirror: every
   object on a live, uncordoned node, per-node load within the quota the exact
-  repair promises (``overflow == 0``);
+  repair promises for the capacities the solve was given (``overflow == 0``);
 * the fused Pallas scaling kernel compiled WITHOUT interpret mode at the shape
   its dispatch rule admits, compared with the XLA scaling core;
 * with >= 4 devices, the mesh x chunk route on a second directory.
@@ -46,6 +46,7 @@ import contextlib
 import json
 import os
 import sys
+import threading
 import time
 
 _T0 = time.perf_counter()
@@ -85,7 +86,7 @@ def phase(name: str):
 
 def _solve_record(stats) -> dict:
     """The SolveStats fields later PRs read, verbatim."""
-    if stats.compile_ms < 0 or stats.exec_ms < 0:
+    if not (0 <= stats.compile_ms <= stats.solve_ms) or stats.exec_ms < 0:
         raise AssertionError(f"solve {stats.mode} has no compile/exec split: {stats}")
     return {
         "mode": stats.mode,
@@ -148,38 +149,62 @@ async def _seats(placement, ids, index_of) -> "np.ndarray":
     return await asyncio.to_thread(read)
 
 
-def _check_directory(seats, schedulable, slack: int | None = 0) -> dict:
+def _capacities(placement, node_order) -> "np.ndarray":
+    """Per node index, the capacity the directory would give a solve right
+    now: the node's declared capacity times its measured-load derate."""
+    import numpy as np
+
+    slots = placement._nodes
+    return np.array(
+        [slots[a].capacity * slots[a].reported_derate for a in node_order], np.float64
+    )
+
+
+def _check_directory(seats, cap, slack: int | None = 0, exact: bool = True) -> dict:
     """Every object on a schedulable node, per-node load within quota.
 
-    Every node has capacity 1.0, so the fair share is ``n`` over the
-    schedulable nodes. A flat solve's exact repair lands every node on
-    floor or ceil of that (``slack=0``); the chunked two-level solve
-    repairs per group and per chunk, so it is allowed ``slack`` more.
+    ``cap`` is the capacity vector the solve was given, 0 for a node that
+    was not schedulable. Node ``j``'s fair share is ``n * cap[j] /
+    sum(cap)``. A flat solve's exact repair is one largest-remainder
+    rounding of those shares, so every node lands on the floor or the
+    ceiling of its own (``slack=0``). The two-level solve rounds twice in
+    every (device, chunk) cell — groups to their share of the cell, nodes
+    to their share of the group — so a node is within 2 of its fair share
+    per cell: ``slack = 2 * cells - 1`` around floor and ceiling.
     ``slack=None`` checks liveness only (``assign_batch``'s waterfill
-    promises no exact quota).
+    promises no exact quota). ``exact=False`` is for the greedy mode
+    (what ``mode="auto"`` resolves to off the chip): it moves no object a
+    quota does not force out, so it promises the ceiling and no floor,
+    and the underflow is reported, not held against it.
     """
     import numpy as np
 
     n = int(seats.shape[0])
-    m = int(schedulable.shape[0])
+    m = int(cap.shape[0])
+    sched = cap > 0
     counts = np.bincount(seats, minlength=m)
     out = {
         "objects": n,
-        "schedulable_nodes": int(schedulable.sum()),
-        "on_unschedulable": int(counts[~schedulable].sum()),
-        "max_load": int(counts[schedulable].max()),
-        "min_load": int(counts[schedulable].min()),
+        "schedulable_nodes": int(sched.sum()),
+        "on_unschedulable": int(counts[~sched].sum()),
+        "max_load": int(counts[sched].max()),
+        "min_load": int(counts[sched].min()),
     }
     if slack is not None:
-        fair = n / out["schedulable_nodes"]
-        live = counts[schedulable]
+        fair = n * cap[sched] / cap[sched].sum()
+        # Unequal capacities: the solver's float32 shares may round one
+        # that is an integer but for float error either way.
+        eps = 0.0 if np.ptp(cap[sched]) == 0 else 1e-3
+        live = counts[sched]
         out.update(
-            overflow=int(np.maximum(live - (np.ceil(fair) + slack), 0).sum()),
-            underflow=int(np.maximum((np.floor(fair) - slack) - live, 0).sum()),
-            fair_load=round(fair, 2),
+            overflow=int(np.maximum(live - (np.ceil(fair + eps) + slack), 0).sum()),
+            underflow=int(np.maximum((np.floor(fair - eps) - slack) - live, 0).sum()),
+            fair_load_min=round(float(fair.min()), 2),
+            fair_load_max=round(float(fair.max()), 2),
+            derated_nodes=int((cap[sched] < cap[sched].max()).sum()),
             slack=slack,
         )
-    if out["on_unschedulable"] or out.get("overflow") or out.get("underflow"):
+    if out["on_unschedulable"] or out.get("overflow") or (exact and out.get("underflow")):
         raise AssertionError(f"directory check failed: {out}")
     return out
 
@@ -242,8 +267,10 @@ async def _run(args, summary: dict) -> None:
         if n_dir < args.churn_nodes or args.servers < 2:
             raise SystemExit("need nodes - servers >= churn-nodes and servers >= 2")
         # Directory-only members are rows in the storage the daemons read:
-        # sync_members marks every node absent from the list dead.
-        dir_nodes = [f"10.77.{i // 250}.{i % 250 + 1}:7000" for i in range(n_dir)]
+        # sync_members marks every node absent from the list dead. Their
+        # addresses are loopback ones nobody listens on, so that a dial
+        # (a daemon's handoff towards one) is refused at once anywhere.
+        dir_nodes = [f"127.77.{i // 250}.{i % 250 + 1}:7000" for i in range(n_dir)]
         for addr in dir_nodes:
             await members.push(Member.from_address(addr, active=True))
         placement = jp.JaxObjectPlacement(mode="auto")
@@ -255,14 +282,6 @@ async def _run(args, summary: dict) -> None:
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
                 placement_daemon=True,
-                # These servers share one event loop with the directory
-                # and this script. Their load monitors would read that
-                # bookkeeping as their own loop lag, the daemons would
-                # derate their capacity (sync_load) and bump the epoch
-                # under every long solve: an artifact of co-location —
-                # in a deployment a server has its own process — and
-                # load-priced seating is not what this check is about.
-                load_monitor=False,
             )
             await s.prepare()
             await s.bind()
@@ -299,21 +318,29 @@ async def _run(args, summary: dict) -> None:
             native_codec=native.status(),
         )
 
-    async def schedulable_now() -> "np.ndarray":
-        """Active per node index, from the membership rows."""
+    async def capacities_now() -> "np.ndarray":
+        """The capacity vector a solve dispatched now would be given: the
+        directory's own capacity x derate, 0 where the membership rows
+        say inactive."""
         active = {m.address for m in await members.active_members()}
-        return np.array([a in active for a in node_order], bool)
+        return _capacities(placement, node_order) * np.array(
+            [a in active for a in node_order], np.float64
+        )
 
     async def forced_solve(label: str, slack: int, **kw) -> dict:
-        """One committed ``rebalance(**kw)``, then the directory check."""
+        """One committed ``rebalance(**kw)``, then the directory check
+        against the capacities read at the call (the solve snapshots
+        them before it first suspends)."""
         discarded = []
         for _ in range(5):
+            cap = await capacities_now()
             await placement.rebalance(**kw)
             stats = placement.stats
             if not stats.discarded:
                 break
-            # The directory moved under the solve (a daemon's own retry
-            # committed, say): the provider threw the result away.
+            # The directory moved under the solve: the provider threw the
+            # result away. Nothing here should cause that; it stays in
+            # the output when something did.
             discarded.append(_solve_record(stats))
             _note(f"{label}: attempt {len(discarded)} lost an epoch race, retrying")
         else:
@@ -321,7 +348,9 @@ async def _run(args, summary: dict) -> None:
         seats = await _seats(placement, ids, index_of)
         out = _solve_record(stats)
         out["discarded_attempts"] = discarded
-        out["directory"] = _check_directory(seats, await schedulable_now(), slack)
+        out["directory"] = _check_directory(
+            seats, cap, slack, exact=not stats.mode.startswith("greedy")
+        )
         return out
 
     client = Client(
@@ -385,7 +414,7 @@ async def _run(args, summary: dict) -> None:
                 objects=args.objects,
                 device_chunks=chunks,
                 chunk_rows=min(args.objects, placement._MAX_PLACE_CHUNK),
-                directory=_check_directory(seats, await schedulable_now(), None),
+                directory=_check_directory(seats, await capacities_now(), None),
             )
 
         with phase("requests_before_churn") as rec:
@@ -415,6 +444,23 @@ async def _run(args, summary: dict) -> None:
             ] + [victim.local_address]
             gone_idx = np.array([index_of[a] for a in gone])
             displaced = np.isin(before, gone_idx)
+            # The delta route moves the displaced and nobody else only
+            # while no survivor is over its quota, and a server derated by
+            # this script's own bookkeeping would be. The event waits
+            # until the servers measure themselves idle again.
+            t0 = time.perf_counter()
+            full = np.array([placement._nodes[a].capacity for a in node_order])
+            quiet = 0
+            for _ in range(1200):
+                quiet = quiet + 1 if (_capacities(placement, node_order) == full).all() else 0
+                if quiet >= 20:
+                    break
+                await asyncio.sleep(0.1)
+            else:
+                raise AssertionError("the servers never measured themselves idle")
+            rec["idle_wait_s"] = round(time.perf_counter() - t0, 3)
+            cap_event = await capacities_now()
+            cap_event[gone_idx] = 0.0
             epoch_start = placement.stats.epoch
 
             def daemon_solves() -> list:
@@ -449,21 +495,7 @@ async def _run(args, summary: dict) -> None:
                     f"nodes' objects: stats={placement.stats}"
                 )
             rec["reseat_s"] = round(time.perf_counter() - t0, 3)
-            # The daemons that lost the epoch race to the one that served
-            # the event retry on a backoff ladder (no-op delta solves, each
-            # an epoch bump); let that pass, or it would discard the forced
-            # solves below and could re-plan seats under the last requests.
-            survivors = [s.placement_daemon for s in servers[:-1]]
-            for _ in range(1200):
-                if not any(d._retry_solve for d in survivors):
-                    break
-                await asyncio.sleep(0.1)
-            else:
-                raise AssertionError("the placement daemons never went quiet")
-            rec["daemons_quiet_s"] = round(time.perf_counter() - t0, 3)
-            delta_solves = [
-                _solve_record(s) for s in daemon_solves() if s.mode.endswith("+delta")
-            ]
+            committed = daemon_solves()
             dstats = [s.placement_daemon.stats for s in servers]
             rec.update(
                 departed_nodes=len(gone),
@@ -473,11 +505,12 @@ async def _run(args, summary: dict) -> None:
                 daemon_rebalances=sum(d.rebalances for d in dstats),
                 daemon_delta_rebalances=sum(d.delta_rebalances for d in dstats),
                 daemon_discarded=sum(d.rebalances_discarded for d in dstats),
+                daemon_skipped=sum(d.rebalances_skipped for d in dstats),
                 daemon_errors=sum(d.errors for d in dstats),
-                delta_solves=delta_solves,
+                daemon_solves=[_solve_record(s) for s in committed],
             )
             if (
-                not delta_solves
+                not all(s.mode.endswith("+delta") for s in committed)
                 or rec["daemon_delta_rebalances"] < 1
                 or rec["daemon_errors"]
                 or rec["undisplaced_moved"]
@@ -485,7 +518,10 @@ async def _run(args, summary: dict) -> None:
             ):
                 raise AssertionError(f"churn was not served by a daemon delta: {rec}")
             live_now = [a for a in live if a != victim.local_address]
-            rec["directory"] = _check_directory(after, await schedulable_now(), 0)
+            rec["directory"] = _check_directory(
+                after, cap_event, 0,
+                exact=not any(s.mode.startswith("greedy") for s in committed),
+            )
 
         with phase("requests_after_churn") as rec:
             # Objects the device solve moved from a departed node onto a
@@ -513,7 +549,7 @@ async def _run(args, summary: dict) -> None:
             want_chunks = max(1, bucket // jp._HIER_CHUNK_ROWS)
             rec.update(
                 await forced_solve(
-                    "hierarchical solve", 2 * want_chunks + 1,
+                    "hierarchical solve", 2 * want_chunks - 1,
                     mode="hierarchical", delta=False,
                 )
             )
@@ -596,6 +632,36 @@ def _kernel_phase(args) -> None:
         rec["verdict"] = "compiled and within tolerance"
 
 
+def _bytes_in_use(devices) -> list[int]:
+    return [int((d.memory_stats() or {}).get("bytes_in_use", 0)) for d in devices]
+
+
+class _MemorySampler:
+    """Highest ``bytes_in_use`` per device seen while the block runs,
+    polled from a thread every 10 ms (the solve runs off the event loop
+    and releases the GIL while the devices work)."""
+
+    def __init__(self, devices) -> None:
+        self.devices = devices
+        self.peak = _bytes_in_use(devices)
+        self.samples = 0
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._poll, daemon=True)
+
+    def _poll(self) -> None:
+        while not self._stop.wait(0.01):
+            self.peak = [max(a, b) for a, b in zip(self.peak, _bytes_in_use(self.devices))]
+            self.samples += 1
+
+    def __enter__(self) -> "_MemorySampler":
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._stop.set()
+        self._thread.join()
+
+
 async def _mesh_phase(args) -> None:
     """The mesh x chunk route over every visible device, on a second
     directory built as ``JaxObjectPlacement(mode="hierarchical", mesh=...)``."""
@@ -613,11 +679,17 @@ async def _mesh_phase(args) -> None:
     with phase("mesh_chunk_solve") as rec:
         mesh = make_mesh()
         p = jp.JaxObjectPlacement(mode="hierarchical", mesh=mesh)
-        nodes = [f"10.78.{i // 250}.{i % 250 + 1}:7000" for i in range(args.mesh_nodes)]
+        nodes = [f"127.78.{i // 250}.{i % 250 + 1}:7000" for i in range(args.mesh_nodes)]
         p.sync_members(nodes)
         ids = [ObjectId("MeshSmoke", str(i)) for i in range(args.mesh_objects)]
         await p.assign_batch(ids)
-        await p.rebalance(delta=False)
+        # Where the solve's memory goes, device by device. The lifetime
+        # peak cannot say: device 0 also ran every earlier phase and the
+        # seating above. So the bytes in use are sampled while the solve
+        # runs, against each device's level just before it.
+        base = _bytes_in_use(devices)
+        with _MemorySampler(devices) as sampler:
+            await p.rebalance(delta=False)
         st = p.stats
         rec.update(_solve_record(st), objects=args.mesh_objects, nodes=args.mesh_nodes)
         per_dev = -(-jp._next_bucket(args.mesh_objects) // len(devices))
@@ -635,17 +707,27 @@ async def _mesh_phase(args) -> None:
             )
         index_of = {a: i for i, a in enumerate(p._node_order)}
         seats = await _seats(p, ids, index_of)
-        # Every (device, chunk) cell repairs its own quotas.
         rec["directory"] = _check_directory(
-            seats, np.ones(len(nodes), bool), 2 * want_chunks * len(devices) + 1
+            seats, _capacities(p, p._node_order), 2 * want_chunks * len(devices) - 1
         )
-        mem = [d.memory_stats() or {} for d in devices]
-        rec["peak_bytes_per_device"] = [
-            int(s.get("peak_bytes_in_use", -1)) for s in mem
-        ]
-        if devices[0].platform != "cpu" and min(rec["peak_bytes_per_device"]) < (1 << 20):
+        grew = [hi - lo for hi, lo in zip(sampler.peak, base)]
+        rec.update(
+            solve_bytes_per_device=grew,
+            memory_samples=sampler.samples,
+            peak_bytes_per_device=[
+                int((d.memory_stats() or {}).get("peak_bytes_in_use", -1))
+                for d in devices
+            ],
+        )
+        # Every device holds its own rows and no more: the feature block
+        # goes shard by shard from the host to its device. Committed to
+        # device 0 first, it would sit there whole (objects x 16 float32,
+        # 268 MB at the default size) for the length of the solve.
+        if devices[0].platform != "cpu" and not (
+            min(grew) >= (1 << 20) and grew[0] <= 1.5 * max(grew[1:])
+        ):
             raise AssertionError(
-                f"a device held under 1 MiB at peak: {rec['peak_bytes_per_device']}"
+                f"the solve's memory is not spread evenly over the devices: {rec}"
             )
     with phase("mesh_result_shards") as rec:
         # Where a sharded solve's result lives: one shard per device.
@@ -750,7 +832,7 @@ def main() -> int:
         cache_misses=_WATCH["cache_misses"],
         solve_modes=[
             p["mode"] for p in _PHASES if "mode" in p
-        ] + [s["mode"] for p in _PHASES for s in p.get("delta_solves", ())],
+        ] + [s["mode"] for p in _PHASES for s in p.get("daemon_solves", ())],
         peak_device_bytes=int(mem.get("peak_bytes_in_use", -1)),
         compile_cache_dir=cache_dir,
         compile_cache_entries_at_exit=cache_entries(),

@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import threading
 import time
 from dataclasses import dataclass, field, replace
 
@@ -461,31 +462,38 @@ def _class_refresh_device(base, counts, cap_alive, g_seed, *, mode, move_cost, e
 
 # -- solver convergence telemetry helpers (PR 11) ----------------------------
 
-# Cumulative backend-compile seconds seen by this process's jax, fed by a
-# jax.monitoring listener registered on first use. Only the backend
-# compile event is summed: it fires once per executable and includes a
-# persistent-cache retrieval, whereas the trace and lowering events nest
-# (a jit traced inside a jit is counted again by its parent) and the
-# "/jax/compilation_cache/..." durations report time SAVED, not spent.
-_COMPILE_WATCH: dict = {"total_s": 0.0, "registered": False}
+# Backend-compile seconds, accumulated per THREAD by a jax.monitoring
+# listener registered on first use. jax reports a compile from the thread
+# that asked for it, and a solve runs start to end in one worker thread, so
+# a per-thread total keeps concurrent solves (N placement daemons sharing
+# one provider each run theirs in ``to_thread``) out of each other's
+# windows. Only the backend compile event is summed: it fires once per
+# executable and includes a persistent-cache retrieval, whereas the trace
+# and lowering events nest (a jit traced inside a jit is counted again by
+# its parent) and the "/jax/compilation_cache/..." durations report time
+# SAVED, not spent.
+_COMPILE_WATCH = threading.local()
+_COMPILE_LISTENER = {"registered": False}
 
 
 def _compile_seconds() -> float:
-    """Backend-compile seconds accumulated so far by this process.
+    """Backend-compile seconds accumulated so far by the CALLING thread.
 
-    Snapshot before and after a solve window to split ``solve_ms`` into
-    compile vs execute. Process-global on purpose: solves run one at a
-    time in the provider's solver thread.
+    Snapshot before and after a solve window, in the solve's own thread,
+    to split ``solve_ms`` into compile vs execute: whatever the delta
+    holds was spent inside that window, so ``compile_ms <= solve_ms``.
     """
-    if not _COMPILE_WATCH["registered"]:
+    if not _COMPILE_LISTENER["registered"]:
 
         def _on_duration(event: str, duration: float, **_kw) -> None:
             if event == "/jax/core/compile/backend_compile_duration":
-                _COMPILE_WATCH["total_s"] += duration
+                _COMPILE_WATCH.total_s = (
+                    getattr(_COMPILE_WATCH, "total_s", 0.0) + duration
+                )
 
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        _COMPILE_WATCH["registered"] = True
-    return _COMPILE_WATCH["total_s"]
+        _COMPILE_LISTENER["registered"] = True
+    return getattr(_COMPILE_WATCH, "total_s", 0.0)
 
 
 def _seed_warm_ratio(seed) -> float:
@@ -522,7 +530,7 @@ def _conv_timing(conv: dict, t0: float, c0: float) -> tuple[float, dict]:
     """Close a solve window: wall ms plus the compile/execute split."""
     ms = (time.perf_counter() - t0) * 1e3
     conv["compile_ms"] = round((_compile_seconds() - c0) * 1e3, 3)
-    conv["exec_ms"] = round(max(ms - conv["compile_ms"], 0.0), 3)
+    conv["exec_ms"] = round(ms - conv["compile_ms"], 3)
     return ms, conv
 
 
@@ -941,7 +949,16 @@ class JaxObjectPlacement(ObjectPlacement):
         # Liveness-flip subscribers (the placement daemon's event kick).
         self._churn_listeners: list = []
         self._lock = asyncio.Lock()
+        self._cache_placed = False  # see _place_compile_cache
         self.stats = SolveStats()
+
+    def _place_compile_cache(self) -> None:
+        """Place the persistent compile cache once, at this provider's
+        first solve (it touches the backend, so never the constructor):
+        a user's server gets it the way the smoke does."""
+        if not self._cache_placed:
+            compile_cache_dir()
+            self._cache_placed = True
 
     def _solver_mode(self) -> str:
         """Resolve ``mode="auto"`` on first use (first backend touch).
@@ -1114,20 +1131,29 @@ class JaxObjectPlacement(ObjectPlacement):
             self._notify_churn()
 
     # Derates quantize to 1/8 steps: sync_load runs every monitor tick
-    # (~seconds), and an un-quantized float would change on every call,
-    # bumping the epoch each time — which would discard every in-flight
-    # solve longer than a tick (the big ones are minutes). A bucket flip
-    # is a real regime change and worth the re-solve.
+    # (~seconds), and an un-quantized float would re-price every node on
+    # every call, so each re-solve would shuffle seats for measurement
+    # noise. A bucket flip is a real regime change.
     _DERATE_STEP = 8.0
 
     def sync_load(self, view) -> None:
         """Feed measured cluster load (``rio_tpu.load.ClusterLoadView``)
         into the cost model: each node's solver capacity column becomes
         ``capacity * derate``. Loop-side and lock-free, exactly like
-        ``sync_members`` (snapshot-solve-apply covers concurrent solves);
-        called by the LoadMonitor's view refresh and the placement
-        daemon's poll. ``view=None`` (or an unknown/stale entry) resets a
-        node to its full capacity."""
+        ``sync_members``; called by the LoadMonitor's view refresh and
+        the placement daemon's poll. ``view=None`` (or an unknown/stale
+        entry) resets a node to its full capacity.
+
+        A derate is a PRICE, not a directory fact, so it does not move
+        the epoch: a solve in flight commits against the capacities it
+        snapshotted, which is what every committed solve is one tick
+        later anyway. (It used to bump the epoch. A server that shares
+        the directory's process reads a long solve's host work as its own
+        loop lag, so the solve derated its neighbours, the flip discarded
+        the solve — on the v5e every cold-compile solve of the
+        1,048,576-row directory, 150 s of work, PR 21 — and under load
+        that wobbles a churn re-solve could lose every retry.) The epoch
+        guards what an apply can corrupt: seats and liveness."""
         changed = False
         for addr, slot in self._nodes.items():
             d = 1.0 if view is None else float(view.derate(addr))
@@ -1139,7 +1165,6 @@ class JaxObjectPlacement(ObjectPlacement):
                 slot.reported_derate = q
                 changed = True
         if changed:
-            self._epoch += 1
             # Derates floor at 0.1 and never zero a capacity column, so no
             # node LEAVES the schedulable set here — the fingerprint check
             # keeps the potentials (they merely under-react to the new
@@ -1318,7 +1343,7 @@ class JaxObjectPlacement(ObjectPlacement):
         """
         if not object_ids or k <= 0:
             return [[] for _ in object_ids]
-        compile_cache_dir()
+        self._place_compile_cache()
         async with self._lock:
             keys = [str(o) for o in object_ids]
             primary = np.asarray(
@@ -1421,10 +1446,7 @@ class JaxObjectPlacement(ObjectPlacement):
         the awaits, so no other locked mutator interleaves within a chunk;
         lock-free dict reads (``lookup``) stay live throughout.
         """
-        # Every solve entry point places the persistent compile cache
-        # first (idempotent; it touches the backend, so never the
-        # constructor): a user's server gets it the way the smoke does.
-        compile_cache_dir()
+        self._place_compile_cache()
         # Snapshot here, not at batch start: the previous chunk's apply
         # (and, between lock holds, any interleaved mutator) changed load.
         load, cap, alive = self._node_vectors()
@@ -2418,10 +2440,10 @@ class JaxObjectPlacement(ObjectPlacement):
         own ``update()`` flips the row. The sink runs OUTSIDE the
         provider lock: handoffs call back into ``update``/``lookup``.
         """
+        self._place_compile_cache()
         # An explicit mode="auto" resolves exactly like the constructor
         # default (it would otherwise fall through every dispatch check
         # and silently run the greedy branch).
-        compile_cache_dir()
         mode = self._solver_mode() if mode in (None, "auto") else mode
         async with self._lock:
             n = len(self._placements)

@@ -50,7 +50,7 @@ class PlacementDaemonStats:
     rebalances: int = 0
     delta_rebalances: int = 0  # committed solves that took the delta path
     rebalances_skipped: int = 0  # sibling daemon on a shared provider won
-    rebalances_discarded: int = 0  # lost an epoch race; retried next poll
+    rebalances_discarded: int = 0  # lost an epoch race; retried unless a sibling won
     retries_abandoned: int = 0  # discard-retry budget exhausted; wait for churn
     degraded_polls: int = 0  # polls lost to storage errors (backoff pacing)
     moves: int = 0
@@ -379,6 +379,7 @@ class PlacementDaemon:
                         await self._idle(cfg.poll_interval)
                         continue
                     stats_before = getattr(self.placement, "stats", None)
+                    committed_before = self._solve_epoch()
                     moved = await self._rebalance(cfg.mode)
                     last_rebalance = loop.time()
                     stats_now = getattr(self.placement, "stats", None)
@@ -392,7 +393,28 @@ class PlacementDaemon:
                         and getattr(stats_now, "discarded", False)
                     )
                     self._journal_solve(stats_before, stats_now, moved)
-                    if ours_discarded:
+                    committed_now = self._solve_epoch()
+                    if (
+                        ours_discarded
+                        and committed_now is not None
+                        and committed_now != committed_before
+                    ):
+                        # What discarded ours was a sibling daemon's COMMIT
+                        # on the same provider (N co-located servers answer
+                        # one churn event with N solves; one wins). A solve
+                        # that commits after our sync_members snapshotted
+                        # at least the liveness we saw — a later flip would
+                        # have discarded it too — so the event is served:
+                        # retrying would dispatch a no-op solve whose own
+                        # epoch bump discards whatever else is in flight.
+                        self.stats.rebalances_discarded += 1
+                        self._retry_solve = False
+                        self._consecutive_discards = 0
+                        log.info(
+                            "churn re-solve discarded; a sibling daemon's "
+                            "solve committed the event"
+                        )
+                    elif ours_discarded:
                         # The solve lost an epoch race (concurrent churn or
                         # allocation landed mid-solve): the liveness change
                         # is still unserved — retry, but on an exponential

@@ -299,7 +299,7 @@ def _view_for(loads: dict[str, LoadVector]) -> ClusterLoadView:
     return ClusterLoadView.from_members(members, now=now)
 
 
-def test_sync_load_derates_and_quantizes_epoch():
+def test_sync_load_derates_in_quantized_steps_without_moving_the_epoch():
     p = _jax_provider()
     a, b = "10.0.0.0:5000", "10.0.0.1:5000"
     epoch0 = p._epoch
@@ -307,15 +307,45 @@ def test_sync_load_derates_and_quantizes_epoch():
     p.sync_load(_view_for({a: LoadVector(inflight=1792), b: LoadVector()}))
     assert p._nodes[a].reported_derate == 0.125
     assert p._nodes[b].reported_derate == 1.0
-    assert p._epoch == epoch0 + 1
-    # A tiny wobble inside the same 1/8 bucket must NOT bump the epoch
-    # (it would discard every in-flight solve once per monitor tick).
+    # A tiny wobble inside the same 1/8 bucket re-prices nothing.
     p.sync_load(_view_for({a: LoadVector(inflight=1800), b: LoadVector()}))
-    assert p._epoch == epoch0 + 1
-    # view=None resets to full capacity (one more epoch bump).
+    assert p._nodes[a].reported_derate == 0.125
+    # view=None resets to full capacity.
     p.sync_load(None)
     assert p._nodes[a].reported_derate == 1.0
-    assert p._epoch == epoch0 + 2
+    # A derate is a price, not a directory fact: the epoch guards seats
+    # and liveness only.
+    assert p._epoch == epoch0
+
+
+async def test_derate_flip_does_not_discard_a_solve_in_flight():
+    """A server sharing the directory's process reads a long solve's host
+    work as its own load; the derate flip that follows must not throw the
+    solve away (on the chip it discarded every cold-compile solve)."""
+    p = _jax_provider(mode="sinkhorn")
+    a, b = "10.0.0.0:5000", "10.0.0.1:5000"
+    await p.assign_batch([ObjectId("T", str(i)) for i in range(64)])
+    real_to_thread = asyncio.to_thread
+
+    async def flip_mid_solve(fn, *args):
+        out = await real_to_thread(fn, *args)
+        p.sync_load(_view_for({a: LoadVector(inflight=1792), b: LoadVector()}))
+        return out
+
+    asyncio.to_thread = flip_mid_solve
+    try:
+        await p.rebalance(delta=False)
+    finally:
+        asyncio.to_thread = real_to_thread
+    assert p._nodes[a].reported_derate == 0.125
+    assert not p.stats.discarded
+    # The committed solve holds the capacities it snapshotted (even);
+    # the next one prices the derate in.
+    counts = [len(p._by_node.get(i, ())) for i in range(2)]
+    assert counts == [32, 32]
+    await p.rebalance(delta=False)
+    assert not p.stats.discarded
+    assert len(p._by_node.get(0, ())) < len(p._by_node.get(1, ()))
 
 
 async def test_assign_batch_respects_derated_capacity():

@@ -433,6 +433,68 @@ def test_daemon_retries_after_epoch_discarded_solve():
     asyncio.run(asyncio.wait_for(run(), 30))
 
 
+def test_daemons_sharing_a_provider_do_not_retry_an_event_a_sibling_committed():
+    """N co-located servers share one provider, so one churn event makes N
+    daemons dispatch a solve and N-1 lose the epoch race to the winner's
+    commit. The losers' event IS served: no retry ladder of no-op solves
+    (each an epoch bump that discards whatever else is in flight)."""
+    from rio_tpu import ObjectId
+    from rio_tpu.cluster.storage import Member
+
+    async def run():
+        storage = LocalStorage()
+        nodes = [f"10.5.0.{i}:90" for i in range(1, 7)]
+        for a in nodes:
+            await storage.push(Member.from_address(a, active=True))
+        placement = JaxObjectPlacement(mode="greedy")
+        placement.sync_members(await storage.members())
+        await placement.assign_batch([ObjectId("T", str(i)) for i in range(120)])
+        await placement.rebalance(delta=False)  # the plan deltas run against
+        cfg = PlacementDaemonConfig(
+            poll_interval=0.05, debounce=0.01, min_rebalance_interval=0.2
+        )
+        daemons = [PlacementDaemon(storage, placement, cfg) for _ in range(4)]
+        # Hold every solve open until all four daemons are inside one:
+        # the epoch race the debounce jitter only makes likely.
+        inside, release = [], asyncio.Event()
+        real = placement.rebalance
+
+        async def slow_rebalance(**kw):
+            inside.append(1)
+            if len(inside) >= len(daemons):
+                release.set()
+            await asyncio.wait_for(release.wait(), 10)
+            return await real(**kw)
+
+        placement.rebalance = slow_rebalance
+        tasks = [asyncio.create_task(d.run()) for d in daemons]
+        try:
+            await asyncio.sleep(0.3)  # first sync (no solve)
+            await storage.set_inactive("10.5.0.6", 90)
+            for _ in range(200):
+                if sum(d.stats.rebalances + d.stats.rebalances_discarded
+                       for d in daemons) >= len(daemons):
+                    break
+                await asyncio.sleep(0.05)
+            dispatched = len(inside)
+            await asyncio.sleep(1.0)  # several rungs of the old ladder
+        finally:
+            for t in tasks:
+                t.cancel()
+            await asyncio.gather(*tasks, return_exceptions=True)
+        assert dispatched == len(daemons)
+        assert sum(d.stats.rebalances for d in daemons) == 1
+        assert sum(d.stats.rebalances_discarded for d in daemons) == 3
+        assert len(inside) == dispatched, "a served event was retried"
+        assert not any(d._retry_solve for d in daemons)
+        seats = await placement.lookup_batch(
+            [ObjectId("T", str(i)) for i in range(120)]
+        )
+        assert "10.5.0.6:90" not in seats
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+
+
 def test_daemon_abandons_retries_after_consecutive_discards():
     """Sustained epoch races must not livelock the device: after
     max_discard_retries consecutive discards the daemon stops dispatching
