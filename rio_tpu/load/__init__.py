@@ -33,6 +33,7 @@ stack.
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 import logging
 import math
@@ -41,6 +42,8 @@ import threading
 import time
 import traceback
 from typing import Any, Callable
+
+from .. import tracing
 
 log = logging.getLogger("rio_tpu.load")
 
@@ -320,6 +323,10 @@ class LoadThresholds:
     max_registry_objects: int | None = None
 
 
+#: Raw per-tick lag samples a monitor keeps (17 minutes at the default tick).
+LAG_SAMPLES = 1024
+
+
 @dataclasses.dataclass
 class LoadMonitorStats:
     """Counters exported through :func:`rio_tpu.otel.stats_gauges`."""
@@ -328,6 +335,12 @@ class LoadMonitorStats:
     sheds: int = 0  # requests refused with ServerBusy
     stalls: int = 0  # loop stalls caught with a stack by the watchdog
     loop_lag_ms: float = 0.0
+    # Each tick's raw lag as ``(time.perf_counter_ns() at the tick, lag_ms)``:
+    # the EMA above hides the tail, this keeps it (bounded; one append a
+    # tick). The loop was late from ``t - lag`` to ``t``.
+    lag_samples: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=LAG_SAMPLES), repr=False
+    )
     inflight: int = 0
     registry_objects: int = 0
     req_rate: float = 0.0
@@ -550,6 +563,20 @@ class LoadMonitor:
         if placement is not None and hasattr(placement, "sync_load"):
             placement.sync_load(view)
 
+    def stall_gauges(self) -> dict[str, float]:
+        """``rio.load.stall_max_ms`` / ``stall_total_ms``, made at scrape time
+        from the kept lag samples: every tick that woke later than the
+        watchdog's threshold, timed by the loop itself (``stalls`` counts the
+        watchdog's captures, which are cooldown-limited; these are not)."""
+        late = [
+            ms for _, ms in list(self.stats.lag_samples)
+            if 0.0 < self.stall_threshold_ms <= ms
+        ]
+        return {
+            "rio.load.stall_max_ms": max(late, default=0.0),
+            "rio.load.stall_total_ms": float(sum(late)),
+        }
+
     def _drain_pending_stall(self) -> None:
         """Journal a watchdog capture from the loop thread (ring discipline:
         only the loop appends; the watchdog merely parks the evidence)."""
@@ -581,9 +608,13 @@ class LoadMonitor:
             self._heartbeat = time.monotonic()
             watchdog = _StallWatchdog(self, threading.get_ident(), self.interval)
             watchdog.start()
+        # Full collections stop the loop too: while any monitor runs, the
+        # process logs each as a ``gc.gen2`` stage (one callback a process).
+        tracing.watch_gc()
         try:
             await self._run(loop, last_view)
         finally:
+            tracing.unwatch_gc()
             if watchdog is not None:
                 watchdog.stop_event.set()
 
@@ -594,6 +625,7 @@ class LoadMonitor:
             # Scheduling drift across our own sleep IS event-loop lag: a
             # loop starved by slow callbacks wakes us late by that much.
             lag_ms = max(0.0, (loop.time() - t0 - self.interval)) * 1e3
+            self.stats.lag_samples.append((time.perf_counter_ns(), lag_ms))
             self._sample(lag_ms)
             self._heartbeat = time.monotonic()
             self._drain_pending_stall()

@@ -27,6 +27,7 @@ service checks ``is_active`` before honoring a placement
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import os
 import threading
@@ -39,6 +40,7 @@ import numpy as np
 
 from ..errors import NoSchedulableCapacity
 from ..registry import ObjectId
+from ..tracing import stage, stage_since
 from ..utils.jaxenv import compile_cache_dir
 from ..ops import (
     build_cost_matrix,
@@ -270,16 +272,6 @@ class AffinityTracker:
             w = 1.0 + rates.get(k, 0.0) / rate_scale + sizes.get(k, 0.0) / bytes_scale
             out[i] = min(max_weight, w)
         return out
-
-
-def _profiler_trace(name: str):
-    """jax.profiler annotation for solver steps (SURVEY §5.1); no-op off-JAX."""
-    import contextlib
-
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler unavailable
-        return contextlib.nullcontext()
 
 
 # Hierarchical solves chunk the object axis above this row count (power
@@ -526,12 +518,12 @@ def _conv_fields(conv: dict | None) -> dict:
     }
 
 
-def _conv_timing(conv: dict, t0: float, c0: float) -> tuple[float, dict]:
-    """Close a solve window: wall ms plus the compile/execute split."""
-    ms = (time.perf_counter() - t0) * 1e3
+def _conv_timing(conv: dict, ms: float, c0: float) -> dict:
+    """Split a closed solve window of ``ms`` wall milliseconds (the
+    ``solve.device`` stage's own stamps) into compile and execute."""
     conv["compile_ms"] = round((_compile_seconds() - c0) * 1e3, 3)
     conv["exec_ms"] = round(ms - conv["compile_ms"], 3)
-    return ms, conv
+    return conv
 
 
 def _apply_class_quotas(quotas: np.ndarray, cur_idx: np.ndarray) -> np.ndarray:
@@ -1377,10 +1369,21 @@ class JaxObjectPlacement(ObjectPlacement):
     # ------------------------------------------------------- batched solve
     async def lookup_batch(self, object_ids: list[ObjectId]) -> list[str | None]:
         out: list[str | None] = []
-        for oid in object_ids:
-            idx = self._placements.get(str(oid))
-            out.append(None if idx is None else self._node_order[idx])
+        with stage("place.lookup"):
+            for oid in object_ids:
+                idx = self._placements.get(str(oid))
+                out.append(None if idx is None else self._node_order[idx])
         return out
+
+    @contextlib.asynccontextmanager
+    async def _lock_staged(self):
+        """``async with self._lock``, the wait logged as ``place.lock_wait``."""
+        with stage("place.lock_wait"):
+            await self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     async def assign_batch(self, object_ids: list[ObjectId]) -> list[str]:
         """Place a batch of (possibly new) objects in one device call.
@@ -1407,18 +1410,25 @@ class JaxObjectPlacement(ObjectPlacement):
         batch cannot be seated anywhere, and silently parking it would
         strand every key.
         """
-        keys = [str(o) for o in object_ids]
-        for start in range(0, len(keys), self._MAX_PLACE_CHUNK):
-            chunk = keys[start : start + self._MAX_PLACE_CHUNK]
-            async with self._lock:
-                unplaced = [k for k in chunk if k not in self._placements]
-                if unplaced:
-                    await self._place_chunk_locked(unplaced)
-        async with self._lock:
-            missing = [k for k in keys if k not in self._placements]
-            if missing:
-                await self._place_keys_async(missing)
-            return [self._node_order[self._placements[k]] for k in keys]
+        # The stages below tile the call (PERF.md section 3 names the metric
+        # that reads each): one record per stage per chunk, nothing per key.
+        with stage("place.assign"):
+            with stage("place.keys"):
+                keys = [str(o) for o in object_ids]
+            for start in range(0, len(keys), self._MAX_PLACE_CHUNK):
+                async with self._lock_staged():
+                    with stage("place.filter"):
+                        chunk = keys[start : start + self._MAX_PLACE_CHUNK]
+                        unplaced = [k for k in chunk if k not in self._placements]
+                    if unplaced:
+                        await self._place_chunk_locked(unplaced)
+            async with self._lock_staged():
+                with stage("place.filter"):
+                    missing = [k for k in keys if k not in self._placements]
+                if missing:
+                    await self._place_keys_async(missing)
+                with stage("place.resolve"):
+                    return [self._node_order[self._placements[k]] for k in keys]
 
     # Bounds the (bucket x node_axis) working set of one placement solve:
     # 262,144 x 1,024 fp32 is ~1 GB of sort/cumsum temps. A single
@@ -1446,17 +1456,26 @@ class JaxObjectPlacement(ObjectPlacement):
         the awaits, so no other locked mutator interleaves within a chunk;
         lock-free dict reads (``lookup``) stay live throughout.
         """
-        self._place_compile_cache()
-        # Snapshot here, not at batch start: the previous chunk's apply
-        # (and, between lock holds, any interleaved mutator) changed load.
-        load, cap, alive = self._node_vectors()
-        g = self._g
-        n_real = len(self._node_order)  # snapshot: the thread reads no live state
-        no_capacity = self._no_schedulable_capacity_host()
-        assignment = await asyncio.to_thread(
-            self._solve_chunk, chunk, load, cap, alive, g, n_real, no_capacity
-        )
-        self._apply_chunk(chunk, assignment)
+        with stage("place.snapshot"):
+            self._place_compile_cache()
+            # Snapshot here, not at batch start: the previous chunk's apply
+            # (and, between lock holds, any interleaved mutator) changed load.
+            load, cap, alive = self._node_vectors()
+            g = self._g
+            n_real = len(self._node_order)  # snapshot: the thread reads no live state
+            no_capacity = self._no_schedulable_capacity_host()
+
+        def _solve() -> tuple[np.ndarray, int]:
+            with stage("place.solve") as st:
+                out = self._solve_chunk(chunk, load, cap, alive, g, n_real, no_capacity)
+            return out, st.t1
+
+        assignment, t_solved = await asyncio.to_thread(_solve)
+        # From the thread's last instant to this coroutine running again:
+        # the wait for a busy loop.
+        stage_since("place.resume", t_solved)
+        with stage("place.apply"):
+            self._apply_chunk(chunk, assignment)
 
     def _solve_chunk(
         self, keys, load, cap, alive, g, n_real, no_capacity=False
@@ -1475,24 +1494,24 @@ class JaxObjectPlacement(ObjectPlacement):
             # (rio-rs/src/service.rs:213-238 re-seats on the next
             # request); the next liveness change re-solves.
             return _least_loaded_spread(load, alive, cap, n_real, n)
-        cost = build_cost_matrix(load, cap, alive)  # (1, n_nodes)
-        if g is not None:
-            # Warm path: bias the score by the cached node potentials from the
-            # last OT solve, then waterfill (balance even under cost ties).
-            g = jnp.where(jnp.isfinite(g), g, -1e9)
-            cost = cost - g[None, :]
-        bucket = _next_bucket(n)
-        rows = jnp.broadcast_to(cost, (bucket, cost.shape[1]))
-        mass = jnp.concatenate(
-            [jnp.ones((n,), jnp.float32), jnp.zeros((bucket - n,), jnp.float32)]
-        )
-        return _route_unseatable(
-            np.asarray(greedy_balanced_assign(rows, mass, cap * alive, load))[:n],
-            n_real,
-            load,
-            alive,
-            cap,
-        )
+        with stage("place.solve.build"):  # up to the jitted call's return
+            cost = build_cost_matrix(load, cap, alive)  # (1, n_nodes)
+            if g is not None:
+                # Warm path: bias the score by the cached node potentials from
+                # the last OT solve, then waterfill (balance even under cost
+                # ties).
+                g = jnp.where(jnp.isfinite(g), g, -1e9)
+                cost = cost - g[None, :]
+            bucket = _next_bucket(n)
+            rows = jnp.broadcast_to(cost, (bucket, cost.shape[1]))
+            mass = jnp.concatenate(
+                [jnp.ones((n,), jnp.float32), jnp.zeros((bucket - n,), jnp.float32)]
+            )
+            seats = greedy_balanced_assign(rows, mass, cap * alive, load)
+        with stage("place.solve.wait"):  # the device, and the transfer back
+            seats = np.asarray(seats)[:n]
+        with stage("place.solve.route"):
+            return _route_unseatable(seats, n_real, load, alive, cap)
 
     def _apply_chunk(self, keys: list[str], assignment: np.ndarray) -> None:
         for k, idx in zip(keys, assignment.tolist()):
@@ -1677,9 +1696,10 @@ class JaxObjectPlacement(ObjectPlacement):
             max(8, int(1.3 * rows_cell * float(share))), minimum=8
         )
 
-        obj_feat = self._build_obj_feat(
-            keys, n_pad, node_order, cur_idx, move_cost, move_w
-        )
+        with stage("solve.features"):
+            obj_feat = self._build_obj_feat(
+                keys, n_pad, node_order, cur_idx, move_cost, move_w
+            )
         d_feat = obj_feat.shape[1]
         node_feat = np.zeros((d_feat, m), np.float32)
         if node_order:
@@ -1927,9 +1947,10 @@ class JaxObjectPlacement(ObjectPlacement):
         sched = cap_alive > 0.0
 
         def _solve():
-            t0 = time.perf_counter()
             c0 = _compile_seconds()
-            with span("placement_solve", mode=solved_as, n=n):
+            with stage("solve.device") as st, span(
+                "placement_solve", mode=solved_as, n=n
+            ):
                 g_new = None
                 coarse_new = None
                 conv: dict = {}
@@ -1977,8 +1998,8 @@ class JaxObjectPlacement(ObjectPlacement):
                 stale = bool(
                     den > 0.0 and num > self._delta_audit_ratio * den
                 )
-                solve_ms, conv = _conv_timing(conv, t0, c0)
-                return fill, g_new, coarse_new, solve_ms, stale, counts_after, conv
+            conv = _conv_timing(conv, st.ms, c0)
+            return fill, g_new, coarse_new, st.ms, stale, counts_after, conv
 
         fill, g, coarse_g, solve_ms, stale, counts_after, conv = (
             await asyncio.to_thread(_solve)
@@ -1999,37 +2020,37 @@ class JaxObjectPlacement(ObjectPlacement):
                 )
                 return 0
             hist = self._archived_history()
-            t_apply = time.perf_counter()
-            moved = 0
-            planned: list[tuple[str, str, str]] = []
-            for (key, old_idx), new_idx in zip(disp, fill.tolist()):
+            with stage("solve.apply") as st_apply:
+                moved = 0
+                planned: list[tuple[str, str, str]] = []
+                for (key, old_idx), new_idx in zip(disp, fill.tolist()):
+                    if move_sink is not None:
+                        planned.append(
+                            (key, node_order[old_idx], node_order[int(new_idx)])
+                        )
+                    elif self._set_placement(key, int(new_idx)):
+                        moved += 1
                 if move_sink is not None:
-                    planned.append(
-                        (key, node_order[old_idx], node_order[int(new_idx)])
-                    )
-                elif self._set_placement(key, int(new_idx)):
-                    moved += 1
-            if move_sink is not None:
-                moved = len(planned)
-            if g is not None:
-                self._g = g
-                self._g_fp = self._sched_fp()
-            self._recount_loads()
-            self._epoch += 1
-            self._plan = PlanState(
-                g=g if g is not None else plan.g,
-                coarse_g=coarse_g if coarse_g is not None else plan.coarse_g,
-                seat_counts=np.asarray(counts_after, np.int64),
-                epoch=self._epoch,
-                liveness_fp=self._sched_fp(),
-                delta_solves=plan.delta_solves + 1,
-                stale=stale,
-            )
+                    moved = len(planned)
+                if g is not None:
+                    self._g = g
+                    self._g_fp = self._sched_fp()
+                self._recount_loads()
+                self._epoch += 1
+                self._plan = PlanState(
+                    g=g if g is not None else plan.g,
+                    coarse_g=coarse_g if coarse_g is not None else plan.coarse_g,
+                    seat_counts=np.asarray(counts_after, np.int64),
+                    epoch=self._epoch,
+                    liveness_fp=self._sched_fp(),
+                    delta_solves=plan.delta_solves + 1,
+                    stale=stale,
+                )
             self.stats = SolveStats(
                 n_objects=n,
                 n_nodes=len(self._node_order),
                 solve_ms=solve_ms,
-                apply_ms=(time.perf_counter() - t_apply) * 1e3,
+                apply_ms=st_apply.ms,
                 moved=moved,
                 displaced=d,
                 epoch=self._epoch,
@@ -2439,37 +2460,47 @@ class JaxObjectPlacement(ObjectPlacement):
         coordinator — actuates each move as a coordinated handoff whose
         own ``update()`` flips the row. The sink runs OUTSIDE the
         provider lock: handoffs call back into ``update``/``lookup``.
+
+        One call is one ``solve.full`` stage (whichever path it takes) with
+        children ``solve.snapshot``, ``solve.device`` (and in it
+        ``solve.features``) and ``solve.apply``; ``SolveStats.solve_ms`` and
+        ``apply_ms`` are the device and apply stages' own stamps.
         """
+        with stage("solve.full"):
+            return await self._rebalance(mode, move_sink, delta)
+
+    async def _rebalance(self, mode: str | None, move_sink, delta: bool | None) -> int:
         self._place_compile_cache()
         # An explicit mode="auto" resolves exactly like the constructor
         # default (it would otherwise fall through every dispatch check
         # and silently run the greedy branch).
         mode = self._solver_mode() if mode in (None, "auto") else mode
-        async with self._lock:
-            n = len(self._placements)
-            snapshot_epoch = self._epoch
-            self._recount_loads()
-            load, cap, alive = self._node_vectors()
-            node_order = list(self._node_order)  # snapshot for off-lock use
-            no_capacity = self._no_schedulable_capacity_host()
-            plan = self._plan  # immutable snapshot (atomic-swap field)
-            # O(displaced) fast path FIRST: for pure node-departure churn
-            # the displaced keys come straight from _by_node and the O(N)
-            # key/seat snapshot below — the dominant per-event host cost
-            # at directory scale — is skipped entirely.
-            fast = None
-            if delta is not False and n and not no_capacity:
-                fast = self._delta_fast_snapshot(
-                    plan, n, cap, alive, force=(delta is True)
-                )
-            if fast is None and n:
-                keys = list(self._placements.keys())
-                # values() iterates in keys() order (insertion order) and
-                # skips the per-key hash lookup a genexpr would pay — the
-                # snapshot was ~0.35 s/1M objects as a genexpr.
-                cur_idx = np.fromiter(
-                    self._placements.values(), np.int32, count=n
-                )
+        with stage("solve.snapshot"):
+            async with self._lock:
+                n = len(self._placements)
+                snapshot_epoch = self._epoch
+                self._recount_loads()
+                load, cap, alive = self._node_vectors()
+                node_order = list(self._node_order)  # snapshot for off-lock use
+                no_capacity = self._no_schedulable_capacity_host()
+                plan = self._plan  # immutable snapshot (atomic-swap field)
+                # O(displaced) fast path FIRST: for pure node-departure churn
+                # the displaced keys come straight from _by_node and the O(N)
+                # key/seat snapshot below — the dominant per-event host cost
+                # at directory scale — is skipped entirely.
+                fast = None
+                if delta is not False and n and not no_capacity:
+                    fast = self._delta_fast_snapshot(
+                        plan, n, cap, alive, force=(delta is True)
+                    )
+                if fast is None and n:
+                    keys = list(self._placements.keys())
+                    # values() iterates in keys() order (insertion order) and
+                    # skips the per-key hash lookup a genexpr would pay — the
+                    # snapshot was ~0.35 s/1M objects as a genexpr.
+                    cur_idx = np.fromiter(
+                        self._placements.values(), np.int32, count=n
+                    )
         if not n:
             return 0
         if fast is not None:
@@ -2485,8 +2516,6 @@ class JaxObjectPlacement(ObjectPlacement):
             TPU finishes, so running it in a thread keeps lookups/gossip/RPCs
             live — and makes the epoch-discard check below load-bearing.
             Only the snapshots taken under the lock are read here."""
-            t0 = time.perf_counter()
-            c0 = _compile_seconds()
             from ..tracing import span
 
             if no_capacity:
@@ -2498,9 +2527,7 @@ class JaxObjectPlacement(ObjectPlacement):
                 # mode next to its SolveStats entry).
                 solved_as = f"{mode}+no_capacity"
                 with span("placement_solve", mode=solved_as, n=n):
-                    return cur_idx.copy(), None, None, (
-                        time.perf_counter() - t0
-                    ) * 1e3, solved_as, 0, False, {}
+                    return cur_idx.copy(), None, None, solved_as, 0, False, None
             # Per-object move prices (object_costs hook; tracker-measured
             # request rates + snapshot bytes by default). Evaluated in the
             # solver thread — hooks must read only atomically-swapped
@@ -2509,10 +2536,11 @@ class JaxObjectPlacement(ObjectPlacement):
             # load telemetry must never break a rebalance.
             obj_w = None
             if self._object_costs is not None:
-                try:
-                    w = np.asarray(self._object_costs(keys), np.float32)
-                except Exception:  # noqa: BLE001
-                    w = None
+                with stage("solve.features"):
+                    try:
+                        w = np.asarray(self._object_costs(keys), np.float32)
+                    except Exception:  # noqa: BLE001
+                        w = None
                 if w is not None and w.shape == (n,):
                     w = np.clip(np.nan_to_num(w, nan=1.0, posinf=1.0), 0.0, 1e6)
                     if n and float(np.ptp(w)) > 0.0:
@@ -2535,9 +2563,8 @@ class JaxObjectPlacement(ObjectPlacement):
                         out_d = _route_unseatable(
                             out_d, len(node_order), load, alive, cap
                         )
-                        solve_ms, conv = _conv_timing(conv, t0, c0)
                         return (
-                            out_d, g_d, coarse_d, solve_ms,
+                            out_d, g_d, coarse_d,
                             f"{mode}+delta", displaced, stale, conv,
                         )
             # Decide the actual code path up front so traces, profiler
@@ -2580,9 +2607,7 @@ class JaxObjectPlacement(ObjectPlacement):
                 if route_hier
                 else f"{mode}+collapsed" if collapse else mode
             )
-            with span("placement_solve", mode=solved_as, n=n), _profiler_trace(
-                f"rio_tpu.solve.{solved_as}"
-            ):
+            with span("placement_solve", mode=solved_as, n=n):
                 def _repair_exact(assignment_padded):
                     """Exact integer quotas at bucket shape (trace reuse);
                     movers evicted first so repair adds ~zero churn."""
@@ -2840,12 +2865,19 @@ class JaxObjectPlacement(ObjectPlacement):
                         refined, len(node_order), load, alive, cap
                     )
                     solved_as = f"{solved_as}+affinity"
-            solve_ms, conv = _conv_timing(conv, t0, c0)
-            return out, g, coarse_g, solve_ms, solved_as, n, False, conv
+            return out, g, coarse_g, solved_as, n, False, conv
+
+        def _solve_staged() -> tuple:
+            c0 = _compile_seconds()
+            with stage("solve.device") as st:
+                out, g, coarse_g, solved_as, displaced, stale, conv = _solve()
+            # No solve ran without capacity: its record carries no split.
+            conv = {} if conv is None else _conv_timing(conv, st.ms, c0)
+            return out, g, coarse_g, st.ms, solved_as, displaced, stale, conv
 
         (
             assignment, g, coarse_g, solve_ms, solved_as, displaced, stale, conv
-        ) = await asyncio.to_thread(_solve)
+        ) = await asyncio.to_thread(_solve_staged)
 
         async with self._lock:
             if self._epoch != snapshot_epoch:
@@ -2873,69 +2905,69 @@ class JaxObjectPlacement(ObjectPlacement):
             # the dominant host cost of a churn rebalance) into
             # O(movers) — typically the displaced few percent.
             hist = self._archived_history()
-            t_apply = time.perf_counter()
-            mover_pos = np.nonzero(assignment != cur_idx)[0]
-            moved = 0
-            planned: list[tuple[str, str, str]] = []
-            for p in mover_pos.tolist():
+            with stage("solve.apply") as st_apply:
+                mover_pos = np.nonzero(assignment != cur_idx)[0]
+                moved = 0
+                planned: list[tuple[str, str, str]] = []
+                for p in mover_pos.tolist():
+                    if move_sink is not None:
+                        # Plan, don't apply: the row flips when the sink's
+                        # handoff commits (or never, if it aborts — the lazy
+                        # request path and the next churn solve cover it).
+                        planned.append(
+                            (
+                                keys[p],
+                                node_order[int(cur_idx[p])],
+                                node_order[int(assignment[p])],
+                            )
+                        )
+                    elif self._set_placement(keys[p], int(assignment[p])):
+                        moved += 1
                 if move_sink is not None:
-                    # Plan, don't apply: the row flips when the sink's
-                    # handoff commits (or never, if it aborts — the lazy
-                    # request path and the next churn solve cover it).
-                    planned.append(
-                        (
-                            keys[p],
-                            node_order[int(cur_idx[p])],
-                            node_order[int(assignment[p])],
-                        )
-                    )
-                elif self._set_placement(keys[p], int(assignment[p])):
-                    moved += 1
-            if move_sink is not None:
-                moved = len(planned)
-            if g is not None:
-                self._g = g
-                self._g_fp = self._sched_fp()
-            self._recount_loads()
-            self._epoch += 1
-            if not solved_as.endswith("+no_capacity"):
-                # Commit the plan the NEXT churn event deltas against. A
-                # delta that produced no fresh potentials (greedy fill,
-                # hierarchical, empty displaced set) carries the previous
-                # seeds forward; a full solve resets the staleness counter.
-                delta_used = solved_as.endswith("+delta")
-                self._plan = PlanState(
-                    g=(
-                        g
-                        if g is not None
-                        else (plan.g if delta_used and plan is not None else None)
-                    ),
-                    coarse_g=(
-                        coarse_g
-                        if coarse_g is not None
-                        else (
-                            plan.coarse_g
+                    moved = len(planned)
+                if g is not None:
+                    self._g = g
+                    self._g_fp = self._sched_fp()
+                self._recount_loads()
+                self._epoch += 1
+                if not solved_as.endswith("+no_capacity"):
+                    # Commit the plan the NEXT churn event deltas against. A
+                    # delta that produced no fresh potentials (greedy fill,
+                    # hierarchical, empty displaced set) carries the previous
+                    # seeds forward; a full solve resets the staleness counter.
+                    delta_used = solved_as.endswith("+delta")
+                    self._plan = PlanState(
+                        g=(
+                            g
+                            if g is not None
+                            else (plan.g if delta_used and plan is not None else None)
+                        ),
+                        coarse_g=(
+                            coarse_g
+                            if coarse_g is not None
+                            else (
+                                plan.coarse_g
+                                if delta_used and plan is not None
+                                else None
+                            )
+                        ),
+                        seat_counts=np.bincount(
+                            assignment, minlength=self._node_axis
+                        ),
+                        epoch=self._epoch,
+                        liveness_fp=self._sched_fp(),
+                        delta_solves=(
+                            plan.delta_solves + 1
                             if delta_used and plan is not None
-                            else None
-                        )
-                    ),
-                    seat_counts=np.bincount(
-                        assignment, minlength=self._node_axis
-                    ),
-                    epoch=self._epoch,
-                    liveness_fp=self._sched_fp(),
-                    delta_solves=(
-                        plan.delta_solves + 1
-                        if delta_used and plan is not None
-                        else 0
-                    ),
-                    stale=stale,
-                )
+                            else 0
+                        ),
+                        stale=stale,
+                    )
             self.stats = SolveStats(
                 n_objects=n,
                 n_nodes=len(self._node_order),
                 solve_ms=solve_ms,
-                apply_ms=(time.perf_counter() - t_apply) * 1e3,
+                apply_ms=st_apply.ms,
                 moved=moved,
                 displaced=displaced,
                 epoch=self._epoch,

@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
-from .tracing import Span
+from .tracing import Span, stage_gauges
 
 
 def stats_gauges(**sources: Any) -> dict[str, float]:
@@ -82,6 +82,12 @@ def server_gauges(server: Any) -> dict[str, float]:
         placement_solve=getattr(placement, "stats", None),
         load=getattr(monitor, "stats", None),
     )
+    stall_gauges = getattr(monitor, "stall_gauges", None)
+    if stall_gauges is not None:
+        gauges.update(stall_gauges())
+    # Coarse host stages of this PROCESS (rio.stage.<name>.count/total_ms/
+    # max_ms): directory batch calls, solves, full collections.
+    gauges.update(stage_gauges())
     registry = getattr(server, "registry", None)
     if registry is not None:
         gauges["rio.registry.objects"] = float(registry.count_objects())

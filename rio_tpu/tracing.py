@@ -6,14 +6,24 @@ exported app-side via OpenTelemetry (observability example). Here: a
 zero-dependency span API that records name, duration, and key/values; sinks
 are pluggable (logging sink provided; an OTLP sink can be registered by the
 application the same way the reference wires ``tracing_subscriber``).
+
+Beside the per-request spans lives the **stage log** (:class:`stage`): one
+always-on, bounded, process-wide record of COARSE host stages — a batch
+call, a solve, a full garbage collection — never a request and never a
+key. It is what tiles a directory call's wall time from inside the program.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import gc
+import itertools
 import logging
 import os
 import random
+import sys
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -197,3 +207,185 @@ def span(name: str, **attrs: Any):
     if not _ENABLED:
         return _NULL_SPAN
     return _LiveSpan(name, attrs)
+
+
+# ---------------------------------------------------------------------------
+# The stage log: coarse host stages, always on, bounded by construction
+# ---------------------------------------------------------------------------
+
+#: Records kept. A stage is entered per batch call, per solve or per full
+#: collection (some tens a second at most), so the ring holds minutes.
+STAGE_LOG_SIZE = 4096
+
+# (name, t0_ns, t1_ns, parent, call_id, thread_id): ``time.perf_counter_ns``
+# stamps; ``parent`` is the enclosing stage's name (None at a root);
+# ``call_id`` is shared by every stage under one root (0: none, a collection).
+_STAGE_LOG: collections.deque = collections.deque(maxlen=STAGE_LOG_SIZE)
+# name -> [count, total_ns, max_ns], the operator's view (rio.stage.*).
+_STAGE_TOTALS: dict[str, list[int]] = {}
+_STAGE_LOCK = threading.Lock()
+_STAGE_CTX: contextvars.ContextVar[tuple[str | None, int]] = contextvars.ContextVar(
+    "rio_tpu_stage", default=(None, 0)
+)
+_CALL_IDS = itertools.count(1)
+
+
+def _annotation(name: str):
+    """A profiler annotation ``rio_tpu.<name>``, entered; None off-JAX.
+
+    Only a process that already imported jax gets one: this module sits on
+    the request path's imports and must never pull the accelerator stack in.
+    """
+    # (getattr: a collection may run while ``import jax`` is under way.)
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation("rio_tpu." + name)
+    ann.__enter__()
+    return ann
+
+
+def _log_stage(name: str, t0: int, t1: int, parent: str | None, call_id: int) -> None:
+    _STAGE_LOG.append((name, t0, t1, parent, call_id, threading.get_ident()))
+    dur = t1 - t0
+    with _STAGE_LOCK:
+        row = _STAGE_TOTALS.get(name)
+        if row is None:
+            row = _STAGE_TOTALS[name] = [0, 0, 0]
+        row[0] += 1
+        row[1] += dur
+        if dur > row[2]:
+            row[2] = dur
+
+
+class stage:
+    """Time one coarse host stage: ``with stage("place.apply"): ...``.
+
+    For work done per batch call, per solve, per full collection — NEVER per
+    request or per key: every exit appends one record to the process-wide
+    bounded log (:func:`stage_log`) and adds to the per-name totals that
+    :func:`rio_tpu.otel.server_gauges` exports as ``rio.stage.<name>.*``.
+    The stage is also a ``jax.profiler.TraceAnnotation("rio_tpu.<name>")``,
+    so a profiler session shows it on the thread that ran it. Nesting and
+    ``asyncio.to_thread`` carry the enclosing stage and the call id along
+    (a contextvar). There is no switch: the cost is bounded by what may be
+    a stage.
+    """
+
+    __slots__ = ("name", "t0", "t1", "_parent", "_call", "_token", "_ann")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.t0 = self.t1 = 0
+
+    def __enter__(self) -> "stage":
+        parent, call = _STAGE_CTX.get()
+        self._parent = parent
+        self._call = call = call or next(_CALL_IDS)
+        self._token = _STAGE_CTX.set((self.name, call))
+        self._ann = _annotation(self.name)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _STAGE_CTX.reset(self._token)
+        _log_stage(self.name, self.t0, self.t1, self._parent, self._call)
+        return False
+
+    @property
+    def ms(self) -> float:
+        """Duration in milliseconds (after exit), from the logged stamps."""
+        return (self.t1 - self.t0) / 1e6
+
+
+def stage_since(name: str, t0_ns: int) -> None:
+    """Log a stage that began at ``t0_ns`` (another stage's ``t1``) and ends
+    now, under the current stage: a wait whose start was stamped elsewhere
+    (a worker thread's last instant) and so cannot be a ``with`` block."""
+    parent, call = _STAGE_CTX.get()
+    _log_stage(name, t0_ns, time.perf_counter_ns(), parent, call)
+
+
+def stage_log() -> list[tuple]:
+    """A snapshot of the log, oldest first."""
+    return list(_STAGE_LOG)
+
+
+def stage_totals() -> dict[str, tuple[int, int, int]]:
+    """``name -> (count, total_ns, max_ns)`` since the process started."""
+    with _STAGE_LOCK:
+        return {k: (v[0], v[1], v[2]) for k, v in _STAGE_TOTALS.items() if v[0]}
+
+
+def stage_gauges() -> dict[str, float]:
+    """The totals in the :func:`rio_tpu.otel.stats_gauges` shape."""
+    out: dict[str, float] = {}
+    for name, (count, total_ns, max_ns) in stage_totals().items():
+        p = f"rio.stage.{name}"
+        out[f"{p}.count"] = float(count)
+        out[f"{p}.total_ms"] = total_ns / 1e6
+        out[f"{p}.max_ms"] = max_ns / 1e6
+    return out
+
+
+def clear_stages() -> None:
+    """Forget every record and total (tests)."""
+    with _STAGE_LOCK:
+        _STAGE_LOG.clear()
+        for row in _STAGE_TOTALS.values():
+            row[:] = [0, 0, 0]
+
+
+# The interpreter's collections ride the same log: a full one (generation 2)
+# walks every container the process holds and stops every thread, so it is a
+# stage, ``gc.gen2``; the young generations only add to their totals. One
+# ``gc.callbacks`` entry per process, held while any LoadMonitor runs. The
+# rows are seated here so that the callback, which runs at any allocation,
+# inserts nothing and takes no lock (collections do not nest: one writer).
+_GC_ROWS = [_STAGE_TOTALS.setdefault(f"gc.gen{g}", [0, 0, 0]) for g in range(3)]
+_GC_OPEN: list = [0, None]  # start stamp, the full collection's annotation
+_GC_WATCHERS = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    gen = info["generation"]
+    if phase == "start":
+        if gen == 2:
+            _GC_OPEN[1] = _annotation("gc.gen2")
+        _GC_OPEN[0] = time.perf_counter_ns()
+        return
+    t1 = time.perf_counter_ns()
+    t0 = _GC_OPEN[0]
+    if gen == 2:
+        ann, _GC_OPEN[1] = _GC_OPEN[1], None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _STAGE_LOG.append(("gc.gen2", t0, t1, None, 0, threading.get_ident()))
+    row = _GC_ROWS[gen]
+    row[0] += 1
+    row[1] += t1 - t0
+    if t1 - t0 > row[2]:
+        row[2] = t1 - t0
+
+
+def watch_gc() -> None:
+    """Install the collection callback (the first caller does; counted)."""
+    global _GC_WATCHERS
+    with _STAGE_LOCK:
+        _GC_WATCHERS += 1
+        if _GC_WATCHERS == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc() -> None:
+    """Undo one :func:`watch_gc`; the last caller removes the callback."""
+    global _GC_WATCHERS
+    with _STAGE_LOCK:
+        if _GC_WATCHERS == 0:
+            return
+        _GC_WATCHERS -= 1
+        if _GC_WATCHERS == 0 and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
