@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import gc
 import logging
 import os
 import threading
@@ -922,7 +923,18 @@ class JaxObjectPlacement(ObjectPlacement):
         # Per-node key index (node index -> keys): keeps clean_server and
         # load recounts O(objects-on-node), the same reason the Redis
         # backend keeps a per-server set (object_placement/redis.py).
-        self._by_node: dict[int, set[str]] = {}
+        # The inner containers are dict[str, None], NOT sets: a set is
+        # always tracked by the cycle collector, so every full collection
+        # visited every key of the directory twice (~40 ms per million
+        # rows on the v5e host, PERF.md PR 26). A dict that holds only str
+        # and None stays untracked on CPython <= 3.13, as ``_placements``
+        # does; ``place_gauges`` reports the rows an interpreter that
+        # tracks every dict would walk again. Written only through
+        # ``_set_placement`` / ``_drop_placement`` / ``_seat_new``.
+        self._by_node: dict[int, dict[str, None]] = {}
+        # What went in through the bulk seam (``place_gauges``).
+        self._bulk_rows = 0
+        self._bulk_chunks = 0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1042,16 +1054,52 @@ class JaxObjectPlacement(ObjectPlacement):
         if old == idx:
             return False
         if old is not None:
-            self._by_node.get(old, set()).discard(key)
+            self._by_node.get(old, {}).pop(key, None)
         self._placements[key] = idx
-        self._by_node.setdefault(idx, set()).add(key)
+        self._by_node.setdefault(idx, {})[key] = None
         return True
 
     def _drop_placement(self, key: str) -> int | None:
         idx = self._placements.pop(key, None)
         if idx is not None:
-            self._by_node.get(idx, set()).discard(key)
+            self._by_node.get(idx, {}).pop(key, None)
         return idx
+
+    def _seat_new(self, keys: list[str], idx: np.ndarray) -> np.ndarray:
+        """Seat ``keys`` at node indices ``idx`` in bulk (lock held): the
+        twin of ``_set_placement`` for keys that have NO seat, each
+        occurring once (``assign_batch`` establishes both under the lock).
+        One write per container instead of one call per key; a key that
+        moves goes through ``_set_placement``. Returns the seats taken per
+        node index.
+        """
+        self._placements.update(zip(keys, idx.tolist()))
+        counts = np.bincount(idx, minlength=len(self._node_order))
+        # Stable: a node's keys enter its index in the chunk's order, as
+        # the per-key seam would have entered them.
+        grouped = np.array(keys, dtype=object)[np.argsort(idx, kind="stable")]
+        start = 0
+        for j in np.flatnonzero(counts).tolist():
+            end = start + int(counts[j])
+            self._by_node.setdefault(j, {}).update(
+                dict.fromkeys(grouped[start:end].tolist())
+            )
+            start = end
+        self._bulk_rows += len(keys)
+        self._bulk_chunks += 1
+        return counts
+
+    def place_gauges(self) -> dict[str, float]:
+        """``rio.place.*`` for ``otel.server_gauges``, made at scrape time
+        in O(nodes). ``index_tracked_rows`` reads 0 while no per-key entry
+        of the mirror sits in a container the collector walks."""
+        return {
+            "rio.place.bulk_rows": float(self._bulk_rows),
+            "rio.place.bulk_chunks": float(self._bulk_chunks),
+            "rio.place.index_tracked_rows": float(
+                sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
+            ),
+        }
 
     def _set_standby_row(self, key: str, addresses: list[str], epoch: int) -> None:
         """Single mutation seam for replica rows (lock held) — like
@@ -1415,16 +1463,20 @@ class JaxObjectPlacement(ObjectPlacement):
         with stage("place.assign"):
             with stage("place.keys"):
                 keys = [str(o) for o in object_ids]
-            for start in range(0, len(keys), self._MAX_PLACE_CHUNK):
+                # A key given twice reaches the solve once: solved twice it
+                # was moved by its second row while its first node kept a
+                # load of +1 it did not hold.
+                uniq = list(dict.fromkeys(keys))
+            for start in range(0, len(uniq), self._MAX_PLACE_CHUNK):
                 async with self._lock_staged():
                     with stage("place.filter"):
-                        chunk = keys[start : start + self._MAX_PLACE_CHUNK]
+                        chunk = uniq[start : start + self._MAX_PLACE_CHUNK]
                         unplaced = [k for k in chunk if k not in self._placements]
                     if unplaced:
                         await self._place_chunk_locked(unplaced)
             async with self._lock_staged():
                 with stage("place.filter"):
-                    missing = [k for k in keys if k not in self._placements]
+                    missing = [k for k in uniq if k not in self._placements]
                 if missing:
                     await self._place_keys_async(missing)
                 with stage("place.resolve"):
@@ -1514,9 +1566,9 @@ class JaxObjectPlacement(ObjectPlacement):
             return _route_unseatable(seats, n_real, load, alive, cap)
 
     def _apply_chunk(self, keys: list[str], assignment: np.ndarray) -> None:
-        for k, idx in zip(keys, assignment.tolist()):
-            self._set_placement(k, int(idx))
-            self._nodes[self._node_order[idx]].load += 1.0
+        counts = self._seat_new(keys, assignment)
+        for j in np.flatnonzero(counts).tolist():
+            self._nodes[self._node_order[j]].load += float(counts[j])
         self._epoch += 1
 
     def _build_obj_feat(

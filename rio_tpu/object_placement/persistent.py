@@ -94,9 +94,10 @@ class PersistentJaxObjectPlacement(JaxObjectPlacement):
                  len(items), type(self._backing).__name__)
 
     # ------------------------------------------------------- dirty tracking
-    # Every mirror mutation in the base class flows through these two
-    # methods (allocation apply, rebalance mover loop, update, remove,
-    # clean_server), so overriding them catches the full write set.
+    # Every mirror mutation in the base class flows through these three
+    # methods (allocation apply in bulk through ``_seat_new``; rebalance
+    # mover loop, update, remove, clean_server per key), so overriding
+    # them catches the full write set.
     def _set_placement(self, key: str, idx: int) -> bool:
         changed = super()._set_placement(key, idx)
         if changed and not self._restoring:
@@ -108,6 +109,15 @@ class PersistentJaxObjectPlacement(JaxObjectPlacement):
         if idx is not None and not self._restoring:
             self._mark(key, None)
         return idx
+
+    def _seat_new(self, keys, idx):
+        counts = super()._seat_new(keys, idx)
+        if keys and not self._restoring:
+            self._dirty.update(
+                zip(keys, map(self._node_order.__getitem__, idx.tolist()))
+            )
+            self._wake_flusher()
+        return counts
 
     def _set_standby_row(self, key: str, addresses: list[str], epoch: int) -> None:
         super()._set_standby_row(key, addresses, epoch)

@@ -88,6 +88,11 @@ def server_gauges(server: Any) -> dict[str, float]:
     # Coarse host stages of this PROCESS (rio.stage.<name>.count/total_ms/
     # max_ms): directory batch calls, solves, full collections.
     gauges.update(stage_gauges())
+    place_gauges = getattr(placement, "place_gauges", None)
+    if place_gauges is not None:
+        # The device-solved directory's host mirror (rio.place.*): rows and
+        # chunks seated in bulk, rows of its per-node index the collector walks.
+        gauges.update(place_gauges())
     registry = getattr(server, "registry", None)
     if registry is not None:
         gauges["rio.registry.objects"] = float(registry.count_objects())

@@ -5,12 +5,15 @@ Trait semantics mirror the reference backend matrix
 are rio-tpu additions.
 """
 
+import gc
+
 import numpy as np
 import pytest
 
 from rio_tpu import ObjectId, ObjectPlacementItem
 from rio_tpu.errors import NoSchedulableCapacity
 from rio_tpu.object_placement.jax_placement import JaxObjectPlacement
+from tests.test_soak_random_ops import _check_invariants
 
 
 def _provider(nodes=4, **kw):
@@ -1043,3 +1046,137 @@ async def test_flat_rebalance_at_scale_composes_with_mesh(monkeypatch):
     assert moved >= 0
     addrs = [await p.lookup(i) for i in ids]
     assert all(a in members for a in addrs)
+
+
+# ---------------------------------------------------------------------------
+# The host mirror: nothing per key where the collector walks, one bulk seam
+# ---------------------------------------------------------------------------
+
+
+def _tracked_entries(p) -> int:
+    """Entries of every collector-tracked container reachable from
+    ``vars(p)`` through containers and rio_tpu's own objects."""
+    seen, total, todo = set(), 0, list(vars(p).values())
+    while todo:
+        o = todo.pop()
+        if id(o) in seen:
+            continue
+        seen.add(id(o))
+        if isinstance(o, (dict, list, tuple, set, frozenset)):
+            if gc.is_tracked(o):
+                total += len(o)
+            todo.extend(o.values() if isinstance(o, dict) else o)
+        elif type(o).__module__.startswith("rio_tpu") and hasattr(o, "__dict__"):
+            todo.extend(vars(o).values())
+    return total
+
+
+async def _moves_by_rebalance(p, ids):
+    p.sync_members([f"10.0.0.{i}:5000" for i in range(2, 8)])
+    assert await p.rebalance() > 0
+
+
+async def _remove_and_clean_server(p, ids):
+    for oid in ids[:50]:
+        await p.remove(oid)
+    await p.clean_server("10.0.0.3:5000")
+    await p.assign_batch(ids[:25])  # a second bulk chunk into the holes
+
+
+async def _update_and_promote(p, ids):
+    await p.update(ObjectPlacementItem(ids[0], "10.0.0.5:5000"))
+    await p.update(ObjectPlacementItem(ids[0], "10.0.0.6:5000"))
+    epoch = await p.set_standbys(ids[1], ["10.0.0.7:5000"])
+    assert await p.promote_standby(ids[1], "10.0.0.7:5000", epoch) == epoch + 1
+
+
+async def _nothing_more(p, ids):
+    pass
+
+
+@pytest.mark.parametrize(
+    "then, bulk_rows, bulk_chunks",
+    [
+        (_nothing_more, 4096, 1),
+        (_moves_by_rebalance, 4096, 1),
+        (_remove_and_clean_server, 4096 + 25, 2),
+        (_update_and_promote, 4096, 1),
+    ],
+    ids=["bulk_assign", "rebalance_moves", "remove_clean_server", "update_promote"],
+)
+async def test_mirror_keeps_no_per_key_entry_where_the_collector_walks(
+    then, bulk_rows, bulk_chunks
+):
+    """The contract of the per-node index: after any sequence of writers the
+    only collector-tracked containers the provider holds are O(nodes) long.
+    Never skipped: an interpreter that tracks every dict fails it, and the
+    index then has to become arrays (``_by_node``'s comment)."""
+    from types import SimpleNamespace
+
+    from rio_tpu.otel import server_gauges
+
+    nodes = 8
+    p = _provider(nodes=nodes, mode="greedy")
+    ids = [ObjectId("Presence", str(i)) for i in range(4096)]
+    await p.assign_batch(ids)
+    await then(p, ids)
+    _check_invariants(p)
+    assert not gc.is_tracked(p._placements)
+    assert p._by_node and not any(gc.is_tracked(c) for c in p._by_node.values())
+    # The node table, the outer index, the stats' history: nothing of N.
+    assert _tracked_entries(p) <= 16 * nodes, _tracked_entries(p)
+    gauges = server_gauges(SimpleNamespace(object_placement=p))
+    assert gauges["rio.place.index_tracked_rows"] == 0
+    assert gauges["rio.place.bulk_rows"] == bulk_rows
+    assert gauges["rio.place.bulk_chunks"] == bulk_chunks
+    # The gauge is the alarm: a container the collector walks reads as rows.
+    j = max(p._by_node, key=lambda j: len(p._by_node[j]))
+    p._by_node[j] = set(p._by_node[j])
+    assert p.place_gauges()["rio.place.index_tracked_rows"] == len(p._by_node[j]) > 0
+
+
+def _seat_per_key(p, keys, assignment):
+    """``_apply_chunk`` as it was: the per-key seam, one call a key."""
+    for k, idx in zip(keys, assignment.tolist()):
+        p._set_placement(k, int(idx))
+        p._nodes[p._node_order[idx]].load += 1.0
+    p._epoch += 1
+
+
+@pytest.mark.parametrize("n", [0, 1, 160, 5000])
+async def test_bulk_seam_seats_what_the_per_key_seam_seats(n):
+    nodes = 8
+    bulk, per_key = _provider(nodes=nodes), _provider(nodes=nodes)
+    rng = np.random.default_rng(n)
+    seated = [f"T.old{i}" for i in range(300)]
+    keys = [f"T.{i}" for i in range(n)]
+    for p in (bulk, per_key):  # a mirror that already holds rows
+        _seat_per_key(p, seated, np.arange(300, dtype=np.int32) % nodes)
+    assignment = rng.integers(0, nodes - 1, n).astype(np.int32)  # node 7 takes none
+    bulk._apply_chunk(keys, assignment)
+    _seat_per_key(per_key, keys, assignment)
+    assert list(bulk._placements.items()) == list(per_key._placements.items())
+    assert {j: list(c) for j, c in bulk._by_node.items()} == {
+        j: list(c) for j, c in per_key._by_node.items()
+    }
+    assert [s.load for s in bulk._nodes.values()] == [s.load for s in per_key._nodes.values()]
+    assert bulk._epoch == per_key._epoch
+    _check_invariants(bulk)
+    assert bulk.place_gauges()["rio.place.bulk_rows"] == n
+
+
+async def test_a_key_given_twice_in_a_batch_is_solved_and_counted_once():
+    """It was solved twice, moved by its second row, and its first node
+    kept a load of +1 it did not hold."""
+    p = _provider(nodes=4, mode="greedy")
+    names = [str(i % 100) for i in range(250)]  # every id 2 or 3 times
+    ids = [ObjectId("Game", n) for n in names]
+    addrs = await p.assign_batch(ids)
+    assert len(addrs) == 250 and p.count() == 100
+    assert len({(n, a) for n, a in zip(names, addrs)}) == 100
+    assert addrs == await p.lookup_batch(ids)
+    _check_invariants(p)
+    loads = {s.index: s.load for s in p._nodes.values()}
+    assert loads == {j: float(len(p._by_node.get(j, ()))) for j in loads}
+    assert sum(loads.values()) == 100.0
+    assert p.place_gauges()["rio.place.bulk_rows"] == 100

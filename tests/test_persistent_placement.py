@@ -218,3 +218,31 @@ async def test_promotion_after_cold_restart_keeps_surviving_standbys(tmp_path):
     await _settled_flush(p2)
     assert await p2._backing.standbys(oid) == (["10.9.0.2:5000"], 1)
     await p2.aclose()
+
+
+async def test_bulk_seats_are_written_behind_and_none_while_restoring(tmp_path):
+    """``assign_batch`` seats a chunk through the bulk seam (``_seat_new``):
+    the subclass must mark every key of it, and none during a restore."""
+    import numpy as np
+
+    backing = SqliteObjectPlacement(str(tmp_path / "dir.db"))
+    p = _provider(backing)
+    await p.prepare()
+    ids = [ObjectId("Bulk", str(i)) for i in range(300)]
+    addrs = await p.assign_batch(ids)
+    assert p._dirty == {str(o): a for o, a in zip(ids, addrs)}
+    assert p.place_gauges()["rio.place.bulk_rows"] == 300
+    await _settled_flush(p)
+    assert p._dirty == {}
+    stored = {str(i.object_id): i.server_address for i in await backing.items()}
+    assert stored == {str(o): a for o, a in zip(ids, addrs)}
+
+    p._restoring = True
+    try:
+        p._seat_new(["Bulk.r0", "Bulk.r1"], np.array([0, 1], np.int32))
+    finally:
+        p._restoring = False
+    assert p._dirty == {} and p.count() == 302
+    p._seat_new([], np.array([], np.int32))  # an empty chunk wakes nobody
+    assert p._dirty == {}
+    await p.aclose()
