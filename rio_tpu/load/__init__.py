@@ -90,8 +90,10 @@ def _finite(value: Any, default: float = 0.0, lo: float = 0.0,
 class LoadVector:
     """One node's compact load sample (what rides the heartbeat row)."""
 
-    loop_lag_ms: float = 0.0  # event-loop scheduling lag, EMA
-    inflight: float = 0.0  # requests currently being served
+    # Event-loop scheduling lag that LASTS: the level most of the node's
+    # recent ticks reached (``sustained``), not a smoothed point sample.
+    loop_lag_ms: float = 0.0
+    inflight: float = 0.0  # requests being served, sustained the same way
     registry_objects: float = 0.0  # live activations on this node
     req_rate: float = 0.0  # served requests/sec, EMA
     state_bytes: float = 0.0  # migration volatile bytes moved (cumulative)
@@ -214,12 +216,14 @@ class ClusterLoadView:
         now = time.time() if now is None else now
         entries: dict[str, ClusterLoadEntry] = {}
         for m in members:
+            raw = getattr(m, "load", None)
+            if not raw:
+                continue  # reports no load (most rows of a large cluster)
             addr = getattr(m, "address", None)
             if callable(addr):
                 addr = addr()
             if not addr:
                 continue
-            raw = getattr(m, "load", None)
             vec = raw if isinstance(raw, LoadVector) else LoadVector.decode(raw)
             if vec is None:
                 continue
@@ -326,6 +330,23 @@ class LoadThresholds:
 #: Raw per-tick lag samples a monitor keeps (17 minutes at the default tick).
 LAG_SAMPLES = 1024
 
+#: The sustained estimators look back over this many ticks and report the
+#: level that all but ``SUSTAIN_MISSES`` of them reached. A loop saturated by
+#: its own requests wakes every tick late, so the level rises within
+#: ``SUSTAIN_TICKS - SUSTAIN_MISSES`` ticks; one hold of the loop (a solve's
+#: host apply, a hand-off burst, a full collection, the machine) makes one
+#: tick late however long it lasts, and moves nothing.
+SUSTAIN_TICKS = 8
+SUSTAIN_MISSES = 3
+
+
+def sustained(recent) -> float:
+    """The level that all but :data:`SUSTAIN_MISSES` of ``recent`` (the last
+    :data:`SUSTAIN_TICKS` samples at most) reached; ticks not taken yet read 0."""
+    recent = sorted(recent)
+    missing = SUSTAIN_TICKS - len(recent)
+    return 0.0 if missing > SUSTAIN_MISSES else float(recent[SUSTAIN_MISSES - missing])
+
 
 @dataclasses.dataclass
 class LoadMonitorStats:
@@ -334,7 +355,11 @@ class LoadMonitorStats:
     samples: int = 0
     sheds: int = 0  # requests refused with ServerBusy
     stalls: int = 0  # loop stalls caught with a stack by the watchdog
-    loop_lag_ms: float = 0.0
+    loop_lag_ms: float = 0.0  # EMA of the ticks' lag: what shedding reads
+    # What the heartbeat publishes and the solver's derate reads: lag and
+    # in-flight depth that last (``sustained`` over the ticks' raw samples).
+    loop_lag_sustained_ms: float = 0.0
+    inflight_sustained: float = 0.0
     # Each tick's raw lag as ``(time.perf_counter_ns() at the tick, lag_ms)``:
     # the EMA above hides the tail, this keeps it (bounded; one append a
     # tick). The loop was late from ``t - lag`` to ``t``.
@@ -391,9 +416,13 @@ class _StallWatchdog(threading.Thread):
             if frame is None:
                 continue
             last_fire = now
+            stack = "".join(traceback.format_stack(frame, limit=24))
+            # A frame keeps every local of its call chain alive: held until
+            # the next capture it would pin whatever the stalled call held.
+            del frame
             m._pending_stall = {
                 "stall_ms": round(stall_s * 1e3, 1),
-                "stack": "".join(traceback.format_stack(frame, limit=24)),
+                "stack": stack,
             }
 
 
@@ -441,6 +470,9 @@ class LoadMonitor:
         self.inflight = 0
         self.requests_total = 0
         self._rate_marker = 0  # requests_total at the previous sample
+        # The last ticks' lag and depth, what the sustained levels are made of.
+        self._lag_ticks: collections.deque = collections.deque(maxlen=SUSTAIN_TICKS)
+        self._inflight_ticks: collections.deque = collections.deque(maxlen=SUSTAIN_TICKS)
         self._last_sample: float | None = None
         self.cluster_view: ClusterLoadView | None = None
         # Optional read-scale hook: an object exposing ``hotness_tick()``
@@ -502,10 +534,14 @@ class LoadMonitor:
         s = self.stats
         now = time.monotonic()
         s.samples += 1
-        s.loop_lag_ms = (1 - self._lag_ema) * s.loop_lag_ms + self._lag_ema * max(
-            0.0, lag_ms
-        )
+        lag_ms = max(0.0, lag_ms)
+        s.lag_samples.append((time.perf_counter_ns(), lag_ms))
+        self._lag_ticks.append(lag_ms)
+        s.loop_lag_sustained_ms = sustained(self._lag_ticks)
+        s.loop_lag_ms = (1 - self._lag_ema) * s.loop_lag_ms + self._lag_ema * lag_ms
         s.inflight = self.inflight
+        self._inflight_ticks.append(self.inflight)
+        s.inflight_sustained = sustained(self._inflight_ticks)
         # Aggregate rate from the monitor's own counter (present on every
         # server); the tracker's per-object window additionally feeds the
         # solver's move weights when the provider carries one.
@@ -535,8 +571,8 @@ class LoadMonitor:
             qs = qos.stats
             qos_interactive = float(qs.interactive_sheds + qs.deadline_drops)
         return LoadVector(
-            loop_lag_ms=s.loop_lag_ms,
-            inflight=float(self.inflight),
+            loop_lag_ms=s.loop_lag_sustained_ms,
+            inflight=s.inflight_sustained,
             registry_objects=float(s.registry_objects),
             req_rate=s.req_rate,
             state_bytes=s.state_bytes,
@@ -625,7 +661,6 @@ class LoadMonitor:
             # Scheduling drift across our own sleep IS event-loop lag: a
             # loop starved by slow callbacks wakes us late by that much.
             lag_ms = max(0.0, (loop.time() - t0 - self.interval)) * 1e3
-            self.stats.lag_samples.append((time.perf_counter_ns(), lag_ms))
             self._sample(lag_ms)
             self._heartbeat = time.monotonic()
             self._drain_pending_stall()

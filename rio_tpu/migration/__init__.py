@@ -36,6 +36,9 @@ delta, not the state copy):
   :class:`MigrateBatch` per pair (chunked at
   :attr:`MigrationConfig.batch_size`), amortizing framing and dispatch
   over many keys; the transport's write-cork batches the state payloads.
+  A source whose share of the plan is many SMALL bursts (a re-priced node
+  sheds a row each to a hundred targets) gets them in one
+  :class:`MigrateSpread` call, not a call a target.
 * **Target-initiated prefetch** — before any pin, the coordinator asks the
   *target* (:class:`PrefetchPull`) to pull volatile snapshots straight
   from the source's inbox (:class:`FetchStates`, served under each
@@ -74,11 +77,13 @@ from __future__ import annotations
 import asyncio
 import logging
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any
 
-from .. import codec
+from .. import codec, tracing
 from ..app_data import AppData
+from ..client import Client
 from ..cluster.storage import MembershipStorage
 from ..errors import ObjectNotFound
 from ..journal import (
@@ -96,6 +101,7 @@ from ..protocol import ResponseError
 from ..registry import ObjectId, Registry, handler, message, type_id, type_name
 from ..reminders.daemon import SHARD_TYPE
 from ..service_object import ServiceObject
+from ..utils import ExponentialBackoff
 
 log = logging.getLogger("rio_tpu.migration")
 
@@ -107,6 +113,7 @@ __all__ = [
     "MigrateBatch",
     "MigrateBatchAck",
     "MigrateObject",
+    "MigrateSpread",
     "MigrationAck",
     "MigrationConfig",
     "MigrationControl",
@@ -133,6 +140,8 @@ FENCE_TTL = 300.0
 #: inside the target's stash TTL — past this, install fresh rather than
 #: trust a stash entry the target may be about to prune.
 _PREFETCH_HIT_MAX_AGE = 30.0
+#: Bare directory flips ``apply_moves`` runs between two turns of the loop.
+_FLIPS_PER_TURN = 256
 
 
 @dataclass
@@ -169,6 +178,12 @@ class MigrationStats:
     pinned_le_10ms: int = 0
     pinned_le_100ms: int = 0
     pinned_gt_100ms: int = 0
+    # Coordinator role (``apply_moves`` plans actuated from this node):
+    plans: int = 0
+    plan_bursts: int = 0  # (source, target) bursts those plans shipped
+    plan_burst_keys: int = 0  # keys those bursts carried
+    plan_flips: int = 0  # bare directory flips (dead source, seat rows)
+    plan_burst_ms_max: float = 0.0  # the slowest burst of any plan
 
 
 @message(name="rio.MigrateObject")
@@ -186,6 +201,14 @@ class MigrateBatch:
 
     target: str = ""
     items: list = field(default_factory=list)  # [type_name, object_id] pairs
+
+
+@message(name="rio.MigrateSpread")
+class MigrateSpread:
+    """One source's bursts to SEVERAL targets, in one RPC: what a plan that
+    takes a few rows from a node for each of many targets is made of."""
+
+    bursts: list = field(default_factory=list)  # [target, [[type_name, object_id], ...]]
 
 
 @message(name="rio.MigrateBatchAck")
@@ -265,6 +288,86 @@ class ReplicaAck:
     detail: str = ""
 
 
+class _Lane:
+    """The sockets every migration client of one process (on one event
+    loop) keeps to the cluster's nodes for one kind of call: bundles by
+    address, shared, so that N co-located managers hold one bundle to a node
+    and not N."""
+
+    __slots__ = ("conns", "users")
+
+    def __init__(self) -> None:
+        self.conns: dict = {}  # address -> client._ServerConns
+        self.users = 0
+
+    def open_connections(self) -> int:
+        return sum(
+            1 for pool in self.conns.values() for c in pool.conns if not c.closed
+        )
+
+
+# loop -> {"control" | "inbox": _Lane}. Two lanes, never one: a pipelined
+# socket answers in order, so an inbox call queued behind a long control
+# call on a shared socket would wait for it, and control calls wait for
+# inbox calls: MigrateBatch@S -> InstallState@T (behind PrefetchPull@T) ->
+# FetchStates@S (behind MigrateBatch@S). Inbox handlers make no call of
+# their own, so the inbox lane always drains, and control calls wait for
+# nothing else.
+_LANES: "weakref.WeakKeyDictionary[Any, dict[str, _Lane]]" = weakref.WeakKeyDictionary()
+
+#: Sockets per (address, lane). Control calls to one node are bounded by
+#: ``per_node_inflight``; 32 more pipeline on each socket.
+_LANE_SOCKETS = 2
+#: Re-tries of a lane call (microseconds of back-off in all).
+_LANE_RETRIES = 4
+
+
+def _lanes() -> dict[str, _Lane]:
+    loop = asyncio.get_running_loop()
+    lanes = _LANES.get(loop)
+    if lanes is None:
+        lanes = _LANES[loop] = {"control": _Lane(), "inbox": _Lane()}
+    return lanes
+
+
+def open_connections() -> int:
+    """Open sockets of this process's migration lanes (client ends)."""
+    try:
+        return sum(lane.open_connections() for lane in _lanes().values())
+    except RuntimeError:  # no running loop
+        return 0
+
+
+class _LaneClient(Client):
+    """A ``Client`` whose connection bundles are one lane of the process."""
+
+    def __init__(self, lane: _Lane, members_storage, resolver) -> None:
+        # Both lanes call node-scoped actors: the id IS the node. A node that
+        # does not answer, or that its peers call dead (DEALLOCATE), has left,
+        # and the default twenty re-tries (a second and more, a burst) only
+        # hold the plan; the rows it held are the next solve's.
+        super().__init__(
+            members_storage, placement_resolver=resolver,
+            pool_per_server=_LANE_SOCKETS,
+            backoff=ExponentialBackoff(max_retries=_LANE_RETRIES),
+        )
+        self._lane: _Lane | None = lane
+        lane.users += 1
+        self._conns = lane.conns
+
+    def close(self) -> None:
+        lane, self._lane = self._lane, None
+        if lane is None:
+            return
+        self._conns = {}
+        lane.users -= 1
+        if lane.users <= 0:
+            for pool in lane.conns.values():
+                pool.close()
+            lane.conns.clear()
+        super().close()
+
+
 class MigrationManager:
     """Per-node migration coordinator; injected into AppData by the Server.
 
@@ -305,7 +408,9 @@ class MigrationManager:
         self._served_prefetch: dict[tuple[str, str], tuple[bytes, str, float]] = {}
         self._node_sems: dict[str, asyncio.Semaphore] = {}
         self._global_sem = asyncio.Semaphore(max(1, self.config.global_inflight))
+        # One client per lane (``_LANES``); a caller-supplied client serves both.
         self._client = client
+        self._clients: dict[str, Any] = {}
         # Control-plane flight recorder (None when journaling is off): each
         # handoff phase — pin, snapshot, install, flip, abort — lands one
         # event, carrying the driving request's trace id across nodes.
@@ -486,6 +591,7 @@ class MigrationManager:
                 # Someone re-seated the row mid-handoff; their row wins and
                 # our deactivation degrades to an ordinary cold stop.
                 log.info("migration of %s lost the directory race", object_id)
+            self._fenced.pop(key, None)  # armed again: to the back of the order
             self._fenced[key] = (target, time.monotonic())
             fenced = True
             if not live:
@@ -522,39 +628,53 @@ class MigrationManager:
         A failed key only loses that key (its row stands for the lazy
         re-seat); the burst keeps going.
         """
-        attempted = len(items)
-        if not attempted:
+        if not items:
             return 0, 0
-        if (
-            not target
-            or target == self.address
-            or not await self.members_storage.is_active(target)
-        ):
-            log.warning(
-                "burst of %d keys refused: bad or inactive target %r", attempted, target
-            )
-            return 0, attempted
-        self.stats.batches += 1
-        self.stats.batch_keys += attempted
-        if self._journal is not None:
-            self._journal.record(MIGRATE_BURST, target=target, keys=attempted)
+        return await self.migrate_spread([[target, items]])
+
+    async def migrate_spread(self, bursts: list) -> tuple[int, int]:
+        """Run ``[target, items]`` bursts from this node; ``(done, attempted)``.
+
+        One membership read covers every target (a read a target would copy
+        the table a hundred times for a hundred single-row bursts), and the
+        handoffs of all bursts share one ``handoff_concurrency`` budget."""
+        attempted = sum(len(items) for _, items in bursts)
+        if len(bursts) == 1:
+            active = {t for t, _ in bursts if await self.members_storage.is_active(t)}
+        else:
+            active = {m.address for m in await self.members_storage.active_members()}
+        pairs: list[tuple[str, str, str]] = []
+        for target, items in bursts:
+            if not items:
+                continue
+            if not target or target == self.address or target not in active:
+                log.warning(
+                    "burst of %d keys refused: bad or inactive target %r",
+                    len(items), target,
+                )
+                continue
+            self.stats.batches += 1
+            self.stats.batch_keys += len(items)
+            if self._journal is not None:
+                self._journal.record(MIGRATE_BURST, target=target, keys=len(items))
+            pairs.extend((tname, oid, target) for tname, oid in items)
         sem = asyncio.Semaphore(max(1, self.config.handoff_concurrency))
 
-        async def one(tname: str, oid: str) -> bool:
+        async def one(tname: str, oid: str, target: str) -> bool:
             async with sem:
                 return await self.migrate_out(
                     ObjectId(tname, oid), target, target_checked=True
                 )
 
         results = await asyncio.gather(
-            *(one(tname, oid) for tname, oid in items), return_exceptions=True
+            *(one(*pair) for pair in pairs), return_exceptions=True
         )
         return sum(1 for r in results if r is True), attempted
 
     async def _install_on(
         self, target: str, object_id: ObjectId, payload: bytes
     ) -> None:
-        ack = await self._get_client().send(
+        ack = await self._get_client("inbox").send(
             INBOX_TYPE,
             target,
             InstallState(
@@ -568,10 +688,17 @@ class MigrationManager:
             raise RuntimeError(f"target {target} refused state install: {ack.detail}")
 
     def _prune_fences(self) -> None:
+        # Fences are kept in the order they were armed: the expired ones are
+        # at the front (a pass over all of them after every hand-off is
+        # quadratic in a burst).
         now = time.monotonic()
-        for key, (_, ts) in list(self._fenced.items()):
-            if now - ts > FENCE_TTL:
-                self._fenced.pop(key, None)
+        expired = []
+        for key, (_, ts) in self._fenced.items():
+            if now - ts <= FENCE_TTL:
+                break
+            expired.append(key)
+        for key in expired:
+            del self._fenced[key]
 
     def _record_pinned_window(self, ms: float) -> None:
         s = self.stats
@@ -625,7 +752,7 @@ class MigrationManager:
         """Target side: pull snapshots for ``items`` from ``source``'s inbox
         and park them in the stash the LOAD lifecycle reads. Returns the
         number of snapshots stashed."""
-        batch = await self._get_client().send(
+        batch = await self._get_client("inbox").send(
             INBOX_TYPE,
             source,
             FetchStates(items=items, requester=self.address),
@@ -696,28 +823,41 @@ class MigrationManager:
         Moves with a live source are grouped by ``(source, target)`` and
         shipped as :class:`MigrateBatch` bursts — prefetch first, then the
         pinned handoffs — with burst concurrency bounded by the global
-        budget and a per-source semaphore. Dead sources and
-        activation-less framework rows (reminder-shard seats) get the bare
-        directory flip, which for them *is* the migration. A failed move
-        (or a whole failed burst — e.g. the source died mid-batch) leaves
-        its rows standing: the lazy request-path re-seat and the next
-        churn solve both cover them, and any pins die with the source.
+        budget and a per-source semaphore. A source that holds no live
+        activation of an object still runs its move (pin and fence are the
+        one-activation guarantee). Dead sources and activation-less
+        framework rows (reminder-shard seats) get the bare directory flip,
+        which for them *is* the migration. A failed move (or a whole failed
+        burst — e.g. the source died mid-batch) leaves its rows standing:
+        the lazy request-path re-seat and the next churn solve both cover
+        them, and any pins die with the source.
+
+        One call is one ``migrate.apply_moves`` stage; its children are
+        ``migrate.burst`` (ONE record a plan: the slowest burst's span; how
+        many bursts and keys is in ``MigrationStats.plan_*``) and
+        ``migrate.flips`` (the bare flips).
         """
+        with tracing.stage("migrate.apply_moves"):
+            return await self._apply_moves(moves)
+
+    async def _apply_moves(self, moves: list[tuple[str, str, str]]) -> int:
         groups: dict[tuple[str, str], list] = {}
         flips: list[tuple[str, ObjectId, str, str]] = []
-        active: dict[str, bool] = {}
+        # One membership read a plan, not one a source: a read copies the
+        # whole table, and a plan may name a thousand sources.
+        active: set[str] | None = None
         for key, src, dst in moves:
             oid = self._split_key(key)
             if oid is None or src == dst:
                 if oid is None:
                     log.warning("unroutable directory key %r; row left in place", key)
                 continue
-            if src != self.address and src not in active and self.registry.has_type(
+            if src != self.address and active is None and self.registry.has_type(
                 oid.type_name
             ):
-                active[src] = await self.members_storage.is_active(src)
+                active = {m.address for m in await self.members_storage.active_members()}
             if src == self.address or (
-                self.registry.has_type(oid.type_name) and active.get(src, False)
+                self.registry.has_type(oid.type_name) and src in (active or ())
             ):
                 groups.setdefault((src, dst), []).append([oid.type_name, oid.id])
             else:
@@ -730,54 +870,109 @@ class MigrationManager:
             for (src, dst), items in sorted(groups.items())
             for i in range(0, len(items), size)
         ]
+        # One call a source for as many of its bursts as fit ``batch_size``
+        # keys together: a full burst travels alone, as ever; a hundred
+        # single-row bursts of one source (a re-priced node's rows, one to
+        # each of a hundred targets) travel in one call and not a hundred,
+        # each a round trip behind ``per_node_inflight``.
+        calls: list[tuple[str, list]] = []  # (source, [[target, items], ...])
+        for src, dst, items in bursts:
+            last = calls[-1] if calls else None
+            if (
+                last is not None
+                and last[0] == src
+                and sum(len(i) for _, i in last[1]) + len(items) <= size
+            ):
+                last[1].append([dst, items])
+            else:
+                calls.append((src, [[dst, items]]))
+        st = self.stats
+        st.plans += 1
+        st.plan_bursts += len(bursts)
+        st.plan_burst_keys += sum(len(b[2]) for b in bursts)
+        slowest = (0, 0)  # perf_counter_ns span of the plan's slowest call
 
-        async def run(src: str, dst: str, items: list) -> int:
+        async def run(src: str, spread: list) -> int:
+            nonlocal slowest
             try:
                 async with self._global_sem, self._node_sem(src):
-                    return await self._run_burst(src, dst, items)
+                    t0 = time.perf_counter_ns()
+                    try:
+                        return await self._run_bursts(src, spread)
+                    finally:
+                        t1 = time.perf_counter_ns()
+                        if t1 - t0 > slowest[1] - slowest[0]:
+                            slowest = (t0, t1)
             except Exception as e:
                 self.stats.aborted += 1
                 log.warning(
-                    "burst %s -> %s (%d keys) failed: %r", src, dst, len(items), e
+                    "%d bursts from %s (%d keys) failed: %r",
+                    len(spread), src, sum(len(i) for _, i in spread), e,
                 )
                 return 0
 
-        if bursts:
-            done += sum(await asyncio.gather(*(run(*b) for b in bursts)))
+        if calls:
+            done += sum(await asyncio.gather(*(run(*c) for c in calls)))
+            tracing.stage_between("migrate.burst", *slowest)
+            st.plan_burst_ms_max = max(
+                st.plan_burst_ms_max, (slowest[1] - slowest[0]) / 1e6
+            )
 
-        for key, oid, src, dst in flips:
+        if flips:
+            with tracing.stage("migrate.flips"):
+                done += await self._bare_flips(flips)
+        return done
+
+    async def _bare_flips(self, flips: list) -> int:
+        done = 0
+        for i, (key, oid, src, dst) in enumerate(flips, 1):
             try:
                 if await self.placement.lookup(oid) == src:
                     await self.placement.update(
                         ObjectPlacementItem(object_id=oid, server_address=dst)
                     )
                     self.stats.seat_flips += 1
+                    self.stats.plan_flips += 1
                     done += 1
             except Exception as e:
                 self.stats.aborted += 1
                 log.warning("move %s %s->%s failed: %r", key, src, dst, e)
+            if not i % _FLIPS_PER_TURN:
+                # Thousands of uncontended updates never suspend: give the
+                # loop (the requests this node serves) a turn between runs.
+                await asyncio.sleep(0)
         return done
 
-    async def _run_burst(self, src: str, dst: str, items: list) -> int:
-        """One (source, target) chunk: warm the target, then fire the burst."""
+    async def _prefetch(self, src: str, dst: str, items: list) -> None:
+        try:
+            await self._get_client("control").send(
+                CONTROL_TYPE,
+                dst,
+                PrefetchPull(source=src, items=items),
+                returns=MigrateBatchAck,
+            )
+        except Exception as e:  # noqa: BLE001 - prefetch is best-effort
+            log.debug("prefetch pull %s <- %s failed: %r", dst, src, e)
+
+    async def _run_bursts(self, src: str, spread: list) -> int:
+        """One source's ``[target, items]`` chunks that travel together:
+        warm the targets, then fire the bursts in one call."""
         if self.config.prefetch:
-            try:
-                await self._get_client().send(
-                    CONTROL_TYPE,
-                    dst,
-                    PrefetchPull(source=src, items=items),
-                    returns=MigrateBatchAck,
-                )
-            except Exception as e:  # noqa: BLE001 - prefetch is best-effort
-                log.debug("prefetch pull %s <- %s failed: %r", dst, src, e)
+            # (A type with no ``__migrate_state__`` has nothing to pull: the
+            # two calls a pull costs would warm nothing.)
+            await asyncio.gather(*(
+                self._prefetch(src, dst, items) for dst, items in spread
+                if any(self.registry.exports_volatile(tname) for tname, _ in items)
+            ))
         if src == self.address:
-            burst_done, _ = await self.migrate_batch(dst, items)
+            burst_done, _ = await self.migrate_spread(spread)
             return burst_done
-        ack = await self._get_client().send(
-            CONTROL_TYPE,
-            src,
-            MigrateBatch(target=dst, items=items),
-            returns=MigrateBatchAck,
+        if len(spread) == 1:
+            call = MigrateBatch(target=spread[0][0], items=spread[0][1])
+        else:
+            call = MigrateSpread(bursts=spread)
+        ack = await self._get_client("control").send(
+            CONTROL_TYPE, src, call, returns=MigrateBatchAck
         )
         return ack.done
 
@@ -808,14 +1003,22 @@ class MigrationManager:
 
     # ------------------------------------------------------------------
 
-    def _get_client(self):
-        if self._client is None:
-            from ..client import Client
-
-            self._client = Client(
-                self.members_storage, placement_resolver=self._resolve
+    def _get_client(self, lane: str):
+        """The client for ``lane`` ("control": the long handoff calls,
+        "inbox": the purely local ones). Its sockets are the process's
+        (``_LANES``): N co-located managers hold one bundle to a node."""
+        if self._client is not None:
+            return self._client
+        client = self._clients.get(lane)
+        if client is None:
+            client = self._clients[lane] = _LaneClient(
+                _lanes()[lane], self.members_storage, self._resolve
             )
-        return self._client
+        return client
+
+    def gauges(self) -> dict[str, float]:
+        """``rio.migrate.*`` for ``otel.server_gauges``, made at scrape time."""
+        return {"rio.migrate.open_connections": float(open_connections())}
 
     async def _resolve(self, handler_type: str, handler_id: str) -> str | None:
         if handler_type in (CONTROL_TYPE, INBOX_TYPE):
@@ -823,9 +1026,11 @@ class MigrationManager:
         return await self.placement.lookup(ObjectId(handler_type, handler_id))
 
     def close(self) -> None:
-        if self._client is not None:
-            self._client.close()
-            self._client = None
+        for client in [self._client, *self._clients.values()]:
+            if client is not None:
+                client.close()
+        self._client = None
+        self._clients = {}
 
 
 @type_name(CONTROL_TYPE)
@@ -848,6 +1053,14 @@ class MigrationControl(ServiceObject):
         if mgr is None:
             return MigrateBatchAck(detail="migration disabled on this node")
         done, attempted = await mgr.migrate_batch(msg.target, msg.items)
+        return MigrateBatchAck(done=done, attempted=attempted)
+
+    @handler
+    async def migrate_spread(self, msg: MigrateSpread, ctx: AppData) -> MigrateBatchAck:
+        mgr = ctx.try_get(MigrationManager)
+        if mgr is None:
+            return MigrateBatchAck(detail="migration disabled on this node")
+        done, attempted = await mgr.migrate_spread(msg.bursts)
         return MigrateBatchAck(done=done, attempted=attempted)
 
     @handler

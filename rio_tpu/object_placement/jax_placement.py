@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import gc
+import itertools
 import logging
 import os
 import threading
@@ -41,7 +42,7 @@ import numpy as np
 
 from ..errors import NoSchedulableCapacity
 from ..registry import ObjectId
-from ..tracing import stage, stage_since
+from ..tracing import stage, stage_between, stage_since
 from ..utils.jaxenv import compile_cache_dir
 from ..ops import (
     build_cost_matrix,
@@ -416,6 +417,53 @@ def _route_unseatable(
     out[bad] = _least_loaded_spread(
         load, alive, cap, n_real, int(bad.sum())
     ).astype(assignment.dtype)
+    return out
+
+
+def _cancel_transit(assignment: np.ndarray, cur_idx: np.ndarray) -> np.ndarray:
+    """Re-express a plan over interchangeable rows with the fewest moves.
+
+    An entropic plan spreads: with 1,000 schedulable nodes each survivor's
+    row of the soft plan leaks a few percent of its mass off the diagonal,
+    the per-row rounding turns that into a row or so sent to the emptiest
+    columns (the rejoiners), and the leavers' rows fill the survivors back
+    up — two moves where one would do, a thousand single-row hand-offs a
+    churn event at 1,048,576 x 1,024 (PERF.md PR 27). Where every row costs
+    the same to move and every destination the same to reach, a node that
+    both loses and receives rows can keep what it loses: the row that would
+    have entered goes where the kept row was headed. Every node's final
+    load is unchanged; no row moves that did not, and ``min(out, in)`` fewer
+    move at every node. O(movers).
+    """
+    cur = np.asarray(cur_idx, assignment.dtype)
+    movers = np.flatnonzero(assignment != cur)
+    if movers.size == 0:
+        return assignment
+    m = int(max(assignment.max(), cur.max())) + 1
+    src, dst = cur[movers], assignment[movers]
+    transit = np.minimum(np.bincount(src, minlength=m), np.bincount(dst, minlength=m))
+    if not transit.any():
+        return assignment
+    out = assignment.copy()
+    src_l, dst_l = src.tolist(), dst.tolist()
+    leave: dict[int, set] = {}
+    enter: dict[int, set] = {}
+    for i, (a, b) in enumerate(zip(src_l, dst_l)):
+        leave.setdefault(a, set()).add(i)
+        enter.setdefault(b, set()).add(i)
+    for j in np.flatnonzero(transit).tolist():
+        leaving, entering = leave.get(j, set()), enter.get(j, set())
+        while leaving and entering:
+            a, b = leaving.pop(), entering.pop()
+            d = dst_l[a]
+            enter[d].discard(a)
+            dst_l[a] = j  # a stays where it is
+            dst_l[b] = d
+            if src_l[b] == d:  # b was coming from where a was going: it stays too
+                leave[d].discard(b)
+            else:
+                enter[d].add(b)
+    out[movers] = np.asarray(dst_l, out.dtype)
     return out
 
 
@@ -935,6 +983,11 @@ class JaxObjectPlacement(ObjectPlacement):
         # What went in through the bulk seam (``place_gauges``).
         self._bulk_rows = 0
         self._bulk_chunks = 0
+        # Lattice steps ``sync_load`` applied, over all nodes (``place_gauges``).
+        self._derate_steps = 0
+        # Rows the delta route found displaced and moved (``place_gauges``).
+        self._delta_displaced = 0
+        self._delta_moved = 0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1096,6 +1149,9 @@ class JaxObjectPlacement(ObjectPlacement):
         return {
             "rio.place.bulk_rows": float(self._bulk_rows),
             "rio.place.bulk_chunks": float(self._bulk_chunks),
+            "rio.load.derate_steps": float(self._derate_steps),
+            "rio.place.delta.displaced": float(self._delta_displaced),
+            "rio.place.delta.moved": float(self._delta_moved),
             "rio.place.index_tracked_rows": float(
                 sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
             ),
@@ -1175,6 +1231,13 @@ class JaxObjectPlacement(ObjectPlacement):
     # every call, so each re-solve would shuffle seats for measurement
     # noise. A bucket flip is a real regime change.
     _DERATE_STEP = 8.0
+    # ...with hysteresis: a node leaves the step it is on only for a price at
+    # least this share of a step away from it. A price that sits on the edge
+    # between two steps (a server whose tick runs ~7 ms late behind its
+    # co-located siblings' ticks reads 0.9375, PERF.md PR 27) would otherwise
+    # flap between them on every call, and one flap of one server moves every
+    # node's quota by a row: a thousand single-row hand-offs a flap.
+    _DERATE_HOLD = 0.75
 
     def sync_load(self, view) -> None:
         """Feed measured cluster load (``rio_tpu.load.ClusterLoadView``)
@@ -1195,13 +1258,24 @@ class JaxObjectPlacement(ObjectPlacement):
         that wobbles a churn re-solve could lose every retry.) The epoch
         guards what an apply can corrupt: seats and liveness."""
         changed = False
+        # A node can only step if the view prices it or it stands off full
+        # price now: at a thousand members of which a few report load, the
+        # rest is one comparison each and not a pricing.
+        priced = getattr(view, "entries", None)
         for addr, slot in self._nodes.items():
+            if priced is not None and slot.reported_derate == 1.0 and addr not in priced:
+                continue
             d = 1.0 if view is None else float(view.derate(addr))
             if not (d == d):  # NaN guard (view sanitizes; belt-and-braces)
                 d = 1.0
             d = min(1.0, max(0.1, d))
+            if abs(d - slot.reported_derate) * self._DERATE_STEP < self._DERATE_HOLD:
+                continue
             q = round(d * self._DERATE_STEP) / self._DERATE_STEP
             if q != slot.reported_derate:
+                self._derate_steps += round(
+                    abs(q - slot.reported_derate) * self._DERATE_STEP
+                )
                 slot.reported_derate = q
                 changed = True
         if changed:
@@ -1899,30 +1973,31 @@ class JaxObjectPlacement(ObjectPlacement):
         residual. A missing seed is passed as zeros, not
         None: cold start IS the zero seed in both solver forms, and a
         None-vs-array flip would mint a second trace."""
-        base = build_cost_matrix(jnp.zeros_like(load), cap, alive)[0]
-        g_seed = (
-            jnp.zeros((base.shape[0],), jnp.float32)
-            if plan.g is None
-            else jnp.asarray(plan.g)
-        )
-        g_r, err = _class_refresh_device(
-            base,
-            jnp.asarray(np.asarray(counts_np, np.float32)),
-            jnp.asarray(cap_alive.astype(np.float32)),
-            g_seed,
-            mode=mode,
-            move_cost=self._move_cost,
-            eps=min(
-                self._eps,
-                self._move_cost / 25.0 if self._move_cost > 0 else self._eps,
-            ),
-            n_iters=max(4, min(8, self._n_iters)),
-        )
-        g_np = np.asarray(g_r, np.float64)
-        score = np.asarray(base, np.float64) - np.where(
-            np.isfinite(g_np), g_np, -1e30
-        )
-        return g_r, score, float(np.asarray(err))
+        with stage("solve.delta.refresh"):
+            base = build_cost_matrix(jnp.zeros_like(load), cap, alive)[0]
+            g_seed = (
+                jnp.zeros((base.shape[0],), jnp.float32)
+                if plan.g is None
+                else jnp.asarray(plan.g)
+            )
+            g_r, err = _class_refresh_device(
+                base,
+                jnp.asarray(np.asarray(counts_np, np.float32)),
+                jnp.asarray(cap_alive.astype(np.float32)),
+                g_seed,
+                mode=mode,
+                move_cost=self._move_cost,
+                eps=min(
+                    self._eps,
+                    self._move_cost / 25.0 if self._move_cost > 0 else self._eps,
+                ),
+                n_iters=max(4, min(8, self._n_iters)),
+            )
+            g_np = np.asarray(g_r, np.float64)
+            score = np.asarray(base, np.float64) - np.where(
+                np.isfinite(g_np), g_np, -1e30
+            )
+            return g_r, score, float(np.asarray(err))
 
     def _delta_fast_snapshot(self, plan, n, cap, alive, force):
         """O(displaced) delta snapshot, taken under the provider lock.
@@ -1937,13 +2012,19 @@ class JaxObjectPlacement(ObjectPlacement):
         the displaced ``(key, old_index)`` pairs, so the whole event costs
         O(displaced + M^2) instead of O(N).
 
-        Returns None whenever per-seat decisions could matter — a survivor
-        over its integer quota needs rank-based eviction (honoring
-        ``object_costs`` prices); the array-snapshot delta / full pipeline
-        handles those. Per-object prices are irrelevant HERE by
-        construction: with no survivor over quota there are no evictions,
-        so prices cannot change which objects move, and the flat cost
-        model prices every destination identically for all objects.
+        A survivor over its integer quota (a node RETURNED, or a load
+        derate shrank a node's share) sheds its overflow here too: under the
+        flat cost model its rows are interchangeable, so the newest
+        ``counts - quota`` of ``_by_node`` are as good an overflow as any
+        rank. (Routed through the array snapshot, every re-priced node cost
+        a churning cluster an O(N) hold of the loop per event.)
+
+        Returns None whenever per-seat decisions could matter — with an
+        ``object_costs`` hook an over-quota survivor needs rank-based
+        eviction (hot objects are kept); the array-snapshot delta / full
+        pipeline handles that. With no survivor over quota there are no
+        evictions, so prices cannot change which objects move, and the flat
+        cost model prices every destination identically for all objects.
         """
         if not self._delta_gates_ok(plan, force):
             return None
@@ -1956,17 +2037,24 @@ class JaxObjectPlacement(ObjectPlacement):
         for j, seats in self._by_node.items():
             if j < m:
                 counts[j] = len(seats)
-        quota = integer_fair_quotas(cap_alive, n)
-        if np.any(sched & (counts > quota)):
-            return None  # over-quota eviction: needs per-seat ranks
+        quota = integer_fair_quotas(cap_alive, n, counts)
+        over_nodes = np.nonzero(sched & (counts > quota))[0]
+        if over_nodes.size and self._object_costs is not None:
+            return None  # priced over-quota eviction: needs per-seat ranks
         disp_nodes = np.nonzero(~sched & (counts > 0))[0]
-        d = int(counts[disp_nodes].sum())
+        retained = np.where(sched, np.minimum(counts, quota), 0)
+        d = int(counts[disp_nodes].sum() + (counts - quota)[over_nodes].sum())
         if not force and d > self._delta_threshold * n:
             return None
         disp: list[tuple[str, int]] = []
         for j in disp_nodes.tolist():
             disp.extend((k, j) for k in self._by_node.get(j, ()))
-        retained = np.where(sched, counts, 0)
+        for j in over_nodes.tolist():
+            seats = self._by_node[j]
+            newest = reversed(seats) if hasattr(seats, "__reversed__") else iter(seats)
+            disp.extend(
+                (k, j) for k in itertools.islice(newest, int(counts[j] - quota[j]))
+            )
         residual = quota - retained
         return {
             "disp": disp,
@@ -2002,7 +2090,7 @@ class JaxObjectPlacement(ObjectPlacement):
             c0 = _compile_seconds()
             with stage("solve.device") as st, span(
                 "placement_solve", mode=solved_as, n=n
-            ):
+            ), stage("solve.delta"):
                 g_new = None
                 coarse_new = None
                 conv: dict = {}
@@ -2037,7 +2125,8 @@ class JaxObjectPlacement(ObjectPlacement):
                         score = np.where(
                             sched, retained / np.maximum(quota, 1), 1e18
                         )
-                    fill = residual_capacity_assign(score, residual)
+                    with stage("solve.delta.fill"):
+                        fill = residual_capacity_assign(score, residual)
                 # Transport-cost audit (see _delta_solve): achieved
                 # seating vs the integer-quota ideal; a tripped audit
                 # marks the plan stale so the NEXT solve goes full.
@@ -2084,6 +2173,8 @@ class JaxObjectPlacement(ObjectPlacement):
                         moved += 1
                 if move_sink is not None:
                     moved = len(planned)
+                self._delta_displaced += d
+                self._delta_moved += moved
                 if g is not None:
                     self._g = g
                     self._g_fp = self._sched_fp()
@@ -2148,13 +2239,18 @@ class JaxObjectPlacement(ObjectPlacement):
         n = len(keys)
         if n == 0 or not self._delta_gates_ok(plan, force):
             return None
+        # A delta that runs to a result is one ``solve.delta`` record, logged
+        # at the return (a gate below may still hand the event to the full
+        # solve), beside ``solve.delta.refresh`` and ``solve.delta.fill``.
+        t_delta = time.perf_counter_ns()
         cap_np = np.asarray(cap, np.float64)
         alive_np = np.asarray(alive, np.float64)
         cap_alive = cap_np * (alive_np > 0)
         m = cap_alive.shape[0]
         sched = cap_alive > 0.0
-        quota = integer_fair_quotas(cap_alive, n)  # (m,), sums to n exactly
         cur = np.asarray(cur_idx, np.int64)
+        # (m,), sums to n exactly; a tied unit stays on the fuller node.
+        quota = integer_fair_quotas(cap_alive, n, np.bincount(cur, minlength=m))
         # Rank each object within its current seat's population (one
         # stable sort — the host analog of ops.assignment.rank_within_group).
         # With per-object move prices the heavy/hot objects rank first and
@@ -2173,6 +2269,7 @@ class JaxObjectPlacement(ObjectPlacement):
         d = int(disp_pos.shape[0])
         if d == 0:
             # Nothing displaced (e.g. a node RETURNED): the plan stands.
+            stage_between("solve.delta", t_delta, time.perf_counter_ns())
             return cur.astype(np.int32), None, None, 0, False, {}
         if not force and d > self._delta_threshold * n:
             return None
@@ -2220,7 +2317,8 @@ class JaxObjectPlacement(ObjectPlacement):
                 score = np.where(
                     sched, retained / np.maximum(quota, 1), 1e18
                 )
-            fill = residual_capacity_assign(score, residual)
+            with stage("solve.delta.fill"):
+                fill = residual_capacity_assign(score, residual)
         out = cur.astype(np.int32).copy()
         out[disp_pos] = fill
 
@@ -2237,6 +2335,7 @@ class JaxObjectPlacement(ObjectPlacement):
         num = float(np.sum(counts_after**2 / safe_cap))
         den = float(np.sum(quota.astype(np.float64) ** 2 / safe_cap))
         stale = bool(den > 0.0 and num > self._delta_audit_ratio * den)
+        stage_between("solve.delta", t_delta, time.perf_counter_ns())
         return out, g_new, coarse_new, d, stale, conv
 
     # ------------------------------------------------ communication graph
@@ -2684,6 +2783,12 @@ class JaxObjectPlacement(ObjectPlacement):
                         idx_full,
                         expected,
                         prefer_keep=jnp.where(real, idx_full == cur_full, True),
+                        # A tied unit stays on the node that holds it NOW
+                        # (the delta route's rule too), not where the
+                        # plan's rounding noise put a row more.
+                        tie_counts=jnp.bincount(
+                            jnp.where(real, cur_full, m_axis), length=m_axis + 1
+                        ),
                     )
                     return _guard_sentinel_spill(
                         repaired, real, m_axis, cap_alive
@@ -2900,6 +3005,11 @@ class JaxObjectPlacement(ObjectPlacement):
             out = _route_unseatable(
                 np.asarray(assignment)[:n], len(node_order), load, alive, cap
             )
+            if obj_w is None and not route_hier and mode != "hierarchical":
+                # One price for every object and every destination: the
+                # plan's per-node loads are what the solve decided, which
+                # rows carry them is not.
+                out = _cancel_transit(out, cur_idx)
             # Communication-graph refinement (full solves only: the delta
             # path returned above, and its warm potentials price pure
             # balance). Runs on the already-routed assignment so the
@@ -2977,6 +3087,9 @@ class JaxObjectPlacement(ObjectPlacement):
                         moved += 1
                 if move_sink is not None:
                     moved = len(planned)
+                if solved_as.endswith("+delta"):
+                    self._delta_displaced += displaced
+                    self._delta_moved += moved
                 if g is not None:
                     self._g = g
                     self._g_fp = self._sched_fp()

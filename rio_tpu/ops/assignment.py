@@ -25,7 +25,9 @@ from jax import lax
 DEAD_NODE_COST = 1e6
 
 
-def integer_fair_quotas(cap_alive: np.ndarray, n: int) -> np.ndarray:
+def integer_fair_quotas(
+    cap_alive: np.ndarray, n: int, counts: np.ndarray | None = None
+) -> np.ndarray:
     """Largest-remainder integer fair shares of ``n`` seats (host numpy).
 
     The delta-rebalance counterpart of the device-side quota math inside
@@ -35,7 +37,11 @@ def integer_fair_quotas(cap_alive: np.ndarray, n: int) -> np.ndarray:
     Same invariant as the device repair: NO global rescale of the raw
     shares (an fp rescale flips floor/remainder units on exact-integer
     columns at large scale; see the r4 note there). Zero-capacity nodes
-    get zero share and zero quota.
+    get zero share and zero quota. ``counts`` (rows each column holds now)
+    settles remainder ties between columns of one capacity the way the device
+    repair settles them: the fuller column keeps the unit it has. Without it
+    the unit goes to the lower index, and a solve that alternates with the
+    device's moves a row for every unit the two rules place differently.
     """
     cap = np.maximum(np.asarray(cap_alive, np.float64), 0.0)
     total = cap.sum()
@@ -47,7 +53,10 @@ def integer_fair_quotas(cap_alive: np.ndarray, n: int) -> np.ndarray:
     if short > 0:
         # Remainder ties prefer the higher-capacity column (deterministic,
         # and a bonus unit belongs where it displaces least).
-        rem_order = np.lexsort((-cap, -(target - quota)))
+        keys = (-cap, -(target - quota))
+        if counts is not None:
+            keys = (-np.asarray(counts, np.float64), *keys)
+        rem_order = np.lexsort(keys)
         quota[rem_order[:short]] += 1
     return quota
 

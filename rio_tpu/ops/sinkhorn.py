@@ -200,6 +200,7 @@ def exact_quota_repair(
     idx: jax.Array,
     expected_counts: jax.Array,
     prefer_keep: jax.Array | None = None,
+    tie_counts: jax.Array | None = None,
 ) -> jax.Array:
     """Make a rounded assignment match integer column quotas EXACTLY.
 
@@ -221,6 +222,11 @@ def exact_quota_repair(
         over-quota column. A churn re-solve passes "rounded to its current
         seat", so quota eviction lands on objects that were moving anyway
         and the repair adds ~zero extra churn.
+      tie_counts: optional (m,) occupancy that settles remainder ties in
+        place of ``idx``'s own. A churn re-solve passes the CURRENT
+        directory's: the rounded plan's occupancy differs from it by the
+        plan's rounding noise, and a unit awarded by that noise moves a row
+        (at 1,048,576 x 1,024 some 460 of 400 units' worth, PERF.md PR 27).
     """
     from .assignment import rank_within_group
 
@@ -245,7 +251,7 @@ def exact_quota_repair(
     # Largest remainders get the leftover units; remainder ties prefer the
     # MORE-occupied column (awarding a tied bonus to an empty column would
     # displace a seated object for no quota reason — churn, not repair).
-    rem_order = jnp.lexsort((-counts, -rem))
+    rem_order = jnp.lexsort((-(counts if tie_counts is None else tie_counts), -rem))
     bonus = (
         jnp.zeros((m,), jnp.int32)
         .at[rem_order]

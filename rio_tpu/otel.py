@@ -93,6 +93,9 @@ def server_gauges(server: Any) -> dict[str, float]:
         # The device-solved directory's host mirror (rio.place.*): rows and
         # chunks seated in bulk, rows of its per-node index the collector walks.
         gauges.update(place_gauges())
+    migrate_gauges = getattr(migrator, "gauges", None)
+    if migrate_gauges is not None:
+        gauges.update(migrate_gauges())
     registry = getattr(server, "registry", None)
     if registry is not None:
         gauges["rio.registry.objects"] = float(registry.count_objects())

@@ -31,8 +31,11 @@ from __future__ import annotations
 import asyncio
 import logging
 import random
+import time
+import weakref
 from dataclasses import dataclass
 
+from . import tracing
 from .cluster.storage import MembershipStorage
 from .journal import MEMBER_DOWN, MEMBER_UP, SOLVE, STORAGE
 from .object_placement import ObjectPlacement
@@ -94,6 +97,29 @@ class PlacementDaemonConfig:
     event_kick: bool = True
 
 
+class _SolveClaims:
+    """What the daemons of one process know about the solves they dispatch
+    on a provider they share: the one in flight, and the liveness the last
+    committed one was solved under. N co-located servers answer one churn
+    event with N daemons; without this N - 1 of them dispatch a device solve
+    only to lose the epoch race to the first."""
+
+    def __init__(self) -> None:
+        self.in_flight: asyncio.Future | None = None
+        self.served: frozenset | None = None  # liveness of the last commit
+        self.served_at = float("-inf")  # loop time of that commit
+
+
+_CLAIMS: "weakref.WeakKeyDictionary[object, _SolveClaims]" = weakref.WeakKeyDictionary()
+
+
+def _claims_for(placement) -> _SolveClaims:
+    claims = _CLAIMS.get(placement)
+    if claims is None:
+        claims = _CLAIMS[placement] = _SolveClaims()
+    return claims
+
+
 class PlacementDaemon:
     """Watch membership storage; re-solve placement on liveness changes."""
 
@@ -124,6 +150,11 @@ class PlacementDaemon:
         self._retry_solve = False  # last solve was epoch-discarded
         self._consecutive_discards = 0
         self._retry_not_before = float("-inf")  # backoff gate (loop time)
+        # When the unserved liveness change was first seen: loop time (what a
+        # sibling's commit is compared with) and perf_counter_ns (the
+        # ``daemon.wait`` stage's start).
+        self._event_seen_at = float("-inf")
+        self._event_seen_ns = 0
         self._kick_event = asyncio.Event()
 
     # -- storage-outage bookkeeping (one journal event per edge) -------------
@@ -342,6 +373,11 @@ class PlacementDaemon:
                     # event's epoch races — start over.
                     self._consecutive_discards = 0
                     self._retry_not_before = float("-inf")
+                    if not self._retry_solve:
+                        # (A change on top of an unserved one keeps the
+                        # first one's stamp: that event is still waiting.)
+                        self._event_seen_at = loop.time()
+                        self._event_seen_ns = time.perf_counter_ns()
                 if changed or retry:
                     # NOTE _retry_solve is NOT cleared here: every exit of
                     # this branch sets it explicitly, so a transient
@@ -370,17 +406,42 @@ class PlacementDaemon:
                     wait = last_rebalance + cfg.min_rebalance_interval - loop.time()
                     if wait > 0:
                         await asyncio.sleep(wait)
-                    if solve_epoch is not None and self._solve_epoch() != solve_epoch:
+                    claims = _claims_for(self.placement)
+                    waited = False
+                    while claims.in_flight is not None:
+                        # A sibling daemon on the SAME provider has a solve
+                        # in flight: one beside it would lose the epoch race
+                        # or make the sibling lose it. Wait, then look again.
+                        waited = True
+                        await asyncio.shield(claims.in_flight)
+                    if waited:
+                        liveness, members = await self._liveness()
+                        self._last_liveness = liveness
+                        self.placement.sync_members(members)
+                    if (
+                        solve_epoch is not None and self._solve_epoch() != solve_epoch
+                    ) or (
+                        claims.served == liveness
+                        and claims.served_at >= self._event_seen_at
+                    ):
                         # A sibling daemon on the SAME provider already
-                        # solved this churn event — don't dispatch another
-                        # device solve just to have it epoch-discarded.
+                        # solved this churn event (a commit since we looked,
+                        # or one under this very liveness since we first saw
+                        # the change) — don't dispatch another device solve
+                        # just to have it epoch-discarded.
                         self._retry_solve = False  # event served by sibling
                         self.stats.rebalances_skipped += 1
                         await self._idle(cfg.poll_interval)
                         continue
                     stats_before = getattr(self.placement, "stats", None)
                     committed_before = self._solve_epoch()
-                    moved = await self._rebalance(cfg.mode)
+                    tracing.stage_since("daemon.wait", self._event_seen_ns)
+                    claims.in_flight = loop.create_future()
+                    try:
+                        moved = await self._rebalance(cfg.mode)
+                    finally:
+                        claims.in_flight.set_result(None)
+                        claims.in_flight = None
                     last_rebalance = loop.time()
                     stats_now = getattr(self.placement, "stats", None)
                     # Attribute a discard to OUR attempt only when the
@@ -449,6 +510,8 @@ class PlacementDaemon:
                     else:
                         self._retry_solve = False
                         self._consecutive_discards = 0
+                        claims.served = liveness
+                        claims.served_at = last_rebalance
                         self.stats.rebalances += 1
                         if "+delta" in str(getattr(stats_now, "mode", "")):
                             self.stats.delta_rebalances += 1

@@ -99,6 +99,7 @@ class Registry:
         self._node_scoped: set[str] = set()
         self._replicated: set[str] = set()
         self._readonly: set[tuple[str, str]] = set()
+        self._volatile: set[str] = set()  # types with ``__migrate_state__``
 
     # -- type / handler registration (reference registry/mod.rs:82-182) ----
 
@@ -130,6 +131,8 @@ class Registry:
             # standby set after every acknowledged request
             # (rio_tpu/replication).
             self._replicated.add(tname)
+        if hasattr(cls, "__migrate_state__"):
+            self._volatile.add(tname)
         for spec in resolve_handlers(cls):
             # Lifecycle dispatch (activation Load), reminder wakeups, and
             # stream/saga step delivery are framework plumbing and must
@@ -172,6 +175,11 @@ class Registry:
 
     def is_replicated(self, type_name: str) -> bool:
         return type_name in self._replicated
+
+    def exports_volatile(self, type_name: str) -> bool:
+        """The type snapshots volatile state for a hand-off
+        (``__migrate_state__``); one it does not know may."""
+        return type_name in self._volatile or type_name not in self._constructors
 
     def is_readonly(self, type_name: str, message_type: str) -> bool:
         return (type_name, message_type) in self._readonly

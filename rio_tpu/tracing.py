@@ -309,6 +309,13 @@ def stage_since(name: str, t0_ns: int) -> None:
     _log_stage(name, t0_ns, time.perf_counter_ns(), parent, call)
 
 
+def stage_between(name: str, t0_ns: int, t1_ns: int) -> None:
+    """Log a stage from two stamps taken elsewhere, under the current stage:
+    one record that stands for many (the slowest of a plan's bursts)."""
+    parent, call = _STAGE_CTX.get()
+    _log_stage(name, t0_ns, t1_ns, parent, call)
+
+
 def stage_log() -> list[tuple]:
     """A snapshot of the log, oldest first."""
     return list(_STAGE_LOG)

@@ -77,6 +77,34 @@ async def test_membership_backends(tmp_path):
         await check_membership(backend)
 
 
+@pytest.mark.asyncio
+async def test_local_membership_reads_its_table_and_copies_only_what_it_returns(monkeypatch):
+    """``is_active`` and ``active_members`` of the in-process table used to
+    copy every row a call (the defaults go through ``members()``): at a
+    thousand members 2 ms of the loop for each request routed off-node and
+    each hand-off burst."""
+    from rio_tpu.cluster import storage as storage_mod
+
+    storage = LocalStorage()
+    for i in range(50):
+        await storage.push(Member(ip="10.0.1.%d" % i, port=5000, active=i % 5 == 0))
+    copies = []
+    real = storage_mod._copy
+    monkeypatch.setattr(storage_mod, "_copy", lambda m: copies.append(m) or real(m))
+    assert await storage.is_active("10.0.1.5:5000")
+    assert not await storage.is_active("10.0.1.6:5000")
+    assert not await storage.is_active("10.9.9.9:1")  # never pushed
+    assert copies == []
+    active = await storage.active_members()
+    assert len(active) == 10 == len(copies) and all(m.active for m in active)
+    # Copies still: a caller that edits what it got does not edit the table.
+    active[0].active = False
+    assert await storage.is_active(active[0].address)
+    everyone = await storage.members()
+    assert [vars(m) for m in everyone] == [vars(m) for m in storage._members.values()]
+    assert all(a is not b for a, b in zip(everyone, storage._members.values()))
+
+
 # ---------------------------------------------------------------------------
 # placement
 # ---------------------------------------------------------------------------

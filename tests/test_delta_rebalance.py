@@ -255,6 +255,47 @@ async def test_node_return_rebalances_overflow_onto_it():
     assert undisplaced_kept >= len(pre) - moved
 
 
+@pytest.mark.parametrize("priced", [False, True])
+async def test_a_repriced_survivor_sheds_its_overflow_without_the_array_snapshot(priced):
+    """A survivor over its quota (a load derate shrank its share; a node
+    returned) used to send the whole event through the O(N) array snapshot:
+    a list of every key under the lock, on the loop, per event (0.35 s a
+    million rows) — the directory loading the very loop whose lag had
+    re-priced the node. Under the flat cost model the rows of a node are
+    interchangeable, so the O(displaced) snapshot sheds the newest
+    ``counts - quota`` of them; with an ``object_costs`` hook the ranks
+    decide, and the array route still runs."""
+    hook = (lambda keys: np.ones(len(keys), np.float32)) if priced else None
+    p = await _seeded(400, 4, mode="sinkhorn", object_costs=hook)
+    array_route = []
+    real = p._delta_solve
+    p._delta_solve = lambda *a, **k: array_route.append(1) or real(*a, **k)
+    pre = dict(p._placements)
+    node = p._nodes[_members(4)[1].address]
+    node.reported_derate = 0.5  # what sync_load does on a lattice step
+    moved = await p.rebalance()
+    assert p.stats.mode == "sinkhorn+delta" and not p.stats.discarded
+    assert bool(array_route) is priced
+    # 400 over capacities (1, 0.5, 1, 1): the re-priced node keeps 57 of its
+    # 100 rows; the 43 it sheds are all that moves, and all of them were its.
+    assert moved == p.stats.displaced == 43
+    assert len(p._by_node[node.index]) == 57
+    left = {k for k, v in pre.items() if p._placements[k] != v}
+    assert len(left) == 43 and all(pre[k] == node.index for k in left)
+    counts = sorted(len(p._by_node[j]) for j in range(4))
+    assert counts == [57, 114, 114, 115]
+    if not priced:
+        # The newest of its rows went: the tail of the node's own order.
+        before = [k for k, v in pre.items() if v == node.index]
+        assert left <= set(before) and len(before) == 100
+    # And back: the node returns to full price and is refilled, fast route.
+    array_route.clear()
+    node.reported_derate = 1.0
+    assert await p.rebalance() == p.stats.displaced == 43
+    assert not array_route or priced
+    assert sorted(len(p._by_node[j]) for j in range(4)) == [100, 100, 100, 100]
+
+
 # ---------------------------------------------------- warm-start parity
 
 

@@ -28,7 +28,14 @@ from rio_tpu import (
     message,
 )
 from rio_tpu.cluster.storage import Member
-from rio_tpu.load import DEFAULT_MAX_STALENESS, MIN_DERATE, capacity_derate
+from rio_tpu.load import (
+    DEFAULT_MAX_STALENESS,
+    MIN_DERATE,
+    SUSTAIN_MISSES,
+    SUSTAIN_TICKS,
+    _StallWatchdog,
+    capacity_derate,
+)
 from rio_tpu.object_placement.jax_placement import JaxObjectPlacement
 
 from .server_utils import Cluster, run_integration_test
@@ -263,19 +270,120 @@ async def test_monitor_samples_and_pushes_view():
     try:
         deadline = asyncio.get_event_loop().time() + 5.0
         while asyncio.get_event_loop().time() < deadline:
-            if m.stats.samples >= 3 and pushed:
+            # The published depth is the sustained one: it shows once all
+            # but SUSTAIN_MISSES of the last SUSTAIN_TICKS ticks read it.
+            if m.stats.samples >= SUSTAIN_TICKS - SUSTAIN_MISSES and pushed:
                 break
             await asyncio.sleep(0.02)
     finally:
         task.cancel()
         await asyncio.gather(task, return_exceptions=True)
-    assert m.stats.samples >= 3
+    assert m.stats.samples >= SUSTAIN_TICKS - SUSTAIN_MISSES
     assert m.stats.inflight == 1
     assert m.cluster_view is not None and len(m.cluster_view) == 1
     assert pushed and pushed[0].derate("10.0.0.1:1") < 1.0
     # The published snapshot round-trips through the heartbeat encoding.
     decoded = LoadVector.decode(m.encoded_snapshot())
     assert decoded is not None and decoded.inflight == 1.0
+
+
+# ---------------------------------------------------------------------------
+# The derate's input: lag and depth that last
+# ---------------------------------------------------------------------------
+
+
+def _quantized_derate(m: LoadMonitor) -> float:
+    """What ``sync_load`` makes of the monitor's published vector."""
+    p = _jax_provider(nodes=1)
+    addr = "10.0.0.0:5000"
+    p.sync_load(_view_for({addr: LoadVector.decode(m.encoded_snapshot())}))
+    return p._nodes[addr].reported_derate
+
+
+def test_one_isolated_hold_leaves_the_quantized_derate_where_it_was():
+    """One 300 ms hold of the loop every 4 s (a solve's apply, a hand-off
+    burst) is a mean lag of 300**2 / (2 * 4000) = 11 ms: one tick in four
+    reads up to 300 ms, and the published lag must not follow it."""
+    m = LoadMonitor(stall_threshold_ms=0)
+    for period in range(6):
+        for tick in range(4):
+            held = tick == 1
+            m.inflight = 120 if held else 1  # the hold's backlog, that tick only
+            m._sample(300.0 if held else 0.4)
+            assert _quantized_derate(m) == 1.0, (period, tick)
+    assert m.stats.loop_lag_ms > 20.0  # the EMA did follow it
+
+
+def test_a_loop_late_on_every_tick_is_derated_within_five_ticks():
+    m = LoadMonitor(stall_threshold_ms=0)
+    for _ in range(3):
+        m._sample(0.0)
+    seen = []
+    for _ in range(5):
+        m._sample(100.0)
+        seen.append(_quantized_derate(m))
+    assert seen[-1] <= 0.5, seen
+    assert seen[0] == 1.0  # and not by the first late tick alone
+    # It lasts while the lag does, and goes when most ticks are on time.
+    for _ in range(SUSTAIN_TICKS):
+        m._sample(100.0)
+    assert _quantized_derate(m) == 0.5
+    for _ in range(SUSTAIN_TICKS - SUSTAIN_MISSES):
+        m._sample(0.2)
+    assert _quantized_derate(m) == 1.0
+
+
+def test_shedding_still_reads_the_smoothed_lag_and_the_live_depth():
+    """``Service`` sheds on the node's own smoothed lag (0.3 a tick) and on
+    the requests in flight right now, as before: shedding protects the node
+    in the moment, the derate prices it for the next solve."""
+    m = LoadMonitor(
+        thresholds=LoadThresholds(max_loop_lag_ms=50.0, max_inflight=4),
+        stall_threshold_ms=0,
+    )
+    m._sample(200.0)
+    assert math.isclose(m.stats.loop_lag_ms, 60.0)
+    assert "loop lag" in m.shed_reason()
+    assert m.stats.loop_lag_sustained_ms == 0.0
+    m.stats.loop_lag_ms = 0.0
+    for _ in range(5):
+        m.request_started()
+    assert "inflight" in m.shed_reason()
+    assert m.snapshot().inflight == 0.0  # no tick has seen that depth yet
+
+
+def test_the_watchdog_drops_the_frame_it_captured():
+    """A captured frame pins every local of the stalled call chain; the
+    watchdog keeps the formatted stack and nothing else (D16)."""
+    import gc
+    import threading
+    import weakref
+
+    class Held:
+        pass
+
+    m = LoadMonitor(stall_threshold_ms=20.0, stall_cooldown=0.0)
+    m._heartbeat = time.monotonic() - 10.0
+    dog = _StallWatchdog(m, threading.get_ident(), interval=0.01)
+    ref = []
+
+    def stalled_call():
+        held = Held()
+        ref.append(weakref.ref(held))
+        dog.start()
+        deadline = time.monotonic() + 5.0
+        while m._pending_stall is None and time.monotonic() < deadline:
+            time.sleep(0.01)
+
+    stalled_call()
+    try:
+        assert m._pending_stall is not None
+        assert "stalled_call" in m._pending_stall["stack"]
+        gc.collect()
+        assert ref[0]() is None  # the watchdog thread is still running
+    finally:
+        dog.stop_event.set()
+        dog.join(2.0)
 
 
 # ---------------------------------------------------------------------------
@@ -316,6 +424,71 @@ def test_sync_load_derates_in_quantized_steps_without_moving_the_epoch():
     # A derate is a price, not a directory fact: the epoch guards seats
     # and liveness only.
     assert p._epoch == epoch0
+
+
+def test_a_refresh_prices_the_nodes_that_report_and_those_off_full_price_only():
+    """Eight daemons and eight monitors of one process refresh the view every
+    second or two; priced node by node that was 0.7 ms a call at 1,024 nodes,
+    and calls that fall together hold the loop long enough for a sibling's
+    tick to read it as lag. Only a node the view has an entry for, or one
+    that stands off full price, can step."""
+    p = _jax_provider(nodes=12)
+    a, b, c = "10.0.0.0:5000", "10.0.0.1:5000", "10.0.0.2:5000"
+    view = _view_for({a: LoadVector(inflight=1792), b: LoadVector()})
+    asked = []
+    real = view.derate
+    view.derate = lambda addr: asked.append(addr) or real(addr)
+    p.sync_load(view)
+    assert sorted(asked) == [a, b] and p._nodes[a].reported_derate == 0.125
+    # A node whose report is gone from the view goes back to full price...
+    asked.clear()
+    gone = _view_for({b: LoadVector()})
+    real_gone = gone.derate
+    gone.derate = lambda addr: asked.append(addr) or real_gone(addr)
+    p.sync_load(gone)
+    assert sorted(asked) == [a, b] and p._nodes[a].reported_derate == 1.0
+    assert p._nodes[c].reported_derate == 1.0
+    # ...and a view that is no ClusterLoadView (no ``entries``) prices every node.
+    class Flat:
+        def derate(self, addr):
+            asked.append(addr)
+            return 0.5
+
+    asked.clear()
+    p.sync_load(Flat())
+    assert len(asked) == 12 and p._nodes[c].reported_derate == 0.5
+
+
+def test_a_view_is_built_from_the_rows_that_report_load():
+    rows = [_member(f"10.0.0.{i}:5000", "") for i in range(5)]
+    rows.append(_member("10.0.0.9:5000", LoadVector(inflight=3, epoch=time.time()).encode()))
+    view = ClusterLoadView.from_members(rows)
+    assert list(view.entries) == ["10.0.0.9:5000"] and view.derate("10.0.0.1:5000") == 1.0
+
+
+def test_a_price_on_the_edge_between_two_steps_does_not_flap():
+    """A node leaves the lattice step it is on only for a price at least 3/4
+    of a step away: a server whose loop runs ~7 ms late (its co-located
+    siblings' ticks ahead of its own) prices at 0.9375, the edge between 1.0
+    and 0.875, and every call would otherwise step it one way or the other."""
+    p = _jax_provider()
+    a, b = "10.0.0.0:5000", "10.0.0.1:5000"
+    for lag in (6.0, 7.5, 6.2, 7.9, 6.5, 9.0, 5.0):  # 0.917 .. 0.952: around the edge
+        p.sync_load(_view_for({a: LoadVector(loop_lag_ms=lag), b: LoadVector()}))
+        assert p._nodes[a].reported_derate == 1.0
+    assert p.place_gauges()["rio.load.derate_steps"] == 0.0
+    # A price that IS another step's is taken at once, to the nearest step...
+    p.sync_load(_view_for({a: LoadVector(loop_lag_ms=16.0), b: LoadVector()}))  # 0.862
+    assert p._nodes[a].reported_derate == 0.875
+    p.sync_load(_view_for({a: LoadVector(loop_lag_ms=100.0), b: LoadVector()}))  # 0.5
+    assert p._nodes[a].reported_derate == 0.5
+    assert p.place_gauges()["rio.load.derate_steps"] == 4.0  # 1 + 3 lattice steps
+    # ...and held on the way back as on the way there.
+    for lag in (80.0, 90.0, 72.0):  # 0.556 .. 0.581: nearer 0.5 than 3/4 of a step
+        p.sync_load(_view_for({a: LoadVector(loop_lag_ms=lag), b: LoadVector()}))
+        assert p._nodes[a].reported_derate == 0.5
+    p.sync_load(_view_for({a: LoadVector(loop_lag_ms=0.0), b: LoadVector()}))
+    assert p._nodes[a].reported_derate == 1.0 and p._nodes[b].reported_derate == 1.0
 
 
 async def test_derate_flip_does_not_discard_a_solve_in_flight():

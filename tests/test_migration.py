@@ -9,6 +9,7 @@ reminder-shard seat rows flow through the same ``apply_moves`` path.
 
 import asyncio
 import random
+import time
 
 import pytest
 
@@ -935,3 +936,308 @@ def test_server_gauges_cover_wired_subsystems():
     asyncio.run(
         run_integration_test(body, registry_builder=build_registry, num_servers=1)
     )
+
+
+# ---------------------------------------------------------------------------
+# The coordinator under sustained churn (PR 27): one record a plan, sockets a
+# process shares, nothing pulled where nothing can be
+# ---------------------------------------------------------------------------
+
+
+class Plain(ServiceObject):
+    """No ``__migrate_state__``: a hand-off has nothing volatile to carry."""
+
+    @handler
+    async def add(self, msg: Add, ctx: AppData) -> Totals:
+        return Totals()
+
+
+def test_a_plan_is_one_stage_with_its_slowest_burst_and_counters():
+    from rio_tpu import tracing
+    from rio_tpu.object_placement import ObjectPlacementItem
+
+    async def body(cluster: Cluster):
+        a, b = cluster.servers
+        for i in range(6):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"p{i}"), a.local_address)
+            )
+        await cluster.placement.update(
+            ObjectPlacementItem(ObjectId("Plain", "ghost"), "1.1.1.1:1")
+        )
+        tracing.clear_stages()
+        moves = [(f"Plain.p{i}", a.local_address, b.local_address) for i in range(6)]
+        moves.append(("Plain.ghost", "1.1.1.1:1", b.local_address))  # dead source
+        mgr = b.migration_manager  # neither source nor the ghost's: a coordinator
+        assert await mgr.apply_moves(moves) == 7
+        for i in range(6):
+            assert await cluster.placement.lookup(ObjectId("Plain", f"p{i}")) == b.local_address
+        st = mgr.stats
+        assert (st.plans, st.plan_bursts, st.plan_burst_keys, st.plan_flips) == (1, 1, 6, 1)
+        assert st.plan_burst_ms_max > 0.0
+        # The source ran the moves though it held no activation: pins, fences.
+        assert a.migration_manager.stats.seat_flips == 6
+        log = tracing.stage_log()
+        plan = [r for r in log if r[0] == "migrate.apply_moves"]
+        burst = [r for r in log if r[0] == "migrate.burst"]
+        flips = [r for r in log if r[0] == "migrate.flips"]
+        assert len(plan) == len(burst) == len(flips) == 1  # one record a plan, not a burst
+        assert burst[0][3] == flips[0][3] == "migrate.apply_moves"
+        assert plan[0][1] <= burst[0][1] <= burst[0][2] <= plan[0][2]
+
+    asyncio.run(run_integration_test(
+        body, registry_builder=lambda: Registry().add_type(Plain), num_servers=2,
+    ))
+
+
+def test_nothing_is_prefetched_for_a_type_without_volatile_state():
+    async def body(cluster: Cluster):
+        from rio_tpu.object_placement import ObjectPlacementItem
+
+        a, b = cluster.servers
+        for tname, n in (("Plain", 3), ("Counter", 3)):
+            for i in range(n):
+                await cluster.placement.update(
+                    ObjectPlacementItem(ObjectId(tname, f"x{i}"), a.local_address)
+                )
+        mgr = b.migration_manager
+        pulls = []
+        real = type(mgr).prefetch_pull
+
+        async def spy(self, source, items):
+            pulls.append([t for t, _ in items])
+            return await real(self, source, items)
+
+        type(mgr).prefetch_pull = spy
+        try:
+            plain = [(f"Plain.x{i}", a.local_address, b.local_address) for i in range(3)]
+            assert await mgr.apply_moves(plain) == 3
+            assert pulls == []  # two calls a burst saved: nothing to warm
+            held = [(f"Counter.x{i}", a.local_address, b.local_address) for i in range(3)]
+            assert await mgr.apply_moves(held) == 3
+            assert pulls == [["Counter"] * 3]
+        finally:
+            type(mgr).prefetch_pull = real
+        assert a.registry.exports_volatile("Counter") and not a.registry.exports_volatile("Plain")
+        assert a.registry.exports_volatile("NeverHeardOf")  # unknown: it may
+
+    asyncio.run(run_integration_test(
+        body, registry_builder=lambda: build_registry().add_type(Plain), num_servers=2,
+    ))
+
+
+def test_one_sources_small_bursts_to_many_targets_travel_in_one_call():
+    """A re-priced node sheds a row or two to each of many targets. A call a
+    (source, target) pair is a round trip and a membership read each, two at
+    a time a source (``per_node_inflight``): the hand-offs of one derate step
+    at 1,024 members held the loop for seconds, which stepped the derate
+    again. They travel in ONE ``MigrateSpread`` a source (up to ``batch_size``
+    keys); a full burst still travels alone, as a ``MigrateBatch``."""
+    from rio_tpu.migration import MigrateBatch, MigrateSpread
+    from rio_tpu.object_placement import ObjectPlacementItem
+
+    async def body(cluster: Cluster):
+        a, b, c, d, coord = cluster.servers
+        targets = [b.local_address, c.local_address, d.local_address]
+        for i in range(6):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"s{i}"), a.local_address)
+            )
+        for i in range(4):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"f{i}"), b.local_address)
+            )
+        mgr = coord.migration_manager  # batch_size 4 (the helper's config)
+        sent = []
+        control = mgr._get_client("control")
+        real = control.send
+
+        async def spy(type_name, object_id, msg, **kw):
+            sent.append((type(msg), object_id))
+            return await real(type_name, object_id, msg, **kw)
+
+        control.send = spy
+        membership_reads = []
+        storage = a.migration_manager.members_storage
+        real_active, real_is = storage.active_members, storage.is_active
+
+        async def active_members():
+            membership_reads.append("all")
+            return await real_active()
+
+        async def is_active(address):
+            membership_reads.append(address)
+            return await real_is(address)
+
+        storage.active_members, storage.is_active = active_members, is_active
+        try:
+            # Two rows to each of three targets from a; a full burst from b.
+            moves = [(f"Plain.s{i}", a.local_address, targets[i % 3]) for i in range(6)]
+            moves += [(f"Plain.f{i}", b.local_address, c.local_address) for i in range(4)]
+            assert await mgr.apply_moves(moves) == 10
+        finally:
+            control.send = real
+            del storage.active_members, storage.is_active
+        for i in range(6):
+            assert await cluster.placement.lookup(ObjectId("Plain", f"s{i}")) == targets[i % 3]
+        st = mgr.stats
+        assert (st.plan_bursts, st.plan_burst_keys) == (4, 10)  # bursts are pairs, as ever
+        # a's three bursts (6 keys > batch_size 4): a spread of two and a
+        # burst; b's full burst alone.
+        assert sorted((t.__name__, to) for t, to in sent) == sorted([
+            ("MigrateSpread", a.local_address), ("MigrateBatch", a.local_address),
+            ("MigrateBatch", b.local_address),
+        ])
+        assert a.migration_manager.stats.batches == 3 and a.migration_manager.stats.batch_keys == 6
+        # The coordinator's one read a plan; on the sources one read a call: the
+        # whole table for the spread, the one row for a burst.
+        assert membership_reads.count("all") == 2 and len(membership_reads) == 4
+        assert set(membership_reads) - {"all"} <= set(targets)
+        # A target that left: its burst is refused, the others of the spread run.
+        for i in range(6):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"s{i}"), a.local_address)
+            )
+        host, _, port = d.local_address.rpartition(":")
+        await storage.set_inactive(host, int(port))
+        done, attempted = await a.migration_manager.migrate_spread(
+            [[b.local_address, [["Plain", "s0"]]], [d.local_address, [["Plain", "s1"]]],
+             [c.local_address, [["Plain", "s2"]]]]
+        )
+        assert (done, attempted) == (2, 3)
+        assert await cluster.placement.lookup(ObjectId("Plain", "s1")) == a.local_address
+
+    asyncio.run(run_integration_test(
+        body, registry_builder=lambda: Registry().add_type(Plain), num_servers=5,
+        server_kwargs={"migration_config": MigrationConfig(batch_size=4)},
+    ))
+
+
+def test_fences_are_pruned_from_the_front_of_their_order():
+    from rio_tpu import migration
+
+    mgr = MigrationManager(
+        address="9.9.9.9:9", registry=Registry(), placement=LocalObjectPlacement(),
+        members_storage=LocalStorage(), app_data=AppData(),
+    )
+    now = time.monotonic()
+    old = now - migration.FENCE_TTL - 1
+    mgr._fenced = {("T", "a"): ("x", old), ("T", "b"): ("x", old), ("T", "c"): ("x", now)}
+    mgr._prune_fences()
+    assert list(mgr._fenced) == [("T", "c")]
+
+
+def test_managers_of_one_process_share_their_sockets_by_lane():
+    from rio_tpu.migration import open_connections
+    from rio_tpu.otel import server_gauges
+
+    async def body(cluster: Cluster):
+        a, b, c = cluster.servers
+        ma, mb = a.migration_manager, b.migration_manager
+        control, inbox = ma._get_client("control"), ma._get_client("inbox")
+        assert control is not inbox and control._conns is not inbox._conns
+        assert mb._get_client("control") is not control
+        assert mb._get_client("control")._conns is control._conns  # the process's
+        assert mb._get_client("inbox")._conns is inbox._conns
+        # Both dial c's inbox: one bundle, one socket.
+        from rio_tpu.migration import INBOX_TYPE, InstallState, MigrationAck
+
+        for mgr in (ma, mb, ma, mb):
+            ack = await mgr._get_client("inbox").send(
+                INBOX_TYPE, c.local_address,
+                InstallState(type_name="Counter", object_id="k", payload=b"\x80"),
+                returns=MigrationAck,
+            )
+            assert ack.ok
+        assert open_connections() == 1
+        assert server_gauges(a)["rio.migrate.open_connections"] == 1.0
+        # The first to close leaves the lane to the other; the last closes it.
+        ma.close()
+        assert open_connections() == 1
+        mb.close()
+        assert open_connections() == 0
+
+    asyncio.run(run_integration_test(body, registry_builder=build_registry, num_servers=3))
+
+
+def test_bare_flips_give_the_loop_a_turn():
+    """Thousands of uncontended ``update()`` calls never suspend: the
+    coordinator yields between runs of them so requests keep being served."""
+
+    async def run():
+        from rio_tpu.migration import _FLIPS_PER_TURN
+        from rio_tpu.object_placement import ObjectPlacementItem
+
+        members = LocalStorage()
+        placement = LocalObjectPlacement()
+        mgr = MigrationManager(
+            address="9.9.9.9:9", registry=Registry(), placement=placement,
+            members_storage=members, app_data=AppData(),
+        )
+        n = 4 * _FLIPS_PER_TURN
+        for i in range(n):
+            await placement.update(ObjectPlacementItem(ObjectId("Ghost", str(i)), "1.1.1.1:1"))
+        turns = 0
+
+        async def other_work():
+            nonlocal turns
+            while True:
+                turns += 1
+                await asyncio.sleep(0)
+
+        task = asyncio.create_task(other_work())
+        moved = await mgr.apply_moves(
+            [(f"Ghost.{i}", "1.1.1.1:1", "2.2.2.2:2") for i in range(n)]
+        )
+        task.cancel()
+        assert moved == n and mgr.stats.plan_flips == n
+        assert turns >= 4
+
+    asyncio.run(run())
+
+
+def test_a_burst_through_a_member_that_left_fails_at_once():
+    """Between a plan's snapshot and its burst the source may leave. Its
+    peers answer for it with DEALLOCATE; twenty re-tries of that (a second
+    and more, the client's default) would hold the whole plan, and with it
+    the next event's solve. The lanes give up after a few."""
+    import time
+
+    async def body(cluster: Cluster):
+        from rio_tpu.object_placement import ObjectPlacementItem
+
+        a, b, c = cluster.servers
+        gone = a.local_address
+        for i in range(3):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"p{i}"), gone)
+            )
+        # The membership view the coordinator reads still calls it active
+        # (the plan was made a moment ago); the node itself is gone.
+        cluster.tasks[0].cancel()
+        await asyncio.gather(cluster.tasks[0], return_exceptions=True)
+        host, _, port = gone.rpartition(":")
+        real = type(cluster.members).active_members
+
+        async def stale(self):
+            from rio_tpu.cluster.storage import Member
+
+            return [*await real(self), Member.from_address(gone, active=True)]
+
+        type(cluster.members).active_members = stale
+        try:
+            t0 = time.perf_counter()
+            moved = await c.migration_manager.apply_moves(
+                [(f"Plain.p{i}", gone, b.local_address) for i in range(3)]
+            )
+            took = time.perf_counter() - t0
+        finally:
+            type(cluster.members).active_members = real
+        assert moved == 0 and c.migration_manager.stats.aborted == 1
+        assert took < 0.5, took
+        for i in range(3):  # the rows stand for the next solve
+            assert await cluster.placement.lookup(ObjectId("Plain", f"p{i}")) == gone
+
+    asyncio.run(run_integration_test(
+        body, registry_builder=lambda: Registry().add_type(Plain), num_servers=3,
+    ))
