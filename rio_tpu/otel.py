@@ -162,6 +162,13 @@ def server_gauges(server: Any) -> dict[str, float]:
             # displaced from full subscriber queues — durable-stream fan-in
             # loss that the publish return value alone cannot show.
             gauges.update(router.gauges())
+        from .state import StateProvider
+
+        state_gauges = getattr(app_data.try_get(StateProvider), "gauges", None)
+        if state_gauges is not None:
+            # The state provider's own counters (SqliteState: rio.sqlite.*,
+            # statements and commits of its writer's group commit).
+            gauges.update(state_gauges())
     provider = getattr(server, "cluster_provider", None)
     gossip_stats = getattr(provider, "stats", None)
     if gossip_stats is not None:

@@ -11,6 +11,7 @@ from typing import Any
 
 from .. import codec
 from ..errors import StateNotFound
+from ..otel import stats_gauges
 from ..utils.sqlite import SqliteDb
 from . import StateProvider
 
@@ -59,6 +60,11 @@ class SqliteState(StateProvider):
             "WHERE object_kind=? AND object_id=? AND state_type=?",
             object_kind, object_id, state_type,
         )
+
+    def gauges(self) -> dict[str, float]:
+        """``rio.sqlite.{statements,commits,batch_max}``: statements per commit
+        is how often the writer's group commit engages (~1 = bypassed)."""
+        return stats_gauges(sqlite=self.db.stats)
 
     def close(self) -> None:
         self.db.close()
