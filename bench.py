@@ -1377,7 +1377,7 @@ def _host_provenance() -> dict:
 
 
 def rpc_throughput(baseline: float | None = None) -> dict:
-    """Actor data-plane msgs/sec per transport; also printed to stderr.
+    """Actor data-plane msgs/sec; also printed to stderr.
 
     Every msgs/s figure is ANCHORED to the sqlite baseline measured in the
     SAME session (``vs_sqlite`` ratio): the bench box's absolute throughput
@@ -1386,34 +1386,26 @@ def rpc_throughput(baseline: float | None = None) -> dict:
     """
     import asyncio
 
-    from rio_tpu import native
     from rio_tpu.utils.routing_live import measure_rpc_throughput
 
     if baseline is None:
         baseline = sqlite_baseline_rate()
-    transports = ["asyncio"] + (["native"] if native.get() is not None else [])
-    rates: dict = {
+    # 600 req/worker: long enough to amortize pool warm-up (the 400
+    # default under-reads the steady state by ~25%).
+    rate = asyncio.run(measure_rpc_throughput(requests_per_worker=600))
+    print(
+        f"# rpc throughput (2 servers, 64 workers): "
+        f"{rate:,.0f} msgs/sec = {rate / baseline:.2f}x in-session "
+        f"sqlite baseline",
+        file=sys.stderr,
+    )
+    # Keyed "asyncio" as in every earlier bank, so the history compares.
+    return {
         "sqlite_baseline_in_session": round(baseline),
         "host": _host_provenance(),
+        "asyncio": round(rate),
+        "asyncio_vs_sqlite": round(rate / baseline, 3),
     }
-    for transport in transports:
-        # 600 req/worker: long enough to amortize pool warm-up (the 400
-        # default under-reads the steady state by ~25%).
-        rate = asyncio.run(
-            measure_rpc_throughput(transport=transport, requests_per_worker=600)
-        )
-        rates[transport] = round(rate)
-        rates[f"{transport}_vs_sqlite"] = round(rate / baseline, 3)
-        note = ""
-        if transport == "native" and not native.engine_profitable():
-            note = " (engine demoted: single-core host, thread handoff is pure loss)"
-        print(
-            f"# rpc throughput ({transport}, 2 servers, 64 workers): "
-            f"{rate:,.0f} msgs/sec = {rate / baseline:.2f}x in-session "
-            f"sqlite baseline{note}",
-            file=sys.stderr,
-        )
-    return rates
 
 
 def rpc_egress(baseline: float | None = None) -> dict:
@@ -1422,76 +1414,54 @@ def rpc_egress(baseline: float | None = None) -> dict:
     The load is the standard pipelined echo shape: 64 concurrent senders
     share one client's pooled connections, so completed HEAD responses
     flush from done-callback waves on the server. Coalesced (the default)
-    joins each wave into ONE buffer per connection — one write syscall in
-    the asyncio transport, one engine handoff + sendmsg gather in the
-    native one; per-frame is the pre-coalescing egress (one syscall per
-    response). Interleaved batches, median per-batch ratio — only the
-    ratio is comparable across artifacts (host absolute rates drift
-    ±30-40%; PROFILE_RPC.md). The knob gates the same seam in BOTH
-    transports (rio_tpu/aio.py + rio_tpu/native/transport.py), so both are
-    measured when the native library is available.
+    joins each wave into ONE buffer per connection — one write syscall;
+    per-frame is the pre-coalescing egress (one syscall per response).
+    Interleaved batches, median per-batch ratio — only the ratio is
+    comparable across artifacts (host absolute rates drift ±30-40%;
+    PROFILE_RPC.md).
     """
     import asyncio
     import statistics
 
-    from rio_tpu import aio, native
+    from rio_tpu import aio
     from rio_tpu.utils.routing_live import measure_rpc_throughput
 
     if baseline is None:
         baseline = sqlite_baseline_rate()
+    env_default = aio._EGRESS_COALESCE
+    # 5 batches, like the batch-decode A/B: a syscall-count delta is a few
+    # percent on loopback and needs the extra pairs to resolve out of
+    # scheduler noise.
+    per_frame, coalesced = [], []
     try:
-        from rio_tpu.native import transport as native_transport
-    except Exception:  # pragma: no cover - native build unavailable
-        native_transport = None
-
-    def set_coalesce(enabled: bool) -> None:
-        aio._EGRESS_COALESCE = enabled
-        if native_transport is not None:
-            native_transport._EGRESS_COALESCE = enabled
-
-    env_default = os.environ.get("RIO_TPU_EGRESS_COALESCE", "1") != "0"
-    out: dict = {
+        for _ in range(5):
+            aio._EGRESS_COALESCE = False
+            per_frame.append(asyncio.run(
+                measure_rpc_throughput(requests_per_worker=600)
+            ))
+            aio._EGRESS_COALESCE = True
+            coalesced.append(asyncio.run(
+                measure_rpc_throughput(requests_per_worker=600)
+            ))
+    finally:
+        aio._EGRESS_COALESCE = env_default
+    ratio = statistics.median(c / p for p, c in zip(per_frame, coalesced))
+    print(
+        f"# rpc egress (coalesced vs per-frame flush, paired): "
+        f"{coalesced[-1]:,.0f} vs {per_frame[-1]:,.0f} msgs/sec = {ratio:.3f}x",
+        file=sys.stderr,
+    )
+    # Keyed "asyncio" as in every earlier bank, so the history compares.
+    return {
         "sqlite_baseline_in_session": round(baseline),
         "host": _host_provenance(),
+        "asyncio": {
+            "per_frame": [round(r) for r in per_frame],
+            "coalesced": [round(r) for r in coalesced],
+            "coalesced_vs_per_frame": round(ratio, 3),
+            "vs_sqlite": round(coalesced[-1] / baseline, 3),
+        },
     }
-    transports = ["asyncio"] + (["native"] if native.get() is not None else [])
-    try:
-        for transport in transports:
-            # 5 batches, like the batch-decode A/B: a syscall-count delta
-            # is a few percent on loopback and needs the extra pairs to
-            # resolve out of scheduler noise.
-            per_frame, coalesced = [], []
-            for _ in range(5):
-                set_coalesce(False)
-                per_frame.append(asyncio.run(
-                    measure_rpc_throughput(
-                        transport=transport, requests_per_worker=600
-                    )
-                ))
-                set_coalesce(True)
-                coalesced.append(asyncio.run(
-                    measure_rpc_throughput(
-                        transport=transport, requests_per_worker=600
-                    )
-                ))
-            ratio = statistics.median(
-                c / p for p, c in zip(per_frame, coalesced)
-            )
-            out[transport] = {
-                "per_frame": [round(r) for r in per_frame],
-                "coalesced": [round(r) for r in coalesced],
-                "coalesced_vs_per_frame": round(ratio, 3),
-                "vs_sqlite": round(coalesced[-1] / baseline, 3),
-            }
-            print(
-                f"# rpc egress ({transport}, coalesced vs per-frame flush, "
-                f"paired): {coalesced[-1]:,.0f} vs {per_frame[-1]:,.0f} "
-                f"msgs/sec = {ratio:.3f}x",
-                file=sys.stderr,
-            )
-    finally:
-        set_coalesce(env_default)
-    return out
 
 
 def rpc_sharded(baseline: float | None = None) -> dict:
@@ -1513,16 +1483,12 @@ def rpc_sharded(baseline: float | None = None) -> dict:
       crc32 % N locally (``Client(shard_aware=True)``) vs redirect-
       following, plus the redirect-elimination audit (shard-aware clients
       must pay ZERO redirects for unplaced traffic).
-    * ``engine`` — N workers on the native transport vs asyncio (identity
-      ports only: the front-door listener is asyncio's), plus the
-      ``engine_profitable`` verdict the dispatch rule would apply.
     """
     import asyncio
     import shutil
     import statistics
     import tempfile
 
-    from rio_tpu import native
     from rio_tpu.sharded import ShardedServer, sqlite_members
     from rio_tpu.utils.routing_live import measure_rpc_external
 
@@ -1539,14 +1505,13 @@ def rpc_sharded(baseline: float | None = None) -> dict:
     nodes: list = []
     tmps: list[str] = []
 
-    def boot(workers, *, router=True, front_door=True, env=None,
-             server_kwargs=None):
+    def boot(workers, *, router=True, front_door=True, env=None):
         tmp = tempfile.mkdtemp(prefix="rio_sharded_bench_")
         tmps.append(tmp)
         node = ShardedServer(
             address="127.0.0.1:0", workers=workers, registry=echo,
             data_dir=tmp, router=router, front_door=front_door,
-            env=env, server_kwargs=server_kwargs,
+            env=env,
         )
         node.start()
         nodes.append(node)
@@ -1619,7 +1584,6 @@ def rpc_sharded(baseline: float | None = None) -> dict:
     out: dict = {
         "sqlite_baseline_in_session": round(baseline),
         "host": _host_provenance(),
-        "engine_profitable": native.engine_profitable(),
     }
     try:
         n = max(2, min(4, os.cpu_count() or 1))
@@ -1704,33 +1668,6 @@ def rpc_sharded(baseline: float | None = None) -> dict:
             f"dials",
             file=sys.stderr,
         )
-
-        # Native-engine A/B, identity ports only: the front-door socket is
-        # the asyncio transport's (the native engine owns its one
-        # listener). On a <2-core host engine_profitable() already says
-        # the handoff is pure loss — the measurement shows it anyway.
-        if native.get() is not None:
-            try:
-                node_async = boot(n, front_door=False)
-                node_native = boot(
-                    n, front_door=False,
-                    server_kwargs={"transport": "native"},
-                )
-                ar, nr, native_vs = paired(node_async, node_native)
-                out["engine"] = {
-                    "asyncio": ar, "native": nr,
-                    "native_vs_asyncio": native_vs,
-                }
-                print(
-                    f"# rpc sharded ({n} workers, native vs asyncio "
-                    f"transport, paired): {nr[-1]:,.0f} vs {ar[-1]:,.0f} "
-                    f"msgs/sec = {native_vs:.3f}x (engine_profitable="
-                    f"{out['engine_profitable']})",
-                    file=sys.stderr,
-                )
-            except Exception as e:
-                out["engine"] = {"error": repr(e)}
-                print(f"# rpc sharded engine A/B failed: {e!r}", file=sys.stderr)
     finally:
         for node in nodes:
             try:

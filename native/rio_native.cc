@@ -1,49 +1,23 @@
-// rio-tpu native data plane.
+// rio-tpu native wire codec.
 //
-// Two subsystems behind a plain-C ABI (consumed from Python via ctypes):
+// Behind a plain-C ABI (consumed from Python via ctypes): encoders/decoders
+// for the framework's envelope types (RequestEnvelope / ResponseEnvelope /
+// Subscription{Request,Response}) in the exact positional-msgpack layout of
+// rio_tpu/codec.py + rio_tpu/protocol.py, plus an incremental
+// length-delimited frame reader. The reference implements this layer with
+// tokio's LengthDelimitedCodec + bincode (rio-rs/src/service.rs:370-378,
+// client/mod.rs:199-203). The served path runs the Python codec; this one is
+// the independent implementation tests/test_native.py holds it to, byte for
+// byte.
 //
-//  1. Wire codec — encoders/decoders for the framework's envelope types
-//     (RequestEnvelope / ResponseEnvelope / Subscription{Request,Response})
-//     in the exact positional-msgpack layout of rio_tpu/codec.py +
-//     rio_tpu/protocol.py, plus an incremental length-delimited frame
-//     reader. The reference implements this layer with tokio's
-//     LengthDelimitedCodec + bincode (rio-rs/src/service.rs:370-378,
-//     client/mod.rs:199-203); here it is C++ so the per-frame hot path
-//     does no Python-level packing.
-//
-//  2. Connection engine — an epoll-driven TCP server loop owning the
-//     listening socket, connection lifecycle, framing, and write
-//     backpressure on a dedicated native thread (the reference's accept +
-//     per-connection frame loops, rio-rs/src/server.rs:285-305 and
-//     service.rs:370-459). Completed frames are queued to Python through
-//     an eventfd + drain call; Python never touches a socket.
-//
-// No Python.h dependency: the library is pure C++/syscalls, so native
-// threads run fully outside the GIL.
+// No Python.h dependency: the library is pure C++.
 
-#include <algorithm>
-#include <atomic>
-#include <cerrno>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
 #include <deque>
-#include <mutex>
-#include <string>
-#include <thread>
-#include <unordered_map>
 #include <utility>
 #include <vector>
-
-#include <arpa/inet.h>
-#include <fcntl.h>
-#include <netinet/in.h>
-#include <netinet/tcp.h>
-#include <sys/epoll.h>
-#include <sys/eventfd.h>
-#include <sys/socket.h>
-#include <sys/uio.h>
-#include <unistd.h>
 
 namespace {
 
@@ -636,548 +610,6 @@ int rn_reader_next(void* rp, const uint8_t** data, uint32_t* len) {
   *data = r->current.data();
   *len = static_cast<uint32_t>(r->current.size());
   return 1;
-}
-
-// --- epoll connection engine ----------------------------------------------
-
-enum : uint32_t {
-  RN_EV_FRAME = 1,   // data = frame payload
-  RN_EV_CLOSED = 2,  // data = empty
-  RN_EV_OPENED = 3,  // data = "ip:port" of the peer
-};
-
-struct RnEventOut {
-  uint32_t type;
-  uint32_t pad;
-  uint64_t conn;
-  const uint8_t* data;
-  uint64_t len;
-};
-
-namespace {
-
-struct Conn {
-  int fd = -1;
-  std::vector<uint8_t> rbuf;
-  std::deque<std::vector<uint8_t>> wq;
-  size_t woff = 0;
-  bool epollout = false;
-  bool read_eof = false;       // peer half-closed; write side may still flow
-  bool close_pending = false;  // close requested; waiting for wq to flush
-  bool connecting = false;     // outbound connect in flight (await EPOLLOUT)
-};
-
-struct EngineEvent {
-  uint32_t type;
-  uint64_t conn;
-  std::vector<uint8_t> data;
-};
-
-struct Engine {
-  int epfd = -1;
-  int listen_fd = -1;
-  int notify_fd = -1;  // engine → Python (readable when events pending)
-  int wake_fd = -1;    // Python → engine (sends/closes queued)
-  uint16_t port = 0;
-  std::thread thr;
-  std::atomic<bool> stopping{false};
-
-  std::mutex mu;
-  std::vector<EngineEvent> events;    // pending for Python
-  std::vector<EngineEvent> drained;   // alive until next drain
-  std::vector<std::pair<uint64_t, std::vector<uint8_t>>> outq;
-  std::vector<uint64_t> closeq;
-  std::unordered_map<uint64_t, long long> backlog;  // unsent bytes per conn
-  struct ConnectReq {
-    uint64_t id;
-    uint32_t addr_be;  // IPv4, network order
-    uint16_t port;
-  };
-  std::vector<ConnectReq> connectq;
-
-  std::unordered_map<uint64_t, Conn> conns;  // IO-thread only
-  std::atomic<uint64_t> next_id{1};
-
-  void notify() {
-    uint64_t one = 1;
-    ssize_t rc = write(notify_fd, &one, 8);
-    (void)rc;
-  }
-  void push_event(uint32_t type, uint64_t conn, std::vector<uint8_t> data) {
-    {
-      std::lock_guard<std::mutex> lk(mu);
-      events.push_back(EngineEvent{type, conn, std::move(data)});
-    }
-    notify();
-  }
-};
-
-void set_nonblock(int fd) {
-  int fl = fcntl(fd, F_GETFL, 0);
-  fcntl(fd, F_SETFL, fl | O_NONBLOCK);
-}
-
-void engine_close_conn(Engine* e, uint64_t id, bool emit) {
-  auto it = e->conns.find(id);
-  if (it == e->conns.end()) return;
-  epoll_ctl(e->epfd, EPOLL_CTL_DEL, it->second.fd, nullptr);
-  close(it->second.fd);
-  e->conns.erase(it);
-  {
-    std::lock_guard<std::mutex> lk(e->mu);
-    e->backlog.erase(id);
-  }
-  if (emit) e->push_event(RN_EV_CLOSED, id, {});
-}
-
-// Flush as much of conn's write queue as the socket accepts; manage EPOLLOUT
-// interest. Gathers up to kFlushIov queued buffers into one sendmsg so a
-// pipelined response wave (or a burst of subscription frames) leaves in one
-// syscall instead of one per buffer. Returns false if the connection died
-// (or was finally closed).
-bool engine_flush(Engine* e, uint64_t id, Conn& c) {
-  constexpr size_t kFlushIov = 64;  // well under Linux's IOV_MAX (1024)
-  while (!c.wq.empty()) {
-    struct iovec iov[kFlushIov];
-    size_t niov = 0;
-    for (auto it = c.wq.begin(); it != c.wq.end() && niov < kFlushIov; ++it) {
-      size_t off = (niov == 0) ? c.woff : 0;
-      iov[niov].iov_base = const_cast<uint8_t*>(it->data() + off);
-      iov[niov].iov_len = it->size() - off;
-      ++niov;
-    }
-    struct msghdr mh {};
-    mh.msg_iov = iov;
-    mh.msg_iovlen = niov;
-    ssize_t n = sendmsg(c.fd, &mh, MSG_NOSIGNAL);
-    if (n > 0) {
-      {
-        std::lock_guard<std::mutex> lk(e->mu);
-        auto b = e->backlog.find(id);
-        if (b != e->backlog.end() && (b->second -= n) <= 0)
-          e->backlog.erase(b);
-      }
-      size_t left = static_cast<size_t>(n);
-      while (left > 0) {
-        auto& front = c.wq.front();
-        size_t avail = front.size() - c.woff;
-        if (left >= avail) {
-          left -= avail;
-          c.wq.pop_front();
-          c.woff = 0;
-        } else {
-          c.woff += left;
-          left = 0;
-        }
-      }
-      continue;
-    }
-    if (n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) break;
-    engine_close_conn(e, id, true);
-    return false;
-  }
-  if (c.wq.empty() && c.close_pending) {
-    engine_close_conn(e, id, false);
-    return false;
-  }
-  bool want = !c.wq.empty();
-  if (want != c.epollout) {
-    c.epollout = want;
-    epoll_event ev{};
-    ev.events = (c.read_eof ? 0u : EPOLLIN) | (want ? EPOLLOUT : 0u);
-    ev.data.u64 = id;
-    epoll_ctl(e->epfd, EPOLL_CTL_MOD, c.fd, &ev);
-  }
-  return true;
-}
-
-void engine_handle_readable(Engine* e, uint64_t id, Conn& c) {
-  char tmp[65536];
-  std::vector<EngineEvent> batch;
-  bool hard_close = false;  // poisoned stream / socket error
-  bool soft_eof = false;    // clean EOF; keep the write side open
-  while (true) {
-    ssize_t n = recv(c.fd, tmp, sizeof(tmp), 0);
-    if (n > 0) {
-      c.rbuf.insert(c.rbuf.end(), tmp, tmp + n);
-      if (!extract_frames(c.rbuf, [&](const uint8_t* p, size_t flen) {
-            batch.push_back(
-                EngineEvent{RN_EV_FRAME, id, std::vector<uint8_t>(p, p + flen)});
-          })) {
-        // Poisoned stream: drop the connection (service.py does the same).
-        hard_close = true;
-        break;
-      }
-      continue;
-    }
-    if (n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) break;
-    if (n == 0) {
-      // Half-close: a request that arrived in this same burst
-      // (write-then-shutdown peers) must still be dispatched AND answered,
-      // so the frames queue first, CLOSED follows them, and the fd stays
-      // open for writes until Python closes it after responding.
-      soft_eof = true;
-    } else {
-      hard_close = true;
-    }
-    break;
-  }
-  if (!batch.empty()) {
-    {
-      std::lock_guard<std::mutex> lk(e->mu);
-      for (auto& ev : batch) e->events.push_back(std::move(ev));
-    }
-    e->notify();
-  }
-  if (hard_close) {
-    engine_close_conn(e, id, true);
-  } else if (soft_eof && !c.read_eof) {
-    c.read_eof = true;
-    epoll_event ev{};
-    ev.events = c.epollout ? EPOLLOUT : 0u;
-    ev.data.u64 = id;
-    epoll_ctl(e->epfd, EPOLL_CTL_MOD, c.fd, &ev);
-    e->push_event(RN_EV_CLOSED, id, {});
-  }
-}
-
-void engine_accept_all(Engine* e) {
-  while (true) {
-    sockaddr_in peer{};
-    socklen_t plen = sizeof(peer);
-    int fd = accept4(e->listen_fd, reinterpret_cast<sockaddr*>(&peer), &plen,
-                     SOCK_NONBLOCK | SOCK_CLOEXEC);
-    if (fd < 0) return;
-    int one = 1;
-    setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
-    uint64_t id = e->next_id.fetch_add(1);
-    Conn c;
-    c.fd = fd;
-    e->conns.emplace(id, std::move(c));
-    epoll_event ev{};
-    ev.events = EPOLLIN;
-    ev.data.u64 = id;
-    epoll_ctl(e->epfd, EPOLL_CTL_ADD, fd, &ev);
-    char ip[64];
-    inet_ntop(AF_INET, &peer.sin_addr, ip, sizeof(ip));
-    std::string addr = std::string(ip) + ":" + std::to_string(ntohs(peer.sin_port));
-    e->push_event(RN_EV_OPENED, id,
-                  std::vector<uint8_t>(addr.begin(), addr.end()));
-  }
-}
-
-// Initiate one queued outbound connect on the IO thread.
-void engine_start_connect(Engine* e, const Engine::ConnectReq& req) {
-  int fd = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK | SOCK_CLOEXEC, 0);
-  if (fd < 0) {
-    e->push_event(RN_EV_CLOSED, req.id, {});
-    return;
-  }
-  int one = 1;
-  setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
-  sockaddr_in addr{};
-  addr.sin_family = AF_INET;
-  addr.sin_port = htons(req.port);
-  addr.sin_addr.s_addr = req.addr_be;
-  int rc = connect(fd, reinterpret_cast<sockaddr*>(&addr), sizeof(addr));
-  if (rc < 0 && errno != EINPROGRESS) {
-    close(fd);
-    e->push_event(RN_EV_CLOSED, req.id, {});
-    return;
-  }
-  bool in_progress = (rc < 0);
-  Conn c;
-  c.fd = fd;
-  c.connecting = in_progress;
-  e->conns.emplace(req.id, std::move(c));
-  epoll_event ev{};
-  ev.events = in_progress ? EPOLLOUT : EPOLLIN;
-  ev.data.u64 = req.id;
-  epoll_ctl(e->epfd, EPOLL_CTL_ADD, fd, &ev);
-  if (!in_progress) e->push_event(RN_EV_OPENED, req.id, {});
-}
-
-void engine_handle_wake(Engine* e) {
-  uint64_t buf;
-  while (read(e->wake_fd, &buf, 8) == 8) {
-  }
-  std::vector<std::pair<uint64_t, std::vector<uint8_t>>> outs;
-  std::vector<uint64_t> closes;
-  std::vector<Engine::ConnectReq> connects;
-  {
-    std::lock_guard<std::mutex> lk(e->mu);
-    outs.swap(e->outq);
-    closes.swap(e->closeq);
-    connects.swap(e->connectq);
-  }
-  for (auto& req : connects) engine_start_connect(e, req);
-  for (auto& [id, data] : outs) {
-    auto it = e->conns.find(id);
-    if (it == e->conns.end()) {
-      // Send raced a close: the bytes will never be written, so the
-      // backlog they were counted into must be released (a stale entry
-      // would wedge the Python-side backpressure wait forever).
-      std::lock_guard<std::mutex> lk(e->mu);
-      auto b = e->backlog.find(id);
-      if (b != e->backlog.end() &&
-          (b->second -= static_cast<long long>(data.size())) <= 0)
-        e->backlog.erase(b);
-      continue;
-    }
-    it->second.wq.push_back(std::move(data));
-  }
-  // Flush every connection we touched (dedup via the map walk is fine at
-  // these sizes; typical batches touch a handful of conns).
-  for (auto& [id, data] : outs) {
-    (void)data;
-    auto it = e->conns.find(id);
-    if (it != e->conns.end()) engine_flush(e, id, it->second);
-  }
-  for (uint64_t id : closes) {
-    auto it = e->conns.find(id);
-    if (it == e->conns.end()) continue;
-    if (it->second.wq.empty())
-      engine_close_conn(e, id, false);
-    else
-      it->second.close_pending = true;  // close once the responses flush
-  }
-}
-
-void engine_loop(Engine* e) {
-  constexpr uint64_t kListenTag = 0;
-  constexpr uint64_t kWakeTag = UINT64_MAX;
-  epoll_event evs[128];
-  while (!e->stopping.load(std::memory_order_relaxed)) {
-    int n = epoll_wait(e->epfd, evs, 128, 500);
-    if (n < 0) {
-      if (errno == EINTR) continue;
-      break;
-    }
-    for (int i = 0; i < n; ++i) {
-      uint64_t tag = evs[i].data.u64;
-      if (tag == kListenTag) {
-        engine_accept_all(e);
-        continue;
-      }
-      if (tag == kWakeTag) {
-        engine_handle_wake(e);
-        continue;
-      }
-      auto it = e->conns.find(tag);
-      if (it == e->conns.end()) continue;
-      if (it->second.connecting) {
-        // Outbound connect resolved (EPOLLOUT) or failed (HUP/ERR).
-        int err = 0;
-        socklen_t elen = sizeof(err);
-        getsockopt(it->second.fd, SOL_SOCKET, SO_ERROR, &err, &elen);
-        if (err != 0 || (evs[i].events & (EPOLLHUP | EPOLLERR))) {
-          engine_close_conn(e, tag, true);
-          continue;
-        }
-        it->second.connecting = false;
-        // Reset write-interest tracking so engine_flush re-arms EPOLLOUT
-        // for bytes queued while the connect was in flight.
-        it->second.epollout = false;
-        epoll_event ev{};
-        ev.events = EPOLLIN;
-        ev.data.u64 = tag;
-        epoll_ctl(e->epfd, EPOLL_CTL_MOD, it->second.fd, &ev);
-        e->push_event(RN_EV_OPENED, tag, {});
-        engine_flush(e, tag, it->second);
-        continue;
-      }
-      if (evs[i].events & (EPOLLHUP | EPOLLERR)) {
-        engine_close_conn(e, tag, true);
-        continue;
-      }
-      if (evs[i].events & EPOLLOUT) {
-        if (!engine_flush(e, tag, it->second)) continue;
-        it = e->conns.find(tag);
-        if (it == e->conns.end()) continue;
-      }
-      if (evs[i].events & EPOLLIN) engine_handle_readable(e, tag, it->second);
-    }
-  }
-}
-
-}  // namespace
-
-// Creates the engine and (when host is non-empty) binds the listening
-// socket. host is a dotted quad ("0.0.0.0" for any); an empty host makes a
-// client-only engine with no listener. *port_inout carries the requested
-// port in and the actually-bound port out (0 for client-only). reuse_port
-// != 0 sets SO_REUSEPORT before bind (sharded workers: bind an identity
-// port against the supervisor's reservation, or share one front-door port
-// with kernel accept distribution). Returns nullptr on failure.
-void* rn_engine_create_opt(const char* host, uint16_t* port_inout,
-                           int32_t reuse_port) {
-  auto* e = new Engine();
-  bool want_listener = host != nullptr && host[0] != '\0';
-  e->epfd = epoll_create1(EPOLL_CLOEXEC);
-  e->notify_fd = eventfd(0, EFD_CLOEXEC | EFD_NONBLOCK);
-  e->wake_fd = eventfd(0, EFD_CLOEXEC | EFD_NONBLOCK);
-  if (want_listener)
-    e->listen_fd = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK | SOCK_CLOEXEC, 0);
-  if (e->epfd < 0 || e->notify_fd < 0 || e->wake_fd < 0 ||
-      (want_listener && e->listen_fd < 0)) {
-    for (int fd : {e->epfd, e->notify_fd, e->wake_fd, e->listen_fd})
-      if (fd >= 0) close(fd);
-    delete e;
-    return nullptr;
-  }
-  if (want_listener) {
-    int one = 1;
-    setsockopt(e->listen_fd, SOL_SOCKET, SO_REUSEADDR, &one, sizeof(one));
-    if (reuse_port)
-      setsockopt(e->listen_fd, SOL_SOCKET, SO_REUSEPORT, &one, sizeof(one));
-    sockaddr_in addr{};
-    addr.sin_family = AF_INET;
-    addr.sin_port = htons(*port_inout);
-    // Only dotted quads: the Python caller resolves hostnames. Refusing here
-    // (rather than widening to INADDR_ANY) keeps "localhost" from silently
-    // binding every interface.
-    if (inet_pton(AF_INET, host, &addr.sin_addr) != 1 ||
-        bind(e->listen_fd, reinterpret_cast<sockaddr*>(&addr), sizeof(addr)) < 0 ||
-        listen(e->listen_fd, 512) < 0) {
-      close(e->listen_fd);
-      close(e->epfd);
-      close(e->notify_fd);
-      close(e->wake_fd);
-      delete e;
-      return nullptr;
-    }
-    sockaddr_in bound{};
-    socklen_t blen = sizeof(bound);
-    getsockname(e->listen_fd, reinterpret_cast<sockaddr*>(&bound), &blen);
-    e->port = ntohs(bound.sin_port);
-    epoll_event ev{};
-    ev.events = EPOLLIN;
-    ev.data.u64 = 0;  // listen tag
-    epoll_ctl(e->epfd, EPOLL_CTL_ADD, e->listen_fd, &ev);
-  }
-  *port_inout = e->port;
-  epoll_event wev{};
-  wev.events = EPOLLIN;
-  wev.data.u64 = UINT64_MAX;  // wake tag
-  epoll_ctl(e->epfd, EPOLL_CTL_ADD, e->wake_fd, &wev);
-  return e;
-}
-
-// Legacy ABI kept for env-pinned prebuilt libraries (RIO_TPU_NATIVE_LIB):
-// the Python binding probes rn_engine_create_opt and falls back here.
-void* rn_engine_create(const char* host, uint16_t* port_inout) {
-  return rn_engine_create_opt(host, port_inout, 0);
-}
-
-// Queue an outbound connect; returns the pre-assigned conn id. The IO
-// thread emits RN_EV_OPENED on success or RN_EV_CLOSED on failure. host
-// must be a dotted quad (caller resolves names); returns 0 on bad input.
-uint64_t rn_engine_connect(void* ep, const char* host, uint16_t port) {
-  auto* e = static_cast<Engine*>(ep);
-  Engine::ConnectReq req{};
-  if (inet_pton(AF_INET, host, &req.addr_be) != 1) return 0;
-  req.id = e->next_id.fetch_add(1);
-  req.port = port;
-  {
-    std::lock_guard<std::mutex> lk(e->mu);
-    e->connectq.push_back(req);
-  }
-  uint64_t one = 1;
-  ssize_t rc = write(e->wake_fd, &one, 8);
-  (void)rc;
-  return req.id;
-}
-
-int rn_engine_notify_fd(void* ep) { return static_cast<Engine*>(ep)->notify_fd; }
-uint16_t rn_engine_port(void* ep) { return static_cast<Engine*>(ep)->port; }
-
-void rn_engine_start(void* ep) {
-  auto* e = static_cast<Engine*>(ep);
-  e->thr = std::thread(engine_loop, e);
-}
-
-// Drains up to max pending events. Payload pointers stay valid until the
-// next drain call (Python copies immediately). Also clears the notify
-// eventfd so the caller can re-arm its reader.
-int rn_engine_drain(void* ep, RnEventOut* out, int max) {
-  auto* e = static_cast<Engine*>(ep);
-  uint64_t buf;
-  while (read(e->notify_fd, &buf, 8) == 8) {
-  }
-  std::lock_guard<std::mutex> lk(e->mu);
-  e->drained.clear();
-  int n = static_cast<int>(std::min<size_t>(max, e->events.size()));
-  e->drained.assign(std::make_move_iterator(e->events.begin()),
-                    std::make_move_iterator(e->events.begin() + n));
-  e->events.erase(e->events.begin(), e->events.begin() + n);
-  for (int i = 0; i < n; ++i) {
-    auto& ev = e->drained[static_cast<size_t>(i)];
-    out[i].type = ev.type;
-    out[i].pad = 0;
-    out[i].conn = ev.conn;
-    out[i].data = ev.data.data();
-    out[i].len = ev.data.size();
-  }
-  if (!e->events.empty()) e->notify();  // more pending: keep fd readable
-  return n;
-}
-
-// Queues a pre-framed byte string for sending on conn.
-void rn_engine_send(void* ep, uint64_t conn, const uint8_t* data, uint32_t len) {
-  auto* e = static_cast<Engine*>(ep);
-  {
-    std::lock_guard<std::mutex> lk(e->mu);
-    e->outq.emplace_back(conn, std::vector<uint8_t>(data, data + len));
-    e->backlog[conn] += len;
-  }
-  uint64_t one = 1;
-  ssize_t rc = write(e->wake_fd, &one, 8);
-  (void)rc;
-}
-
-// Unsent bytes queued for conn — the write-backpressure signal the Python
-// subscription pump polls (the asyncio transport gets this for free from
-// `await writer.drain()`).
-long long rn_engine_backlog(void* ep, uint64_t conn) {
-  auto* e = static_cast<Engine*>(ep);
-  std::lock_guard<std::mutex> lk(e->mu);
-  auto it = e->backlog.find(conn);
-  return it == e->backlog.end() ? 0 : it->second;
-}
-
-void rn_engine_close_conn(void* ep, uint64_t conn) {
-  auto* e = static_cast<Engine*>(ep);
-  {
-    std::lock_guard<std::mutex> lk(e->mu);
-    e->closeq.push_back(conn);
-  }
-  uint64_t one = 1;
-  ssize_t rc = write(e->wake_fd, &one, 8);
-  (void)rc;
-}
-
-void rn_engine_stop(void* ep) {
-  auto* e = static_cast<Engine*>(ep);
-  if (e->thr.joinable()) {
-    e->stopping.store(true);
-    uint64_t one = 1;
-    ssize_t rc = write(e->wake_fd, &one, 8);
-    (void)rc;
-    e->thr.join();
-  }
-  for (auto& [id, c] : e->conns) close(c.fd);
-  e->conns.clear();
-  if (e->listen_fd >= 0) close(e->listen_fd);
-  if (e->epfd >= 0) close(e->epfd);
-  if (e->notify_fd >= 0) close(e->notify_fd);
-  if (e->wake_fd >= 0) close(e->wake_fd);
-  e->listen_fd = e->epfd = e->notify_fd = e->wake_fd = -1;
-}
-
-void rn_engine_free(void* ep) {
-  auto* e = static_cast<Engine*>(ep);
-  rn_engine_stop(e);
-  delete e;
 }
 
 }  // extern "C"

@@ -24,10 +24,10 @@ Design constraints, in order:
    contextvar around the send, ``InternalClientSender`` snapshots it into
    the queued command, and the dispatch path stamps it onto the (non-wire)
    ``RequestEnvelope.source`` field. Frames on TCP are byte-identical to
-   before — no codec or native change, old peers unaffected.
+   before — no codec change, old peers unaffected.
 
 The sampler also keeps plain TCP byte counters (``tcp_in_bytes`` /
-``tcp_out_bytes``, fed by both transports) — those are the honest
+``tcp_out_bytes``, fed by the transport) — those are the honest
 numerator of the ``bench.py --affinity`` bytes-over-TCP A/B: co-locating a
 chatty pair must move real frames off the socket, not just reclassify
 edges.

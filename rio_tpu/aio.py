@@ -1,14 +1,13 @@
-"""Protocol-based asyncio transport (the default data plane).
+"""Protocol-based asyncio transport (the data plane).
 
 ``asyncio.StreamReader``'s ``readexactly`` costs two coroutine round trips
 per frame plus wakeup/feed machinery; at rio-tpu's frame sizes that was
 ~30% of the request path.  These ``asyncio.Protocol`` classes do the
 framing inline in ``data_received`` (C-backed buffer handling in
 :class:`rio_tpu.codec.FrameReader`) and hand complete frame payloads
-straight to the dispatch loop — the same event-driven shape as the C++
-epoll engine (``native/rio_native.cc``), so both transports share
-semantics: per-connection ordered responses, streaming-mode switch on a
-subscription request, finish-in-flight on peer EOF.
+straight to the dispatch loop: per-connection ordered responses,
+streaming-mode switch on a subscription request, finish-in-flight on peer
+EOF.
 
 Concurrency model: handlers for one connection run **concurrently** (each
 actor still serializes its own handlers via its per-object lock), responses
@@ -242,10 +241,8 @@ class ServerConnProtocol(asyncio.Protocol):
             self._wake()
             # Inbound backpressure: MAX_CONCURRENT caps in-flight handlers
             # but not buffered frames — a fast pipelining client could grow
-            # _queue without bound (the native engine cuts such peers off at
-            # _MAX_PENDING_FRAMES).  Pausing the transport propagates real
-            # TCP backpressure instead; the dispatch loop resumes reads as
-            # it drains.
+            # _queue without bound.  Pausing the transport propagates real
+            # TCP backpressure; the dispatch loop resumes reads as it drains.
             if (
                 not self._reading_paused
                 and len(self._queue) + len(self._resp_q) > self.MAX_PENDING_FRAMES
@@ -268,8 +265,7 @@ class ServerConnProtocol(asyncio.Protocol):
             self._drain.set_result(None)
         if self._streaming and self._worker is not None:
             # A streaming worker blocks on the router queue, not on inbound
-            # frames; cancellation is the only way to stop it (same rule as
-            # the native transport).
+            # frames; cancellation is the only way to stop it.
             self._worker.cancel()
 
     def pause_writing(self) -> None:
@@ -599,8 +595,7 @@ class ServerConnProtocol(asyncio.Protocol):
 class ClientConnProtocol(asyncio.Protocol):
     """One outbound connection: framing + FIFO frame delivery.
 
-    Surface-compatible with :class:`rio_tpu.native.transport.NativeClientConn`
-    (``roundtrip`` / ``read_frame`` / ``write`` / ``close``), plus
+    ``roundtrip`` / ``read_frame`` / ``write`` / ``close``, with
     **pipelining**: multiple requests may be in flight at once.  The wire
     has no correlation ids (the reference's contract), but the server
     answers each connection's requests in order, so inbound frames resolve

@@ -18,7 +18,7 @@ from dataclasses import dataclass
 from time import perf_counter as _perf
 from typing import Any, AsyncIterator, Awaitable, Callable
 
-from .. import codec
+from .. import aio, codec
 from ..cluster.storage import MembershipStorage
 from ..errors import (
     ClientBuilderError,
@@ -61,8 +61,7 @@ DEFAULT_POOL_PER_SERVER = 8
 class _ServerConns:
     """Multiplexed bundle of framed connections to one server address.
 
-    Both transports (:class:`rio_tpu.aio.ClientConnProtocol` and the native
-    :class:`rio_tpu.native.transport.NativeClientConn`) support pipelining —
+    A connection (:class:`rio_tpu.aio.ClientConnProtocol`) pipelines:
     several in-flight requests per socket, responses matched FIFO (the
     server answers each connection in order). The pool therefore keeps up to
     ``limit`` sockets and up to ``PIPELINE_DEPTH`` in-flight requests per
@@ -80,13 +79,12 @@ class _ServerConns:
     PIPELINE_DEPTH = 32
 
     def __init__(
-        self, address: str, limit: int, timeout: float, engine=None,
+        self, address: str, limit: int, timeout: float,
         faults=None, identity: str = "",
     ) -> None:
         self.address = address
         self.limit = max(1, limit)
         self.timeout = timeout
-        self.engine = engine
         # Fault-injection handle (rio_tpu.faults.TransportFaults) + this
         # client's source identity for (src, dst) link rules; None in every
         # production path — the gates below are then never consulted.
@@ -104,15 +102,10 @@ class _ServerConns:
                 await self.faults.connect_gate(self.identity, self.address)
             except OSError as e:
                 raise ServerNotAvailable(f"{self.address}: {e}") from e
-        if self.engine is not None:
-            conn = await self.engine.connect(host, int(port), self.timeout)
-        else:
-            from .. import aio
-
-            try:
-                conn = await aio.connect(host, int(port), self.timeout)
-            except (OSError, asyncio.TimeoutError) as e:
-                raise ServerNotAvailable(f"{self.address}: {e}") from e
+        try:
+            conn = await aio.connect(host, int(port), self.timeout)
+        except (OSError, asyncio.TimeoutError) as e:
+            raise ServerNotAvailable(f"{self.address}: {e}") from e
         if self.faults is not None:
             conn = self.faults.wrap_conn(conn, self.identity, self.address)
         return conn
@@ -202,7 +195,6 @@ class Client:
         pool_per_server: int = DEFAULT_POOL_PER_SERVER,
         connect_timeout: float = DEFAULT_PING_TIMEOUT,
         backoff: ExponentialBackoff | None = None,
-        transport: str = "asyncio",
         placement_resolver: Callable[[str, str], Awaitable[str | None]] | None = None,
         membership_view_ttl: float = 1.0,
         read_scale: Any | None = None,
@@ -214,8 +206,6 @@ class Client:
         priority: int = 0,
         deadline_ms: int = 0,
     ) -> None:
-        if transport not in ("asyncio", "native", "auto"):
-            raise ValueError(f"unknown transport {transport!r}")
         self.members_storage = members_storage
         self.stats = ClientStats()
         # QoS defaults stamped on every send unless the call overrides them.
@@ -259,18 +249,6 @@ class Client:
         self._pool_per_server = pool_per_server
         self._connect_timeout = connect_timeout
         self._backoff = backoff or ExponentialBackoff()
-        # Resolve the native codec eagerly (may compile once) so the first
-        # send() doesn't do it inside the event loop.
-        from .. import native as _native
-
-        lib = _native.get()
-        self._client_engine = None
-        if transport == "native" or (transport == "auto" and _native.engine_profitable()):
-            from ..native.transport import ClientEngine
-
-            # Request and subscription connections ride the engine's IO
-            # thread; pings keep asyncio streams (cold path, gossip-rate).
-            self._client_engine = ClientEngine()
 
     # -- server/membership view (reference client/mod.rs:153-220) -----------
 
@@ -334,7 +312,6 @@ class Client:
         if pool is None:
             pool = _ServerConns(
                 address, self._pool_per_server, self._connect_timeout,
-                engine=self._client_engine,
                 faults=self._transport_faults, identity=self._identity,
             )
             self._conns[address] = pool
@@ -674,7 +651,7 @@ class Client:
                 try:
                     raw = await conn.roundtrip(frame_bytes)
                 except asyncio.CancelledError:
-                    # Caller timeout/cancel: both transports discard the
+                    # Caller timeout/cancel: the connection discards the
                     # orphaned response, so the shared pipelined socket stays
                     # usable — closing it would kill every sibling in-flight
                     # request for no reason.  But only while the connection
@@ -1006,16 +983,9 @@ class Client:
                 try:
                     address = await self._pick_address(tname, handler_id)
                     host, _, port = address.rpartition(":")
-                    if self._client_engine is not None:
-                        conn = await self._client_engine.connect(
-                            host, int(port), self._connect_timeout
-                        )
-                    else:
-                        from .. import aio
-
-                        conn = await aio.connect(
-                            host, int(port), self._connect_timeout
-                        )
+                    conn = await aio.connect(
+                        host, int(port), self._connect_timeout
+                    )
                     write_frame = conn.write
                     next_frame = conn.read_frame
                     close = conn.close
@@ -1083,8 +1053,6 @@ class Client:
         for pool in self._conns.values():
             pool.close()
         self._conns.clear()
-        if self._client_engine is not None:
-            self._client_engine.close()
 
 
 class ClientBuilder:
@@ -1122,13 +1090,6 @@ class ClientBuilder:
         """How long the cached active-servers view is trusted before a
         storage refetch."""
         self._view_ttl_value = seconds
-        return self
-
-    def transport(self, transport: str) -> "ClientBuilder":
-        """Socket/framing backend: "asyncio" (default), "native", or "auto"."""
-        if transport not in ("asyncio", "native", "auto"):
-            raise ClientBuilderError(f"unknown transport {transport!r}")
-        self._transport = transport
         return self
 
     def placement_resolver(
@@ -1176,7 +1137,6 @@ class ClientBuilder:
             pool_per_server=self._pool,
             connect_timeout=self._timeout,
             backoff=getattr(self, "_backoff_policy", None),
-            transport=getattr(self, "_transport", "asyncio"),
             placement_resolver=getattr(self, "_resolver", None),
             membership_view_ttl=getattr(self, "_view_ttl_value", 1.0),
             read_scale=getattr(self, "_read_scale_config", None),

@@ -15,9 +15,9 @@ Two layers:
 * **Framing** — ``FrameReader``/``frame``: 4-byte big-endian length prefix,
   matching tokio's ``LengthDelimitedCodec`` defaults.
 
-A C++ fast path for framing + envelope packing lives in
-:mod:`rio_tpu.native`; this module is the always-available reference
-implementation and the two are wire-compatible.
+A second, C++ implementation of framing + envelope packing lives in
+:mod:`rio_tpu.native` as the tests' byte-parity oracle; this module is the
+one the served path runs.
 """
 
 from __future__ import annotations

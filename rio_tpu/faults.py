@@ -761,7 +761,7 @@ class TransportFaults:
     :meth:`connect_gate` before dialing and wraps established connections
     via :meth:`wrap_conn`, so both connection-level partitions and
     frame-level drop/delay/reset are injectable without touching the
-    transports themselves.
+    transport itself.
     """
 
     def __init__(self, seed: int = 0) -> None:
@@ -840,7 +840,7 @@ class TransportFaults:
 class FaultyConn:
     """Framed-connection wrapper applying per-frame link verdicts.
 
-    Surface-compatible with both transports' client connections
+    Surface-compatible with the transport's client connections
     (``roundtrip``/``read_frame``/``write``/``close``/``closed``/
     ``pending``/``delivered``) so the pool treats it as any socket. A
     dropped or reset frame closes the underlying connection and raises

@@ -1,15 +1,12 @@
-"""ctypes binding for the C++ data plane (``native/rio_native.cc``).
+"""ctypes binding for the C++ wire codec (``native/rio_native.cc``).
 
-The native library provides:
-
-* a wire codec for the framework envelopes (exactly the byte layout of
-  :mod:`rio_tpu.protocol`) plus an incremental frame reader, and
-* an epoll connection engine that owns sockets + framing on a native
-  thread (see :mod:`rio_tpu.native.transport`).
-
-Everything degrades gracefully: :func:`get` returns ``None`` when the
-library can't be built/loaded (or ``RIO_TPU_NATIVE=0``), and callers fall
-back to the pure-Python paths, which are wire-compatible.
+The library is a second, independent implementation of the byte layout of
+:mod:`rio_tpu.protocol` (envelope encoders and decoders) and of
+:mod:`rio_tpu.codec`'s incremental frame reader. Nothing on the served path
+calls it: ``tests/test_native.py`` holds the Python codec to it byte for
+byte, and ``chip_smoke.py`` reports whether it built. :func:`get` returns
+``None`` when the library can't be built or loaded (or ``RIO_TPU_NATIVE=0``);
+:func:`status` says why.
 """
 
 from __future__ import annotations
@@ -36,20 +33,6 @@ _lib: "NativeLib | None | bool" = False  # False = not attempted yet
 _status = "absent: not attempted"  # outcome of the one load attempt, see status()
 
 
-class RnEvent(ctypes.Structure):
-    _fields_ = [
-        ("type", ctypes.c_uint32),
-        ("pad", ctypes.c_uint32),
-        ("conn", ctypes.c_uint64),
-        ("data", ctypes.POINTER(ctypes.c_uint8)),
-        ("len", ctypes.c_uint64),
-    ]
-
-
-EV_FRAME = 1
-EV_CLOSED = 2
-EV_OPENED = 3
-
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 _U32 = ctypes.c_uint32
 _U32P = ctypes.POINTER(ctypes.c_uint32)
@@ -63,7 +46,8 @@ def _ensure_built() -> tuple[Path | None, str]:
     ``"absent: <why>"``. Freshness is by CONTENT: the library is trusted
     only when the sha256 recorded beside it equals that of
     ``rio_native.cc`` — file mtimes do not survive a copied or archived
-    tree, and ``librio_native.so`` is not under version control.
+    tree. The committed ``librio_native.so`` carries the digest of the
+    committed source, so a checkout compiles only after an edit of it.
     """
     env_lib = os.environ.get("RIO_TPU_NATIVE_LIB")
     if env_lib:
@@ -81,14 +65,14 @@ def _ensure_built() -> tuple[Path | None, str]:
             return _SO, "loaded"
     except OSError:
         pass  # no digest recorded: the library's origin is unknown, rebuild
-    # Build beside the target and rename: workers of one ShardedServer
+    # Build beside the target and rename: the workers of one test run
     # may all find the library stale at once.
     tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
             [
                 os.environ.get("CXX", "g++"),
-                "-O2", "-std=c++17", "-fPIC", "-Wall", "-pthread",
+                "-O2", "-std=c++17", "-fPIC", "-Wall",
                 "-shared", "-o", str(tmp), str(_SRC),
             ],
             check=True,
@@ -191,40 +175,6 @@ class NativeLib:
             _U32P,
         ]
         dll.rn_reader_next.restype = ctypes.c_int
-
-        dll.rn_engine_create.argtypes = [ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint16)]
-        dll.rn_engine_create.restype = ctypes.c_void_p
-        try:
-            # Newer ABI with the SO_REUSEPORT flag; absent from env-pinned
-            # prebuilt libraries (RIO_TPU_NATIVE_LIB), which then refuse
-            # reuse_port loudly in the transport instead of ignoring it.
-            dll.rn_engine_create_opt.argtypes = [
-                ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint16), ctypes.c_int32,
-            ]
-            dll.rn_engine_create_opt.restype = ctypes.c_void_p
-            self.has_engine_opt = True
-        except AttributeError:
-            self.has_engine_opt = False
-        dll.rn_engine_notify_fd.argtypes = [ctypes.c_void_p]
-        dll.rn_engine_notify_fd.restype = ctypes.c_int
-        dll.rn_engine_port.argtypes = [ctypes.c_void_p]
-        dll.rn_engine_port.restype = ctypes.c_uint16
-        dll.rn_engine_start.argtypes = [ctypes.c_void_p]
-        dll.rn_engine_start.restype = None
-        dll.rn_engine_drain.argtypes = [ctypes.c_void_p, ctypes.POINTER(RnEvent), ctypes.c_int]
-        dll.rn_engine_drain.restype = ctypes.c_int
-        dll.rn_engine_send.argtypes = [ctypes.c_void_p, ctypes.c_uint64, ctypes.c_char_p, _U32]
-        dll.rn_engine_send.restype = None
-        dll.rn_engine_backlog.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-        dll.rn_engine_backlog.restype = ctypes.c_longlong
-        dll.rn_engine_connect.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint16]
-        dll.rn_engine_connect.restype = ctypes.c_uint64
-        dll.rn_engine_close_conn.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-        dll.rn_engine_close_conn.restype = None
-        dll.rn_engine_stop.argtypes = [ctypes.c_void_p]
-        dll.rn_engine_stop.restype = None
-        dll.rn_engine_free.argtypes = [ctypes.c_void_p]
-        dll.rn_engine_free.restype = None
 
     # -- codec ---------------------------------------------------------
 
@@ -471,30 +421,11 @@ class NativeFrameReader:
             self._lib._dll.rn_reader_free(handle)
 
 
-def engine_profitable() -> bool:
-    """Whether the ``auto`` transport should pick the C++ epoll engine.
-
-    The engine's win is running sockets + framing on a separate OS thread,
-    overlapping with the interpreter.  MEASURED on a single-core host that
-    becomes a pure loss: every message pays ~4 eventfd wakeups / context
-    switches of thread ping-pong with nothing to overlap (9.0k msgs/s
-    native vs 25k asyncio on the bench box).  So ``auto`` only picks the
-    engine when there is real parallelism to exploit; explicit
-    ``transport="native"`` always honors the caller.  Override with
-    ``RIO_TPU_FORCE_NATIVE=1`` for A/B measurements.
-    """
-    if os.environ.get("RIO_TPU_FORCE_NATIVE") == "1":
-        return get() is not None
-    if (os.cpu_count() or 1) < 2:
-        return False
-    return get() is not None
-
-
 def get() -> NativeLib | None:
     """Load (building on demand) the native library; None when unavailable.
 
-    A failed build or load still degrades to the wire-compatible Python
-    codec, but not invisibly: :func:`status` says what happened."""
+    A failed build or load is not invisible: :func:`status` says what
+    happened."""
     global _lib, _status
     if _lib is not False:
         return _lib  # type: ignore[return-value]

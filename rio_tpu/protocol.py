@@ -217,9 +217,9 @@ class ResponseEnvelope:
 
     def to_bytes(self) -> bytes:
         if self.error is None:
-            # None normalizes to bin0 (not nil) so asyncio and native servers
-            # emit byte-identical frames (native has no nil entry point; both
-            # decoders already normalize to b"").
+            # None normalizes to bin0 (not nil) so this and the C++ codec
+            # emit byte-identical frames (that one has no nil entry point;
+            # both decoders already normalize to b"").
             return codec.serialize([True, self.body or b""])
         return codec.serialize(
             [False, [int(self.error.kind), self.error.detail, self.error.payload]]
@@ -308,10 +308,10 @@ class UnknownFrameKind(SerializationError):
 
 # These helpers are deliberately pure Python.  The C++ codec
 # (``rio_tpu.native``) produces byte-identical frames (parity-locked by
-# ``tests/test_native.py``) and is used where C++ already owns the buffer
-# (the epoll engine's reply fast path); calling it per-frame from Python was
-# MEASURED SLOWER than the msgpack C extension — one ctypes round trip costs
-# more than packing a request-sized envelope — so the hot path stays here.
+# ``tests/test_native.py``) and serves as the tests' oracle only: calling it
+# per-frame from Python was MEASURED SLOWER than the msgpack C extension —
+# one ctypes round trip costs more than packing a request-sized envelope —
+# so the hot path stays here.
 
 
 def encode_request_frame(env: RequestEnvelope) -> bytes:

@@ -4,7 +4,7 @@ Until this subsystem, every request was equal — overload control was one
 binary SERVER_BUSY shed (rio_tpu/load) with no notion of *who* is asking or
 *how long* the answer is still useful. Orleans-style virtual-actor meshes
 put an admission/scheduling layer exactly here, between frame decode and
-handler dispatch; this module is that layer for both transports.
+handler dispatch; this module is that layer.
 
 Three mechanisms compose (each independently optional via config):
 
@@ -23,7 +23,7 @@ Three mechanisms compose (each independently optional via config):
   burning handler time on it only delays requests that are still wanted.
 
 The scheduler reorders handler STARTS only. Per-connection FIFO response
-order — the wire contract both transports implement with done-callback
+order — the wire contract the transport implements with done-callback
 flushes — is untouched: a delayed start just means that connection's
 response future resolves later, exactly like a slow handler.
 
@@ -145,13 +145,13 @@ class QosConfig:
     """Tuning for one node's :class:`QosScheduler`.
 
     Defaults are deliberately benign: no tenant rate limits, equal weights,
-    a concurrency cap matching the per-connection handler cap of both
-    transports, and queues deep enough that uniform traffic never queues.
+    a concurrency cap matching the transport's per-connection handler
+    cap, and queues deep enough that uniform traffic never queues.
     """
 
     # Node-wide concurrent handler starts the scheduler will grant. Beyond
-    # it, requests wait in their class queue (the per-connection transports
-    # additionally cap at 64 in-flight each, unchanged). Unclassified
+    # it, requests wait in their class queue (each connection
+    # additionally caps at 64 in-flight, unchanged). Unclassified
     # requests on an otherwise idle node bypass slot accounting entirely
     # (the zero-wrapper fast path); the cap governs classified traffic and
     # any traffic once classified holders or a queue are present.
@@ -218,7 +218,7 @@ class _Waiter:
 class QosScheduler:
     """Admission + handler-start scheduling for one server node.
 
-    Loop-affine like every other per-node subsystem: both transports call
+    Loop-affine like every other per-node subsystem: the transport calls
     it only from the server's event loop, so there are no locks. ``admit``
     is the synchronous front door (token bucket, queue caps, deadline
     stamping); ``run`` wraps the handler call with a start grant and the
@@ -291,7 +291,7 @@ class QosScheduler:
         return b
 
     def dispatch(self, call, env: RequestEnvelope):
-        """Admission + start grant in ONE synchronous step — the transports'
+        """Admission + start grant in ONE synchronous step — the transport's
         request entry point. Returns either a :class:`ResponseError` (shed;
         the handler never starts and the transport pushes it through the
         ordinary FIFO response path) or an awaitable resolving to the
@@ -394,7 +394,7 @@ class QosScheduler:
 
         The grant may resolve to a DEADLINE_EXCEEDED error instead (budget
         expired while parked) — then the handler never runs. Plain ``def``
-        on purpose: the transports both ``await`` the result and hand it
+        on purpose: the transport both ``await``s the result and hands it
         to ``create_task``, and returning the inner coroutine directly
         keeps the uniform fast path one coroutine deep instead of two.
         """
@@ -403,7 +403,7 @@ class QosScheduler:
             # the scheduler BY DESIGN: no slot accounting, no scope (the
             # ambient contextvar default is already the empty scope), and
             # 7 of 8 requests hand back the bare handler coroutine — zero
-            # wrapper frames. Its only backpressure is the transports'
+            # wrapper frames. Its only backpressure is the transport's
             # per-connection in-flight caps; the moment classified holders
             # fill the slots or a queue forms, admit/dispatch demote
             # unclassified requests to the full grant path and every

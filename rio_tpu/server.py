@@ -14,6 +14,7 @@ import contextlib
 import logging
 import time
 
+from .aio import ServerConnProtocol
 from .app_data import AppData
 from .cluster.membership_protocol import ClusterProvider
 from .cluster.storage import MembershipStorage
@@ -92,7 +93,6 @@ class Server:
         object_placement_provider: ObjectPlacement,
         app_data: AppData | None = None,
         http_members_address: str | None = None,
-        transport: str = "asyncio",
         advertise_address: str | None = None,
         reuse_port: bool = False,
         extra_listen_socks=None,
@@ -123,8 +123,6 @@ class Server:
         autoscale_config=None,
         qos_config=None,
     ) -> None:
-        if transport not in ("asyncio", "native", "auto"):
-            raise ValueError(f"unknown transport {transport!r}")
         self.requested_address = address
         # Explicit override for what goes into membership storage —
         # "host" or "host:port" (port 0/absent keeps the bound port). NAT'd
@@ -136,7 +134,6 @@ class Server:
         self.object_placement = object_placement_provider
         self.app_data = app_data or AppData()
         self.http_members_address = http_members_address
-        self.transport = transport
         # SO_REUSEPORT on the main listener: a sharded worker binds its
         # identity port against the supervisor's port reservation (and, on
         # kernels that distribute accepts, sibling workers can share one
@@ -159,7 +156,6 @@ class Server:
         self.reminder_daemon = None  # set by run() when enabled
 
         self._listener: asyncio.Server | None = None
-        self._native_transport = None
         self._local_addr: str | None = None
         # Batching/prefetch/in-flight knobs for the migration engine
         # (a rio_tpu.migration.MigrationConfig; None → defaults).
@@ -185,13 +181,6 @@ class Server:
         self._draining = ServerDraining()
         self._stopped = asyncio.Event()
         self._conn_tasks: set[asyncio.Task] = set()
-
-        # Resolve (building if stale) the native codec now, off the request
-        # path: the first encode otherwise triggers a synchronous compile
-        # inside the event loop.
-        from . import native as _native
-
-        _native.get()
 
         # Inject framework handles (reference server.rs wiring of AppData).
         self.app_data.set(self._admin)
@@ -267,10 +256,10 @@ class Server:
         # is stamped at bind(); the alarm set defaults to
         # ``health.default_rules()`` (``health_rules`` overrides).
         # Request-waterfall span ring (rio_tpu/spans): on by default — the
-        # transports feed it only for traced requests plus a 1-in-8 stride
+        # transport feeds it only for traced requests plus a 1-in-8 stride
         # of untraced ones (tail capture over ``spans_slo_ms``), so the
         # null fast path stays untouched. ``spans=False`` removes even the
-        # per-request stride check (the transports see no ring). The node
+        # per-request stride check (the transport sees no ring). The node
         # id is stamped at bind(); scraped via rio.Admin DumpSpans.
         self.spans = None
         if spans:
@@ -294,7 +283,7 @@ class Server:
             self.app_data.set(self.affinity)
         # Request QoS (rio_tpu/qos): opt-in via a QosConfig — tenants,
         # priorities, deadline budgets, weighted-fair dispatch. Disabled is
-        # FREE: both transports resolve None and dispatch exactly as before
+        # FREE: the transport resolves None and dispatches exactly as before
         # (no admit call, no wrapper). ``qos_config=True`` means defaults.
         self.qos = None
         if qos_config is not None:
@@ -360,67 +349,30 @@ class Server:
         if StreamStorage in self.app_data:
             await self.app_data.get(StreamStorage).prepare()
 
-    def _resolve_transport(self) -> str:
-        if self.transport == "auto":
-            from . import native
-
-            return "native" if native.engine_profitable() else "asyncio"
-        return self.transport
-
     async def bind(self) -> str:
         host, _, port = self.requested_address.rpartition(":")
         host = host or "0.0.0.0"
-        if self._resolve_transport() == "native":
-            import socket as _socket
+        def _track(task: asyncio.Task) -> None:
+            # Track per-connection workers so shutdown severs live
+            # connections (a stopped node must not keep serving).
+            self._conn_tasks.add(task)
+            task.add_done_callback(self._conn_tasks.discard)
 
-            from .native.transport import NativeServerTransport
-
-            if self.extra_listen_socks:
-                raise ServerError(
-                    "extra_listen_socks (the sharded front door) requires the "
-                    "asyncio transport — the native engine owns its one "
-                    "listener"
-                )
-            if host not in ("", "::", "0.0.0.0"):
-                # The engine takes dotted quads only; resolve names here,
-                # asynchronously — a blocking gethostbyname inside the
-                # transport ctor would stall every coroutine on a slow
-                # resolver (the asyncio path resolves async in start_server).
-                try:
-                    _socket.inet_aton(host)
-                except OSError:
-                    infos = await asyncio.get_running_loop().getaddrinfo(
-                        host, None, family=_socket.AF_INET, type=_socket.SOCK_STREAM
-                    )
-                    host = infos[0][4][0]
-            self._native_transport = NativeServerTransport(
-                self._service, host, int(port), reuse_port=self.reuse_port
+        loop = asyncio.get_running_loop()
+        factory = lambda: ServerConnProtocol(self._service, _track)  # noqa: E731
+        self._listener = await loop.create_server(
+            factory, host, int(port),
+            reuse_port=True if self.reuse_port else None,
+        )
+        for esock in self.extra_listen_socks:
+            # Same service, same protocol: a connection accepted on the
+            # front door is indistinguishable from one on the identity
+            # listener (redirects carry the identity address either way).
+            self._extra_listeners.append(
+                await loop.create_server(factory, sock=esock)
             )
-            bound_host, bound_port = host, self._native_transport.port
-        else:
-            from .aio import ServerConnProtocol
-
-            def _track(task: asyncio.Task) -> None:
-                # Track per-connection workers so shutdown severs live
-                # connections (a stopped node must not keep serving).
-                self._conn_tasks.add(task)
-                task.add_done_callback(self._conn_tasks.discard)
-
-            loop = asyncio.get_running_loop()
-            factory = lambda: ServerConnProtocol(self._service, _track)  # noqa: E731
-            self._listener = await loop.create_server(
-                factory, host, int(port),
-                reuse_port=True if self.reuse_port else None,
-            )
-            for esock in self.extra_listen_socks:
-                # Same service, same protocol: a connection accepted on the
-                # front door is indistinguishable from one on the identity
-                # listener (redirects carry the identity address either way).
-                self._extra_listeners.append(
-                    await loop.create_server(factory, sock=esock)
-                )
-            sock = self._listener.sockets[0]
-            bound_host, bound_port = sock.getsockname()[:2]
+        sock = self._listener.sockets[0]
+        bound_host, bound_port = sock.getsockname()[:2]
         self._local_addr = self._advertised(bound_host, bound_port)
         self.app_data.set(ServerInfo(self._local_addr))
         if self.journal is not None:
@@ -873,10 +825,8 @@ class Server:
         Reference ``server.rs:178-283``: all loops race under one select;
         any loop finishing tears the node down.
         """
-        if self._listener is None and self._native_transport is None:
+        if self._listener is None:
             await self.bind()
-        if self._native_transport is not None:
-            self._native_transport.start()
         tasks = [
             asyncio.ensure_future(self.cluster_provider.serve(self.local_address)),
             asyncio.ensure_future(self._consume_internal_commands()),
@@ -955,9 +905,6 @@ class Server:
             for t in tasks:
                 t.cancel()
             await asyncio.gather(*tasks, return_exceptions=True)
-            if self._native_transport is not None:
-                self._native_transport.close()
-                await self._native_transport.wait_closed()
             if self._listener is not None:
                 self._listener.close()
             for extra in self._extra_listeners:

@@ -74,7 +74,7 @@ class Service:
 
         # Communication-edge sampler (None when the sampler is off): the
         # dispatch path records (source → served object) edges through it,
-        # and both transports read it off the service for their TCP byte
+        # and the transport reads it off the service for its TCP byte
         # counters — same resolve-once pattern as ``spans``.
         self.affinity = app_data.try_get(EdgeSampler)
         from .migration import MigrationManager
@@ -110,14 +110,14 @@ class Service:
         from .spans import SpanRing
 
         # Request-waterfall span ring (None when span retention is off).
-        # Resolved here once so both transports share the same handle per
-        # connection; the transports own all phase stamping — the service
+        # Resolved here once so every connection shares the same handle;
+        # the transport owns all phase stamping — the service
         # request path is untouched (null fast path byte-identical).
         self.spans = app_data.try_get(SpanRing)
         from .qos import QosScheduler
 
         # Request QoS scheduler (None when the server was built without a
-        # qos_config): both transports read it off the service and run
+        # qos_config): the transport reads it off the service and runs
         # admission + handler-start grants between decode and dispatch —
         # the service request path itself is untouched.
         self.qos = app_data.try_get(QosScheduler)
@@ -778,6 +778,5 @@ class Service:
         return router.create_subscription(req.handler_type, req.handler_id)
 
     # The per-connection frame loop (reference service.rs:370-459) lives in
-    # the transports: rio_tpu/aio.py (asyncio Protocol) and
-    # rio_tpu/native/transport.py (C++ epoll engine). Both dispatch through
-    # this class, so semantics are defined once here.
+    # the transport, rio_tpu/aio.py (asyncio Protocol), which dispatches
+    # through this class.

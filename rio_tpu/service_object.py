@@ -51,7 +51,7 @@ class ReminderFired:
     """One durable-reminder tick, delivered as an ordinary request.
 
     Riding the existing request path (rather than a new frame kind) keeps
-    the wire format untouched: the native codec and both transports see a
+    the wire format untouched: both codecs and the transport see a
     plain message. ``due`` is the tick's scheduled time; ``missed`` counts
     whole periods lost before this fire (0 on a healthy schedule — the
     catch-up signal after an ownership gap).

@@ -10,7 +10,7 @@ completed request spans *go*. Three pieces:
   request whose total wall time crosses the ring's ``slo_ms`` is retained
   even when the head-unsampled traffic around it is not — the slow outlier
   survives with a fresh trace id and a ``tail=1`` attr.
-* :class:`Phases` — the per-request phase clock the transports carry
+* :class:`Phases` — the per-request phase clock the transport carries
   beside a decoded :class:`~rio_tpu.protocol.RequestEnvelope`:
   ``perf_counter`` stamps at frame receive, decode, dispatch-queue exit,
   handler start/end, response encode, and flush. Attached only when the
@@ -25,7 +25,7 @@ completed request spans *go*. Three pieces:
 The ring is deliberately **not** a :func:`rio_tpu.tracing.add_sink` sink:
 registering one flips the tracing layer's global enable and would drag
 every request onto the full span ceremony, defeating the null fast path
-cluster-wide. The transports feed it explicitly instead.
+cluster-wide. The transport feeds it explicitly instead.
 
 Client-side hops live in a process-local ring (:func:`arm_client_ring`)
 so ``admin trace`` can merge the *calling* process's send/await phases —
@@ -116,7 +116,7 @@ class SpanRecord:
 class SpanRing:
     """Bounded ring of :class:`SpanRecord`, appended from the event loop.
 
-    Single-writer by construction (both transports record from the
+    Single-writer by construction (the transport records from the
     server's loop thread), so there is no lock: ``record`` is a couple of
     attribute writes and one list store. When the ring is full the oldest
     record is overwritten and ``dropped`` incremented — recording NEVER
@@ -136,7 +136,7 @@ class SpanRing:
         self.dropped = 0  # records overwritten before anyone read them
         self.tail_captured = 0  # untraced-but-over-SLO requests retained
 
-    # -- write side (called from the transports, loop thread only) -----------
+    # -- write side (called from the transport, loop thread only) ------------
 
     def record(
         self,

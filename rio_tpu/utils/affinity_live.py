@@ -15,8 +15,8 @@ crossed a real loopback socket:
   each delivery is a cross-node hop (the cursor's local-first send
   redirects and falls back to the cluster client).
 * **blind phase** — traffic runs with the placement exactly as seated;
-  the per-server ``EdgeSampler`` TCP byte counters (fed by both
-  transports) price the phase.
+  the per-server ``EdgeSampler`` TCP byte counters (fed by the
+  transport) price the phase.
 * **feedback** — the per-node edge graphs are scraped OVER THE WIRE with
   the admin ``DumpEdges`` command, merged cluster-wide
   (:func:`rio_tpu.admin.cluster_edges`), installed via
@@ -131,7 +131,6 @@ async def measure_affinity_payoff(
     n_records: int = 256,
     pad_bytes: int = 4096,
     redelivery_period: float = 0.25,
-    transport: str = "asyncio",
     affinity_weight: float = 2.0,
     affinity_host_factor: float = 0.05,
     drain_timeout: float = 60.0,
@@ -176,7 +175,6 @@ async def measure_affinity_payoff(
                 registry=_build_registry(),
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
-                transport=transport,
                 app_data=ad,
                 reminder_daemon=True,
                 reminder_daemon_config=ReminderDaemonConfig(
@@ -202,7 +200,7 @@ async def measure_affinity_payoff(
             if len(await members.active_members()) >= 2:
                 break
             await asyncio.sleep(0.02)
-        client = Client(members, transport=transport)
+        client = Client(members)
 
         n_parts = storage.num_partitions
         keys = _partition_keys(STREAM, n_parts)
@@ -385,7 +383,6 @@ async def measure_sampler_overhead(
     requests_per_batch: int = 128,
     n_objects: int = 256,
     cycles: int = 16,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with the edge sampler off vs on (stride 8).
 
@@ -423,7 +420,6 @@ async def measure_sampler_overhead(
             for name in boot_order:
                 members, placement, tasks, servers = await boot_echo_cluster(
                     n_servers,
-                    transport=transport,
                     server_kwargs={"affinity_sampler": name == "on"},
                 )
                 tname = type_id(EchoActor)
@@ -434,7 +430,7 @@ async def measure_sampler_overhead(
                             servers[i % n_servers].local_address,
                         )
                     )
-                client = Client(members, transport=transport)
+                client = Client(members)
                 clusters[name] = (client, tasks, servers)
                 for i in range(n_objects):
                     await client.send(

@@ -116,7 +116,6 @@ async def measure_autoscale_idle_overhead(
     requests_per_batch: int = 64,
     n_objects: int = 256,
     batches: int = 24,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with autoscaling absent vs armed-but-pinned.
 
@@ -164,7 +163,6 @@ async def measure_autoscale_idle_overhead(
         for name, cfg in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 members=cfg["members"],
                 placement=cfg["placement"],
                 server_kwargs=cfg.get("server_kwargs"),
@@ -180,7 +178,7 @@ async def measure_autoscale_idle_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)

@@ -67,7 +67,6 @@ async def measure_faults_overhead(
     n_objects: int = 256,
     batches: int = 24,
     lookup_ops: int = 20_000,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with the fault wrappers absent vs installed-but-disabled.
 
@@ -97,7 +96,6 @@ async def measure_faults_overhead(
         for name, (members, placement) in storages.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 members=members,
                 placement=placement,
             )
@@ -114,7 +112,7 @@ async def measure_faults_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)

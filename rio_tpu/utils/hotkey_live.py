@@ -122,7 +122,6 @@ async def _run_once(
     write_fraction: float,
     seed: int,
     max_inflight: int = 12,
-    transport: str = "asyncio",
 ) -> dict:
     """Boot a fresh 3-node cluster, replay the seeded zipf stream open-loop,
     and return the latency distribution plus the subsystem counters."""
@@ -138,7 +137,6 @@ async def _run_once(
                 registry=Registry().add_type(Profile),
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
-                transport=transport,
                 replication_config=ReplicationConfig(
                     k=2, anti_entropy_interval=0.2
                 ),
@@ -275,8 +273,6 @@ async def measure_hotkey(
     work_s: float = 0.005,
     write_fraction: float = 0.06,
     seed: int = 7,
-    *,
-    transport: str = "asyncio",
 ) -> dict:
     """Read-through-primary vs replica-reads under the SAME zipf stream.
 
@@ -295,7 +291,6 @@ async def measure_hotkey(
         work_s=0.0,
         write_fraction=write_fraction,
         seed=seed,
-        transport=transport,
     )
     common = dict(
         n_keys=n_keys,
@@ -305,7 +300,6 @@ async def measure_hotkey(
         work_s=work_s,
         write_fraction=write_fraction,
         seed=seed,
-        transport=transport,
     )
     baseline = await _run_once(replica_reads=False, **common)
     replica = await _run_once(replica_reads=True, **common)

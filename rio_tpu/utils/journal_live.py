@@ -38,7 +38,6 @@ async def measure_journal_overhead(
     requests_per_batch: int = 64,
     n_objects: int = 256,
     batches: int = 24,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with the control-plane journal off vs on.
 
@@ -58,7 +57,6 @@ async def measure_journal_overhead(
         for name, journal_on in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 server_kwargs={"journal": journal_on},
             )
             # Identical pre-seating in both clusters (see tracing_live: a
@@ -74,7 +72,7 @@ async def measure_journal_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)

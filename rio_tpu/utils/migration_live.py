@@ -79,8 +79,6 @@ async def _drain_once(
     n_objects: int,
     payload_bytes: int,
     config: MigrationConfig,
-    *,
-    transport: str = "asyncio",
 ) -> dict:
     """Boot a fresh 2-server cluster, seat+warm N actors on node 0, drain
     them all to node 1 under ``config``, and return the measured numbers.
@@ -99,7 +97,6 @@ async def _drain_once(
                 registry=Registry().add_type(DrainActor),
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
-                transport=transport,
                 migration_config=config,
             )
             await s.prepare()
@@ -164,18 +161,12 @@ async def _drain_once(
 async def measure_migration_drain(
     n_objects: int = 1000,
     payload_bytes: int = 1024,
-    *,
-    transport: str = "asyncio",
 ) -> dict:
     """Per-key vs batched+prefetch drain of ``n_objects``, same session."""
     # Throwaway warm-up: codec schema caches, transport pools, first-GC.
-    await _drain_once(16, payload_bytes, MigrationConfig(), transport=transport)
-    per_key = await _drain_once(
-        n_objects, payload_bytes, per_key_config(), transport=transport
-    )
-    batched = await _drain_once(
-        n_objects, payload_bytes, MigrationConfig(), transport=transport
-    )
+    await _drain_once(16, payload_bytes, MigrationConfig())
+    per_key = await _drain_once(n_objects, payload_bytes, per_key_config())
+    batched = await _drain_once(n_objects, payload_bytes, MigrationConfig())
     out: dict = {
         "n_objects": n_objects,
         "payload_bytes": payload_bytes,

@@ -79,7 +79,6 @@ def build_burn_registry() -> Registry:
 async def _boot_burn_cluster(
     n_servers: int,
     *,
-    transport: str = "asyncio",
     server_kwargs: dict | None = None,
 ):
     """``boot_echo_cluster`` with the burn registry (same teardown shape)."""
@@ -94,7 +93,6 @@ async def _boot_burn_cluster(
                 registry=build_burn_registry(),
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
-                transport=transport,
                 **(server_kwargs or {}),
             )
             await s.prepare()
@@ -121,7 +119,6 @@ async def measure_qos_overhead(
     requests_per_batch: int = 16,
     n_objects: int = 256,
     batches: int = 48,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with the QoS scheduler off vs on, uniform traffic.
 
@@ -142,7 +139,6 @@ async def measure_qos_overhead(
         for name, qos_config in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 server_kwargs=(
                     {"qos_config": qos_config} if qos_config is not None else {}
                 ),
@@ -158,7 +154,7 @@ async def measure_qos_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)
@@ -230,7 +226,6 @@ async def measure_qos_flood(
     interactive_probes: int = 80,
     spin_s: float = 0.002,
     max_concurrent: int = 4,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B interactive latency under a bulk flood of one hot object.
 
@@ -255,14 +250,13 @@ async def measure_qos_flood(
     for name, qos_config in modes.items():
         members, placement, tasks, servers = await _boot_burn_cluster(
             n_servers,
-            transport=transport,
             server_kwargs=(
                 {"qos_config": qos_config} if qos_config is not None else {}
             ),
         )
-        bulk_client = Client(members, transport=transport, tenant="bulk")
+        bulk_client = Client(members, tenant="bulk")
         inter_client = Client(
-            members, transport=transport, tenant="frontend", priority=2
+            members, tenant="frontend", priority=2
         )
         stop = asyncio.Event()
         bulk_done = 0
@@ -335,13 +329,10 @@ async def measure_qos_flood(
     }
 
 
-async def measure_qos(*, transport: str = "asyncio", fast: bool = False) -> dict:
+async def measure_qos(*, fast: bool = False) -> dict:
     """Both halves of the ``bench.py --qos`` stage, paired in-session."""
-    overhead = await measure_qos_overhead(
-        transport=transport, batches=16 if fast else 48
-    )
+    overhead = await measure_qos_overhead(batches=16 if fast else 48)
     flood = await measure_qos_flood(
-        transport=transport,
         interactive_probes=40 if fast else 80,
     )
     return {"uniform": overhead, "flood": flood}

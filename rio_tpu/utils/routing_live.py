@@ -81,7 +81,6 @@ def _stats(hops: list[int]) -> LiveHopStats:
 async def boot_echo_cluster(
     n_servers: int,
     *,
-    transport: str = "asyncio",
     members=None,
     placement=None,
     server_kwargs: dict | None = None,
@@ -107,7 +106,6 @@ async def boot_echo_cluster(
                 registry=build_echo_registry(),
                 cluster_provider=LocalClusterProvider(members),
                 object_placement_provider=placement,
-                transport=transport,
                 **(server_kwargs or {}),
             )
             await s.prepare()
@@ -134,7 +132,6 @@ async def measure_route_hops_live(
     n_servers: int = 8,
     n_objects: int = 1024,
     seed: int = 0,
-    transport: str = "asyncio",
     placement=None,
     sample_size: int | None = None,
 ) -> dict[str, LiveHopStats]:
@@ -147,9 +144,7 @@ async def measure_route_hops_live(
     to run the cluster on a specific provider; allocation is concurrent,
     hop measurement sequential over ``sample_size`` (default: all) ids.
     """
-    members, placement, tasks, _servers = await boot_echo_cluster(
-        n_servers, transport=transport, placement=placement
-    )
+    members, placement, tasks, _servers = await boot_echo_cluster(n_servers, placement=placement)
     try:
         ids = [f"obj-{i}" for i in range(n_objects)]
         # Warm-up pass: allocate every object somewhere (random landing →
@@ -327,7 +322,6 @@ async def measure_rpc_throughput(
     n_workers: int = 64,
     requests_per_worker: int = 400,
     n_objects: int = 1024,
-    transport: str = "asyncio",
 ) -> float:
     """Messages/sec through the full actor data plane (real TCP loopback).
 
@@ -335,13 +329,9 @@ async def measure_rpc_throughput(
     connection pool) and round-robin over ``n_objects`` actors — the shape
     of the reference's only load artifact, the metric-aggregator 20k-send
     driver (``metric_aggregator_loadall.rs:26-37``), but concurrent.
-    ``transport`` selects the asyncio or the native (C++ epoll) data plane
-    on both servers and client.
     """
-    members, _placement, tasks, _servers = await boot_echo_cluster(
-        n_servers, transport=transport
-    )
-    client = Client(members, transport=transport)
+    members, _placement, tasks, _servers = await boot_echo_cluster(n_servers)
+    client = Client(members)
     try:
         return await _drive_echo_load(
             client, n_workers, requests_per_worker, n_objects
@@ -379,14 +369,13 @@ async def measure_rpc_external(
     n_workers: int = 64,
     requests_per_worker: int = 400,
     n_objects: int = 512,
-    transport: str = "asyncio",
 ) -> float:
     """Messages/sec against an EXTERNAL cluster (servers in other
     processes, e.g. a :class:`rio_tpu.sharded.ShardedServer`): same load
     shape as :func:`measure_rpc_throughput`, but this process runs only
     the client side. ``members`` is the shared membership view (e.g. the
     sharded node's sqlite storage)."""
-    client = Client(members, transport=transport)
+    client = Client(members)
     try:
         return await _drive_echo_load(
             client, n_workers, requests_per_worker, n_objects

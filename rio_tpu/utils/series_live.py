@@ -38,7 +38,6 @@ async def measure_series_overhead(
     n_objects: int = 256,
     batches: int = 24,
     sample_interval: float = 0.05,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with gauge time-series sampling off vs on.
 
@@ -57,7 +56,6 @@ async def measure_series_overhead(
         for name, series_on in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 server_kwargs={
                     "timeseries": series_on,
                     # The sampler rides the load loop: tick the loop at the
@@ -77,7 +75,7 @@ async def measure_series_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)

@@ -9,7 +9,7 @@ promise with the ``series_live`` discipline — two cluster configurations,
 identical traffic, one process:
 
 * **off** — servers booted with ``spans=False``: no ring, no phase
-  stamping, the transports' pre-waterfall paths byte-for-byte.
+  stamping, the transport's pre-waterfall path byte-for-byte.
 * **on** — retention enabled with head sampling OFF and tail capture
   ARMED at an aggressive SLO (default 1 ms — far below the shipping
   250 ms default), so the priced configuration actually exercises the
@@ -40,7 +40,6 @@ async def measure_spans_overhead(
     n_objects: int = 256,
     batches: int = 24,
     slo_ms: float = 1.0,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the RPC loop with span retention off vs on (tail capture armed).
 
@@ -60,7 +59,6 @@ async def measure_spans_overhead(
         for name, spans_on in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 server_kwargs={
                     "spans": spans_on,
                     # A tight SLO keeps tail capture genuinely firing under
@@ -80,7 +78,7 @@ async def measure_spans_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks, servers)
             for i in range(n_objects):
                 await client.send(EchoActor, f"w{i}", Echo(value=i), returns=Echo)

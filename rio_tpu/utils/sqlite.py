@@ -21,6 +21,7 @@ import contextlib
 import dataclasses
 import sqlite3
 import threading
+import time
 import weakref
 from collections import deque
 from typing import Any
@@ -41,9 +42,21 @@ class SqliteStats:
 
 def _connect(path: str) -> sqlite3.Connection:
     conn = sqlite3.connect(path)
-    conn.execute("PRAGMA journal_mode=WAL")
     conn.execute("PRAGMA busy_timeout=5000")
-    return conn
+    # The switch to WAL wants the file to itself, and SQLite refuses two
+    # openers that ask at once without calling the busy handler (it would
+    # deadlock): the workers of one ShardedServer open a fresh file
+    # together. Ask again; on a file already in WAL it is a read.
+    deadline = time.monotonic() + 5.0
+    while True:
+        try:
+            conn.execute("PRAGMA journal_mode=WAL")
+            return conn
+        except sqlite3.OperationalError as e:
+            if "locked" not in str(e) or time.monotonic() > deadline:
+                conn.close()
+                raise
+            time.sleep(0.005)
 
 
 class _Writer:

@@ -55,7 +55,6 @@ async def measure_streams_overhead(
     publishes_per_batch: int = 96,
     batches: int = 12,
     n_keys: int = 16,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B the stream data path with the redelivery backstop idle vs ticking.
 
@@ -102,7 +101,6 @@ async def measure_streams_overhead(
                     registry=build_echo_registry(),
                     cluster_provider=LocalClusterProvider(members),
                     object_placement_provider=placement,
-                    transport=transport,
                     app_data=ad,
                     **server_kwargs,
                 )
@@ -115,7 +113,7 @@ async def measure_streams_overhead(
                 if len(await members.active_members()) >= n_servers:
                     break
                 await asyncio.sleep(0.02)
-            client = Client(members, transport=transport)
+            client = Client(members)
             await client.subscribe_stream(
                 STREAM, GROUP, EchoActor, redelivery_period=cfg["period"]
             )

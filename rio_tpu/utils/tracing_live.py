@@ -53,7 +53,6 @@ async def measure_tracing_overhead(
     requests_per_batch: int = 64,
     n_objects: int = 256,
     batches: int = 24,
-    transport: str = "asyncio",
 ) -> dict:
     """A/B/C the RPC loop across the three observability configurations.
 
@@ -78,7 +77,6 @@ async def measure_tracing_overhead(
         for name, cfg in modes.items():
             members, placement, tasks, servers = await boot_echo_cluster(
                 n_servers,
-                transport=transport,
                 server_kwargs={"metrics": cfg["metrics"]},
             )
             # Seat object i on server i%N in EVERY cluster before first
@@ -97,7 +95,7 @@ async def measure_tracing_overhead(
                         servers[i % n_servers].local_address,
                     )
                 )
-            client = Client(members, transport=transport)
+            client = Client(members)
             clusters[name] = (client, tasks)
             # Warm untimed: placement, activation, connection pools, codec
             # caches — and one full-traffic pass per tracing config so

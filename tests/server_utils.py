@@ -87,7 +87,6 @@ async def run_integration_test(
     placement: ObjectPlacement | None = None,
     gossip: bool = False,
     provider_builder: Callable[[LocalStorage], ClusterProvider] | None = None,
-    transport: str = "asyncio",
     server_kwargs: dict | None = None,
     app_data_builder: Callable[[], "AppData"] | None = None,
 ) -> None:
@@ -114,7 +113,6 @@ async def run_integration_test(
             registry=registry_builder(),
             cluster_provider=provider,
             object_placement_provider=placement,
-            transport=transport,
             **extra,
         )
         await server.prepare()

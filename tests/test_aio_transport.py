@@ -10,6 +10,8 @@ response must be discarded, not delivered to the next waiter).
 
 import asyncio
 
+import pytest
+
 from rio_tpu import (
     AppData,
     LocalObjectPlacement,
@@ -211,61 +213,6 @@ def test_subscription_switch_flushes_pipeline_first():
     asyncio.run(body())
 
 
-def test_native_transport_pipelining_invariants():
-    """The C++ engine path honors the same FIFO + orphan-discard contract."""
-    from rio_tpu import native as native_mod
-
-    if native_mod.get() is None:
-        import pytest
-
-        pytest.skip("native library unavailable")
-    from rio_tpu.native.transport import ClientEngine, NativeServerTransport
-
-    async def body():
-        members, placement = LocalStorage(), LocalObjectPlacement()
-        server = Server(
-            address="127.0.0.1:0",
-            registry=Registry().add_type(SleepyActor),
-            cluster_provider=LocalClusterProvider(members),
-            object_placement_provider=placement,
-            transport="native",
-        )
-        await server.prepare()
-        addr = await server.bind()
-        task = asyncio.create_task(server.run())
-        for _ in range(100):
-            if await members.active_members():
-                break
-            await asyncio.sleep(0.02)
-        host, _, port = addr.rpartition(":")
-        engine = ClientEngine()
-        try:
-            conn = await engine.connect(host, int(port), 2.0)
-            # FIFO under out-of-order completion
-            slow = asyncio.ensure_future(conn.roundtrip(_frame("na", 1, delay_ms=120)))
-            await asyncio.sleep(0.01)
-            fast = asyncio.ensure_future(conn.roundtrip(_frame("nb", 2, delay_ms=0)))
-            r1, r2 = await asyncio.gather(slow, fast)
-            assert deserialize(decode_response(r1).body, Tagged).tag == 1
-            assert deserialize(decode_response(r2).body, Tagged).tag == 2
-            # orphan discard after cancellation
-            doomed = asyncio.ensure_future(conn.roundtrip(_frame("nc", 7, delay_ms=80)))
-            await asyncio.sleep(0.01)
-            doomed.cancel()
-            try:
-                await doomed
-            except asyncio.CancelledError:
-                pass
-            raw = await conn.roundtrip(_frame("nd", 8, delay_ms=100))
-            assert deserialize(decode_response(raw).body, Tagged).tag == 8
-        finally:
-            engine.close()
-            task.cancel()
-            await asyncio.gather(task, return_exceptions=True)
-
-    asyncio.run(body())
-
-
 def test_subscription_backpressure_bounds_server_memory():
     """A subscriber that stops reading must not grow server memory without
     bound: the streaming pump parks on pause_writing, the router's bounded
@@ -360,9 +307,8 @@ def test_server_inbound_backpressure_pauses_and_resumes_reads():
     """A pipelining flood beyond MAX_PENDING_FRAMES pauses the transport.
 
     MAX_CONCURRENT caps in-flight handlers but not buffered frames; without
-    pause_reading a fast client grows server memory without bound (the native
-    engine cuts such peers off at its _MAX_PENDING_FRAMES — the asyncio path
-    must propagate TCP backpressure instead). Regression for the round-3
+    pause_reading a fast client grows server memory without bound: the
+    transport must propagate TCP backpressure. Regression for the round-3
     advisor finding.
     """
 
@@ -419,47 +365,40 @@ def test_server_inbound_backpressure_pauses_and_resumes_reads():
     asyncio.run(body())
 
 
-def test_native_client_conn_pipelined_fifo_is_race_free():
-    """Responses resolve the issuing roundtrip even when a later roundtrip
-    starts before an earlier (already-resolved) one resumes.
 
-    Regression for the round-3 advisor 'high': the shared-Queue design let a
-    roundtrip issued after a response was queued steal that response from the
-    parked earlier caller. The futures-deque design resolves frames to their
-    FIFO slot inside the engine drain, so arrival/resume interleaving is
-    irrelevant.
-    """
 
-    async def body():
-        from rio_tpu.native.transport import NativeClientConn
+def _server_with_transport():
+    return Server(
+        address="127.0.0.1:0",
+        registry=Registry().add_type(SleepyActor),
+        cluster_provider=LocalClusterProvider(LocalStorage()),
+        object_placement_provider=LocalObjectPlacement(),
+        transport="asyncio",
+    )
 
-        class _Sink:
-            def send(self, conn_id, data):
-                pass
 
-        class _EngineStub:
-            _engine = _Sink()
+def _client_with_transport():
+    from rio_tpu import Client
 
-        conn = NativeClientConn(_EngineStub(), 1)
-        rt1 = asyncio.ensure_future(conn.roundtrip(b"r1"))
-        await asyncio.sleep(0)  # rt1's waiter registered, parked
-        conn._deliver(b"resp1")  # resolves rt1's future; rt1 NOT yet resumed
-        rt2 = asyncio.ensure_future(conn.roundtrip(b"r2"))
-        await asyncio.sleep(0)  # rt2 registered before rt1 resumes
-        conn._deliver(b"resp2")
-        assert await rt1 == b"resp1"
-        assert await rt2 == b"resp2"
+    return Client(LocalStorage(), transport="asyncio")
 
-        # Cancelled roundtrip: its orphan frame is discarded, one per slot.
-        rt3 = asyncio.ensure_future(conn.roundtrip(b"r3"))
-        await asyncio.sleep(0)
-        rt4 = asyncio.ensure_future(conn.roundtrip(b"r4"))
-        await asyncio.sleep(0)
-        rt3.cancel()
-        await asyncio.gather(rt3, return_exceptions=True)
-        conn._deliver(b"orphan")  # rt3's response -> dropped
-        conn._deliver(b"resp4")
-        assert await rt4 == b"resp4"
-        assert conn.pending == 0
 
-    asyncio.run(body())
+def _builder_with_transport():
+    from rio_tpu.client import ClientBuilder
+
+    return ClientBuilder().members_storage(LocalStorage()).transport("asyncio")
+
+
+@pytest.mark.parametrize(
+    "call, error",
+    [
+        pytest.param(_server_with_transport, TypeError, id="Server"),
+        pytest.param(_client_with_transport, TypeError, id="Client"),
+        pytest.param(_builder_with_transport, AttributeError, id="ClientBuilder"),
+    ],
+)
+def test_transport_option_is_gone(call, error):
+    """There is one transport: even the value that used to be the default
+    is refused, by Python itself (no shim)."""
+    with pytest.raises(error, match="transport"):
+        call()

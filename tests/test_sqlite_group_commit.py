@@ -434,3 +434,37 @@ def test_server_gauges_carry_the_sqlite_counters_only_with_a_sqlite_state(tmp_pa
     assert not any(
         k.startswith("rio.sqlite.") for k in server_gauges(SimpleNamespace(app_data=AppData()))
     )
+
+
+def _open_at_the_barrier(path: str, barrier, results) -> None:
+    barrier.wait(30)
+    try:
+        conn = sqlite_mod._connect(path)
+        mode = conn.execute("PRAGMA journal_mode").fetchone()[0]
+        conn.close()
+        results.put(mode)
+    except Exception as e:  # noqa: BLE001 - the parent asserts on what came
+        results.put(repr(e))
+
+
+def test_processes_that_open_a_fresh_file_together_all_get_wal(tmp_path):
+    """The workers of one ShardedServer open the node's databases at the
+    same moment; the switch to WAL refuses all but one of them at once
+    ("database is locked", no busy handler), so the opener asks again."""
+    import multiprocessing
+
+    ctx = multiprocessing.get_context("spawn")
+    for round_ in range(4):
+        path = str(tmp_path / f"fresh{round_}.db")
+        barrier, results = ctx.Barrier(6), ctx.Queue()
+        procs = [
+            ctx.Process(target=_open_at_the_barrier, args=(path, barrier, results))
+            for _ in range(6)
+        ]
+        for p in procs:
+            p.start()
+        got = [results.get(timeout=60) for _ in procs]  # drained before the joins
+        for p in procs:
+            p.join(30)
+            assert not p.is_alive()
+        assert got == ["wal"] * 6

@@ -177,50 +177,3 @@ def test_server_survives_connection_chaos():
             await asyncio.gather(task, return_exceptions=True)
 
     asyncio.run(asyncio.wait_for(run(), 120))
-
-
-def test_native_server_survives_connection_chaos():
-    """Same storm against the C++ epoll engine: both data planes must hold
-    the refuse/drain/keep-serving posture under hostile timing, not just
-    hostile bytes (CLAUDE.md wire invariant)."""
-    from rio_tpu import native
-
-    if native.get() is None:
-        import pytest
-
-        pytest.skip("native library unavailable")
-
-    async def run():
-        from rio_tpu import (
-            LocalObjectPlacement,
-            LocalStorage,
-            Registry,
-            Server,
-        )
-        from rio_tpu.cluster.membership_protocol import LocalClusterProvider
-
-        from tests.test_aio_transport import SleepyActor
-
-        members = LocalStorage()
-        server = Server(
-            address="127.0.0.1:0",
-            registry=Registry().add_type(SleepyActor),
-            cluster_provider=LocalClusterProvider(members),
-            object_placement_provider=LocalObjectPlacement(),
-            transport="native",
-        )
-        await server.prepare()
-        addr = await server.bind()
-        task = asyncio.create_task(server.run())
-        for _ in range(100):
-            if await members.active_members():
-                break
-            await asyncio.sleep(0.02)
-        host, _, port = addr.rpartition(":")
-        try:
-            await _storm(host, int(port))
-        finally:
-            task.cancel()
-            await asyncio.gather(task, return_exceptions=True)
-
-    asyncio.run(asyncio.wait_for(run(), 120))
