@@ -983,6 +983,10 @@ class JaxObjectPlacement(ObjectPlacement):
         # What went in through the bulk seam (``place_gauges``).
         self._bulk_rows = 0
         self._bulk_chunks = 0
+        # ``assign_batch`` calls answered from the seats they carried, and
+        # calls that re-validated because the epoch moved (``place_gauges``).
+        self._assign_carried = 0
+        self._assign_revalidated = 0
         # Lattice steps ``sync_load`` applied, over all nodes (``place_gauges``).
         self._derate_steps = 0
         # Rows the delta route found displaced and moved (``place_gauges``).
@@ -1149,6 +1153,8 @@ class JaxObjectPlacement(ObjectPlacement):
         return {
             "rio.place.bulk_rows": float(self._bulk_rows),
             "rio.place.bulk_chunks": float(self._bulk_chunks),
+            "rio.place.assign_carried": float(self._assign_carried),
+            "rio.place.assign_revalidated": float(self._assign_revalidated),
             "rio.load.derate_steps": float(self._derate_steps),
             "rio.place.delta.displaced": float(self._delta_displaced),
             "rio.place.delta.moved": float(self._delta_moved),
@@ -1490,12 +1496,13 @@ class JaxObjectPlacement(ObjectPlacement):
 
     # ------------------------------------------------------- batched solve
     async def lookup_batch(self, object_ids: list[ObjectId]) -> list[str | None]:
-        out: list[str | None] = []
         with stage("place.lookup"):
-            for oid in object_ids:
-                idx = self._placements.get(str(oid))
-                out.append(None if idx is None else self._node_order[idx])
-        return out
+            # The key is ``str(ObjectId)`` spelled inline: no call a key.
+            get, order = self._placements.get, self._node_order
+            return [
+                None if (idx := get(f"{o.type_name}.{o.id}")) is None else order[idx]
+                for o in object_ids
+            ]
 
     @contextlib.asynccontextmanager
     async def _lock_staged(self):
@@ -1520,34 +1527,76 @@ class JaxObjectPlacement(ObjectPlacement):
         a 10M-key batch solves for ~46 s, and holding ``self._lock`` across
         it starved ``update``/``remove``/``clean_server``/``rebalance`` and
         every other ``assign_batch`` caller for the duration. Each chunk
-        re-checks membership under its lock hold (two callers racing on
-        overlapping keys place each key once), and the final address
-        resolution re-validates: a concurrent ``remove``/``clean_server``
-        between chunks may have dropped keys placed earlier, so stragglers
-        are re-placed under one last lock hold — no unlocked await separates
-        that re-place from the read, so the resolution cannot miss.
+        probes membership under its lock hold (two callers racing on
+        overlapping keys place each key once) and keeps what the probe and
+        the solve told it: the seat index of every key of the chunk.
+
+        The answer is built from those carried seats, inside the last
+        chunk's lock hold, as long as ``self._epoch`` at every acquisition
+        is what it was when the previous hold ended: every writer of seats
+        bumps it under the lock, so nobody else wrote and every carried seat
+        still stands (a batch of one chunk has no gap to check). Only when
+        the epoch did move between two holds (an ``update``/``remove``/
+        ``clean_server``/committed solve, or a liveness change) does the
+        final resolution re-validate: keys dropped meanwhile are re-placed
+        under one last lock hold, with no unlocked await between that
+        re-place and the per-key read, so the resolution cannot miss.
 
         Raises :class:`rio_tpu.errors.NoSchedulableCapacity` (a
         ``ValueError`` subclass) when no node has registered yet — the
         batch cannot be seated anywhere, and silently parking it would
         strand every key.
         """
+        if not object_ids:
+            return []
         # The stages below tile the call (PERF.md section 3 names the metric
         # that reads each): one record per stage per chunk, nothing per key.
         with stage("place.assign"):
             with stage("place.keys"):
-                keys = [str(o) for o in object_ids]
+                # ``str(ObjectId)`` spelled inline: no call a key.
+                keys = [f"{o.type_name}.{o.id}" for o in object_ids]
                 # A key given twice reaches the solve once: solved twice it
                 # was moved by its second row while its first node kept a
                 # load of +1 it did not hold.
-                uniq = list(dict.fromkeys(keys))
+                uniq = keys if len(set(keys)) == len(keys) else list(dict.fromkeys(keys))
+            carried: list[np.ndarray] = []  # seat index per key of uniq, by chunk
+            left_at = None  # the epoch when the previous hold ended
+            clean = True
             for start in range(0, len(uniq), self._MAX_PLACE_CHUNK):
                 async with self._lock_staged():
+                    if left_at is not None and self._epoch != left_at:
+                        clean = False
                     with stage("place.filter"):
                         chunk = uniq[start : start + self._MAX_PLACE_CHUNK]
-                        unplaced = [k for k in chunk if k not in self._placements]
-                    if unplaced:
-                        await self._place_chunk_locked(unplaced)
+                        # The common wave, every key new, is one pass in C
+                        # that builds nothing; it ends at the first seated key.
+                        if self._placements.keys().isdisjoint(chunk):
+                            held, unplaced = None, chunk
+                        else:
+                            held = list(map(self._placements.get, chunk))
+                            unplaced = [k for k, i in zip(chunk, held) if i is None]
+                    seats = await self._place_chunk_locked(unplaced) if unplaced else ()
+                    if clean:
+                        if held is not None:
+                            found = np.fromiter(
+                                (-1 if i is None else i for i in held), np.int64, len(held)
+                            )
+                            found[found < 0] = seats
+                            seats = found
+                        carried.append(seats)
+                    left_at = self._epoch
+                    if clean and start + self._MAX_PLACE_CHUNK >= len(uniq):
+                        self._assign_carried += 1
+                        with stage("place.resolve"):
+                            idx = np.concatenate(carried)
+                            if uniq is not keys:
+                                at = dict(zip(uniq, range(len(uniq))))
+                                idx = idx[
+                                    np.fromiter(map(at.__getitem__, keys), np.int64, len(keys))
+                                ]
+                            return np.array(self._node_order, object)[idx].tolist()
+            # Somebody else wrote between two of the holds above.
+            self._assign_revalidated += 1
             async with self._lock_staged():
                 with stage("place.filter"):
                     missing = [k for k in uniq if k not in self._placements]
@@ -1570,8 +1619,9 @@ class JaxObjectPlacement(ObjectPlacement):
         for start in range(0, len(keys), self._MAX_PLACE_CHUNK):
             await self._place_chunk_locked(keys[start : start + self._MAX_PLACE_CHUNK])
 
-    async def _place_chunk_locked(self, chunk: list[str]) -> None:
-        """One chunk's placement with the device solve OFF the event loop.
+    async def _place_chunk_locked(self, chunk: list[str]) -> np.ndarray:
+        """One chunk's placement with the device solve OFF the event loop;
+        returns the node index each key of ``chunk`` was seated at.
 
         Snapshot-solve-apply, the same discipline as ``rebalance``: the
         node vectors and cached potentials are snapshotted ON the event
@@ -1602,6 +1652,7 @@ class JaxObjectPlacement(ObjectPlacement):
         stage_since("place.resume", t_solved)
         with stage("place.apply"):
             self._apply_chunk(chunk, assignment)
+        return assignment
 
     def _solve_chunk(
         self, keys, load, cap, alive, g, n_real, no_capacity=False

@@ -5,14 +5,18 @@ Trait semantics mirror the reference backend matrix
 are rio-tpu additions.
 """
 
+import asyncio
 import gc
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from rio_tpu import ObjectId, ObjectPlacementItem
 from rio_tpu.errors import NoSchedulableCapacity
+from rio_tpu.object_placement import LocalObjectPlacement
 from rio_tpu.object_placement.jax_placement import JaxObjectPlacement
+from rio_tpu.object_placement.persistent import PersistentJaxObjectPlacement
 from tests.test_soak_random_ops import _check_invariants
 
 
@@ -587,53 +591,29 @@ async def test_assign_batch_concurrent_with_membership_churn():
     assert all(w is not None for w in looked)
 
 
-async def test_assign_batch_releases_lock_between_chunks():
+async def test_assign_batch_releases_lock_between_chunks(monkeypatch):
     """r4 review: a huge batch must not hold the provider lock for its
     whole runtime. A locked mutator (remove of a chunk-0 key) queues on the
     lock WHILE chunk 0 is still held, so FIFO fairness serves it in the
     between-chunk gap — it must complete while the batch is still running
     (the old whole-batch hold blocked it until the end), and the batch's
     final resolution pass must re-place the removed straggler."""
-    import asyncio
-
     placement = JaxObjectPlacement(mode="greedy")
     placement.sync_members([f"10.5.0.{i}:70" for i in range(4)])
-
-    chunk0_done = asyncio.Event()
+    ids = [ObjectId("Big", str(i)) for i in range(4000)]
     batch_done = False
     removed_while_batch_ran = None
-    orig = JaxObjectPlacement._place_chunk_locked
 
-    async def chunk_and_signal(self, chunk):
-        await orig(self, chunk)
-        if not chunk0_done.is_set():
-            chunk0_done.set()
-            # Still holding the lock: yield so the mutator wakes and QUEUES
-            # its lock request behind us — FIFO then guarantees it runs in
-            # the gap before chunk 1, not after the whole batch.
-            for _ in range(5):
-                await asyncio.sleep(0)
-
-    ids = [ObjectId("Big", str(i)) for i in range(4000)]
-    straggler = ids[3]  # placed in chunk 0
-
-    async def mutator():
+    async def remove_a_chunk0_key(p):
         nonlocal removed_while_batch_ran
-        await chunk0_done.wait()
-        await placement.remove(straggler)
+        await p.remove(ids[3])
         removed_while_batch_ran = not batch_done
 
-    old_chunk = JaxObjectPlacement._MAX_PLACE_CHUNK
-    JaxObjectPlacement._MAX_PLACE_CHUNK = 512
-    JaxObjectPlacement._place_chunk_locked = chunk_and_signal
-    try:
-        task = asyncio.create_task(mutator())
-        where = await placement.assign_batch(ids)
-        batch_done = True
-        await asyncio.wait_for(task, 30)
-    finally:
-        JaxObjectPlacement._MAX_PLACE_CHUNK = old_chunk
-        JaxObjectPlacement._place_chunk_locked = orig
+    monkeypatch.setattr(JaxObjectPlacement, "_MAX_PLACE_CHUNK", 512)
+    task = await _after_first_chunk(placement, remove_a_chunk0_key)
+    where = await placement.assign_batch(ids)
+    batch_done = True
+    await asyncio.wait_for(task, 30)
     # The remove interleaved mid-batch (lock released between chunks)...
     assert removed_while_batch_ran is True
     # ...and the final resolution re-placed it: every key resolves.
@@ -1180,3 +1160,304 @@ async def test_a_key_given_twice_in_a_batch_is_solved_and_counted_once():
     assert loads == {j: float(len(p._by_node.get(j, ()))) for j in loads}
     assert sum(loads.values()) == 100.0
     assert p.place_gauges()["rio.place.bulk_rows"] == 100
+
+
+# ---------------------------------------------------------------------------
+# assign_batch answers from the seats it carried; the epoch says when it may not
+# ---------------------------------------------------------------------------
+
+
+def _directory(kind: str, nodes: int = 6):
+    if kind == "persistent":
+        p = PersistentJaxObjectPlacement(
+            LocalObjectPlacement(), flush_interval=0.01, node_axis_size=16, mode="greedy"
+        )
+    else:
+        p = JaxObjectPlacement(node_axis_size=16, mode="greedy")
+    for i in range(nodes):
+        p.register_node(f"10.0.0.{i}:5000")
+    return p
+
+
+def _game_ids(lo, hi):
+    return [ObjectId("Game", str(i)) for i in range(lo, hi)]
+
+
+async def _after_first_chunk(p, action):
+    """Run ``action(p)`` between the first chunk's lock hold and the second's:
+    the task asks for the lock while chunk 0 still holds it, and the lock is
+    first come, first served."""
+    chunk0_done = asyncio.Event()
+    real = p._place_chunk_locked
+
+    async def chunk_and_signal(chunk):
+        seats = await real(chunk)
+        if not chunk0_done.is_set():
+            chunk0_done.set()
+            for _ in range(5):
+                await asyncio.sleep(0)
+        return seats
+
+    p._place_chunk_locked = chunk_and_signal
+
+    async def mutator():
+        await chunk0_done.wait()
+        await action(p)
+
+    return asyncio.create_task(mutator())
+
+
+async def _remove_a_chunk0_key(p):
+    await p.remove(ObjectId("Game", "3"))
+
+
+async def _clean_a_server(p):
+    await p.clean_server("10.0.0.2:5000")
+
+
+async def _update_a_chunk0_key(p):
+    oid = ObjectId("Game", "5")
+    held = await p.lookup(oid)
+    await p.update(ObjectPlacementItem(oid, next(a for a in p._node_order if a != held)))
+
+
+async def _drop_a_chunk0_key_by_update(p):
+    await p.update(ObjectPlacementItem(ObjectId("Game", "7"), None))
+
+
+async def _reprice_every_node(p):
+    async with p._lock:  # in the gap between two holds, like the others
+        p.sync_load(SimpleNamespace(derate=lambda address: 0.5))
+
+
+# name: (ids seated before, the batch, chunk size, what lands between chunks, carried?)
+_ASSIGN_CASES = {
+    "all_new": ([], _game_ids(0, 300), None, None, True),
+    "some_seated": (_game_ids(100, 200), _game_ids(50, 250)[::-1], None, None, True),
+    "all_seated": (_game_ids(0, 300), _game_ids(0, 300), None, None, True),
+    "a_key_twice": ([], _game_ids(0, 120) + _game_ids(60, 180), None, None, True),
+    "several_chunks": (_game_ids(30, 90), _game_ids(0, 300) + _game_ids(10, 20), 64, None, True),
+    "remove_between_chunks": ([], _game_ids(0, 300), 64, _remove_a_chunk0_key, False),
+    "clean_server_between_chunks": ([], _game_ids(0, 300), 64, _clean_a_server, False),
+    "update_between_chunks": ([], _game_ids(0, 300), 64, _update_a_chunk0_key, False),
+    "update_to_none_between_chunks": (
+        [], _game_ids(0, 300), 64, _drop_a_chunk0_key_by_update, False,
+    ),
+    "sync_load_between_chunks": ([], _game_ids(0, 300), 64, _reprice_every_node, True),
+}
+
+
+@pytest.mark.parametrize("kind", ["mirror", "persistent"])
+@pytest.mark.parametrize("case", list(_ASSIGN_CASES))
+async def test_assign_batch_returns_the_seat_the_directory_holds(case, kind, monkeypatch):
+    """Whichever route the call takes, what it returns is what a probe of
+    every key would read at the moment of return, and it took the carried
+    route exactly when nobody else wrote seats or liveness between its holds."""
+    seated, ids, chunk_size, between, carried = _ASSIGN_CASES[case]
+    p = _directory(kind)
+    if seated:
+        await p.assign_batch(seated)
+    if chunk_size:
+        monkeypatch.setattr(JaxObjectPlacement, "_MAX_PLACE_CHUNK", chunk_size)
+    before = p.place_gauges()
+    task = await _after_first_chunk(p, between) if between else None
+    addrs = await p.assign_batch(ids)
+    if task is not None:
+        await asyncio.wait_for(task, 30)
+    assert addrs == [p._node_order[p._placements[str(o)]] for o in ids]
+    assert addrs == await p.lookup_batch(ids) == [await p.lookup(o) for o in ids]
+    assert p.count() == len({str(o) for o in ids} | {str(o) for o in seated})
+    _check_invariants(p)
+    if between is None:  # (remove and update leave loads to the next solve's recount)
+        loads = {s.index: s.load for s in p._nodes.values()}
+        assert loads == {j: float(len(p._by_node.get(j, ()))) for j in loads}
+    if between is _clean_a_server:
+        assert "10.0.0.2:5000" not in addrs and not p._by_node.get(2)
+    after = p.place_gauges()
+    took = {
+        k: after[f"rio.place.assign_{k}"] - before[f"rio.place.assign_{k}"]
+        for k in ("carried", "revalidated")
+    }
+    assert took == {"carried": float(carried), "revalidated": float(not carried)}
+    if kind == "persistent":
+        await p.flush()
+        stored = {str(i.object_id): i.server_address for i in await p._backing.items()}
+        assert all(stored[str(o)] == a for o, a in zip(ids, addrs))
+        assert len(stored) == p.count()
+        await p.aclose()
+
+
+async def test_two_overlapping_batches_at_once_agree_on_every_shared_key(monkeypatch):
+    """Each caller's chunks interleave with the other's, whose applies move
+    the epoch: a seat carried over such a gap is re-validated, not trusted."""
+    monkeypatch.setattr(JaxObjectPlacement, "_MAX_PLACE_CHUNK", 64)
+    p = _directory("mirror")
+    a, b = _game_ids(0, 400), _game_ids(200, 600)[::-1]
+    got_a, got_b = await asyncio.wait_for(
+        asyncio.gather(p.assign_batch(a), p.assign_batch(b)), 60
+    )
+    assert got_a == await p.lookup_batch(a) and got_b == await p.lookup_batch(b)
+    assert p.count() == 600
+    _check_invariants(p)
+    gauges = p.place_gauges()
+    assert gauges["rio.place.assign_carried"] + gauges["rio.place.assign_revalidated"] == 2
+    assert gauges["rio.place.assign_revalidated"] >= 1
+    assert gauges["rio.place.bulk_rows"] == 600  # no key was seated twice
+
+
+@pytest.mark.parametrize("kind", ["mirror", "persistent"])
+async def test_lookup_batch_reads_what_lookup_reads_and_none_for_an_unseated_id(kind):
+    p = _directory(kind)
+    await p.assign_batch(_game_ids(0, 50))
+    ids = _game_ids(40, 60) + [ObjectId("Other", "7"), ObjectId("Game", "0")]
+    got = await p.lookup_batch(ids)
+    assert got == [await p.lookup(o) for o in ids]
+    assert got[:10].count(None) == 0 and got[10:21] == [None] * 11 and got[21] is not None
+    assert await p.lookup_batch([]) == [] and await p.assign_batch([]) == []
+    if kind == "persistent":
+        await p.aclose()
+
+
+# ---------------------------------------------------------------------------
+# The epoch contract the carried route rests on
+# ---------------------------------------------------------------------------
+
+
+class _AuditedLock(asyncio.Lock):
+    """``p._lock`` that, at every release, holds the writer to the contract:
+    a hold in which a seat was written ends with ``_epoch`` moved."""
+
+    def __init__(self, p):
+        super().__init__()
+        self.p, self.wrote, self.epoch, self.broken, self.writes = p, [], None, [], 0
+
+    def note(self, seam: str) -> None:
+        assert self.locked(), f"{seam} wrote a seat without the lock"
+        if not self.wrote:
+            self.epoch = self.p._epoch
+        self.wrote.append(seam)
+        self.writes += 1
+
+    def release(self) -> None:
+        if self.wrote and self.p._epoch == self.epoch:
+            self.broken.append(sorted(set(self.wrote)))
+        self.wrote = []
+        super().release()
+
+
+def _audit_the_write_seams(p) -> _AuditedLock:
+    lock = p._lock = _AuditedLock(p)
+    set_, drop, bulk = p._set_placement, p._drop_placement, p._seat_new
+
+    def set_placement(key, idx):
+        changed = set_(key, idx)
+        if changed:
+            lock.note("_set_placement")
+        return changed
+
+    def drop_placement(key):
+        idx = drop(key)
+        if idx is not None:
+            lock.note("_drop_placement")
+        return idx
+
+    def seat_new(keys, idx):
+        if len(keys):
+            lock.note("_seat_new")
+        return bulk(keys, idx)
+
+    p._set_placement, p._drop_placement, p._seat_new = set_placement, drop_placement, seat_new
+    return lock
+
+
+async def _epoch_update(p, ids):
+    await p.update(ObjectPlacementItem(ids[0], "10.0.0.5:5000"))
+
+
+async def _epoch_update_to_none(p, ids):
+    await p.update(ObjectPlacementItem(ids[1], None))
+
+
+async def _epoch_remove(p, ids):
+    await p.remove(ids[2])
+
+
+async def _epoch_clean_server(p, ids):
+    await p.clean_server("10.0.0.1:5000")
+
+
+async def _epoch_promote_standby(p, ids):
+    held = await p.lookup(ids[3])
+    other = next(a for a in p._node_order if a != held)
+    epoch = await p.set_standbys(ids[3], [other])
+    assert await p.promote_standby(ids[3], other, epoch) == epoch + 1
+
+
+async def _epoch_rebalance_full(p, ids):
+    p.sync_members([f"10.0.0.{i}:5000" for i in range(2, 9)])
+    assert await p.rebalance(delta=False) > 0 and "+delta" not in p.stats.mode
+
+
+async def _epoch_rebalance_delta(p, ids):
+    await p.rebalance(delta=False)  # a committed plan to delta against
+    p.sync_members([f"10.0.0.{i}:5000" for i in range(1, 6)])
+    assert await p.rebalance() > 0 and p.stats.mode.endswith("+delta")
+
+
+async def _epoch_assign_batch(p, ids):
+    await p.assign_batch(_game_ids(1000, 1300))
+
+
+async def _epoch_assign_batch_after_a_remove(p, ids):
+    task = await _after_first_chunk(p, _remove_a_chunk0_key)
+    await p.assign_batch(_game_ids(0, 200) + _game_ids(1000, 1300))
+    await asyncio.wait_for(task, 30)
+
+
+_EPOCH_MUTATORS = [
+    _epoch_update, _epoch_update_to_none, _epoch_remove, _epoch_clean_server,
+    _epoch_promote_standby, _epoch_rebalance_full, _epoch_rebalance_delta,
+    _epoch_assign_batch, _epoch_assign_batch_after_a_remove,
+]
+
+
+@pytest.mark.parametrize("kind", ["mirror", "persistent"])
+@pytest.mark.parametrize("mutator", _EPOCH_MUTATORS, ids=lambda f: f.__name__[7:])
+async def test_every_writer_of_seats_moves_the_epoch_in_the_hold_it_wrote_in(
+    mutator, kind, monkeypatch
+):
+    """``assign_batch`` trusts its carried seats while ``_epoch`` stands still
+    between its lock holds. That is sound only while every write through
+    ``_set_placement`` / ``_drop_placement`` / ``_seat_new`` happens under
+    ``_lock`` and the hold it happens in ends with the epoch moved. A later
+    writer that forgets the bump fails here, by seam, under its mutator's name."""
+    monkeypatch.setattr(JaxObjectPlacement, "_MAX_PLACE_CHUNK", 128)
+    p = _directory(kind)
+    ids = _game_ids(0, 200)
+    await p.assign_batch(ids)
+    lock = _audit_the_write_seams(p)
+    epoch = p._epoch
+    await mutator(p, ids)
+    assert lock.writes > 0, "the scenario wrote no seat: it proves nothing"
+    assert lock.broken == [] and not lock.locked()
+    assert p._epoch > epoch
+    _check_invariants(p)
+    if kind == "persistent":
+        await p.aclose()
+
+
+async def test_a_restore_with_items_moves_the_epoch_and_an_empty_one_need_not():
+    backing = LocalObjectPlacement()
+    empty = PersistentJaxObjectPlacement(backing, mode="greedy")
+    lock = _audit_the_write_seams(empty)
+    await empty.prepare()
+    assert lock.writes == 0 and lock.broken == [] and empty._epoch == 0
+    await backing.update(ObjectPlacementItem(ObjectId("Game", "1"), "10.0.0.1:5000"))
+    await backing.update(ObjectPlacementItem(ObjectId("Game", "2"), "10.0.0.2:5000"))
+    p = PersistentJaxObjectPlacement(backing, mode="greedy")
+    lock = _audit_the_write_seams(p)
+    await p.prepare()
+    assert lock.writes == 2 and lock.broken == [] and p._epoch > 0
+    assert await p.lookup_batch(_game_ids(1, 3)) == ["10.0.0.1:5000", "10.0.0.2:5000"]
+    await empty.aclose()
+    await p.aclose()
