@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
-from .tracing import Span, stage_gauges
+from .tracing import Span, gc_gauges, stage_gauges
 
 
 def stats_gauges(**sources: Any) -> dict[str, float]:
@@ -88,6 +88,8 @@ def server_gauges(server: Any) -> dict[str, float]:
     # Coarse host stages of this PROCESS (rio.stage.<name>.count/total_ms/
     # max_ms): directory batch calls, solves, full collections.
     gauges.update(stage_gauges())
+    # How the old heap settles between whole walks of it (rio.gc.*).
+    gauges.update(gc_gauges())
     place_gauges = getattr(placement, "place_gauges", None)
     if place_gauges is not None:
         # The device-solved directory's host mirror (rio.place.*): rows and

@@ -347,23 +347,66 @@ def clear_stages() -> None:
 
 
 # The interpreter's collections ride the same log: a full one (generation 2)
-# walks every container the process holds and stops every thread, so it is a
-# stage, ``gc.gen2``; the young generations only add to their totals. One
-# ``gc.callbacks`` entry per process, held while any LoadMonitor runs. The
-# rows are seated here so that the callback, which runs at any allocation,
-# inserts nothing and takes no lock (collections do not nest: one writer).
+# stops every thread, so it is a stage, ``gc.gen2``; the young generations
+# only add to their totals. One ``gc.callbacks`` entry per process, held
+# while any LoadMonitor runs. The rows are seated here so that the callback,
+# which runs at any allocation, inserts nothing and takes no lock
+# (collections do not nest: one writer).
+#
+# While the entry is held the old heap SETTLES: what a full collection kept
+# is moved to the interpreter's permanent generation when it stops
+# (``gc.freeze()``: three list merges), so the next one walks only what was
+# allocated since, not the modules, servers and actors it has walked before.
+# Only survivors of a full collection are ever set aside, they are still
+# freed by reference count, and a cycle that dies among them waits for the
+# next WHOLE walk: when the settled heap has doubled since the last one the
+# ``start`` of a full collection thaws it first (the callback runs before
+# the collector gathers its lists, so that collection covers it). Nothing
+# here is O(heap) except a whole walk and the count that decides on one.
 _GC_ROWS = [_STAGE_TOTALS.setdefault(f"gc.gen{g}", [0, 0, 0]) for g in range(3)]
-_GC_OPEN: list = [0, None]  # start stamp, the full collection's annotation
+_GC_OPEN: list = [0, None, False]  # start stamp, the full collection's annotation, a whole walk
+# Objects the last whole walk kept at the most (or a later exact count, if
+# lower); an upper bound on what is settled now (each full collection adds
+# the survivors it walked; what reference counts freed since is still in
+# it); the bound at which to count exactly again.
+_GC_HEAP = [0, 0, 0]
+_GC_WALKS = [0, 0]  # full collections that walked the young heap only, whole walks
 _GC_WATCHERS = 0
 
 
 def _on_gc(phase: str, info: dict) -> None:
     gen = info["generation"]
     if phase == "start":
+        _GC_OPEN[0] = time.perf_counter_ns()
         if gen == 2:
             _GC_OPEN[1] = _annotation("gc.gen2")
-        _GC_OPEN[0] = time.perf_counter_ns()
+            kept, bound, recount_at = _GC_HEAP
+            if bound >= recount_at:
+                # The exact count walks the permanent generation's list, so
+                # it is taken once per half of ``kept`` objects settled.
+                bound = gc.get_freeze_count()
+                if bound >= 2 * kept:
+                    # A whole walk keeps at most what is settled and what is
+                    # young now (counted before the thaw: O(young)).
+                    kept = bound + len(gc.get_objects())
+                    _GC_HEAP[:] = kept, kept, 2 * kept
+                    gc.unfreeze()
+                    _GC_OPEN[2] = True
+                else:
+                    # (A heap that shrank since is the one to double.)
+                    kept = min(kept, bound)
+                    _GC_HEAP[:] = kept, bound, max(2 * kept, bound + kept // 2)
         return
+    if gen == 2:
+        if _GC_OPEN[2]:
+            _GC_OPEN[2] = False
+            _GC_WALKS[1] += 1
+        else:
+            # After a full collection every tracked object that is not
+            # settled is a survivor of it: O(young).
+            _GC_HEAP[1] += len(gc.get_objects())
+            _GC_WALKS[0] += 1
+        gc.freeze()
     t1 = time.perf_counter_ns()
     t0 = _GC_OPEN[0]
     if gen == 2:
@@ -378,6 +421,17 @@ def _on_gc(phase: str, info: dict) -> None:
         row[2] = t1 - t0
 
 
+def gc_gauges() -> dict[str, float]:
+    """How the old heap settles (``rio.gc.*``): full collections that walked
+    only the young heap, those that thawed and walked everything, and the
+    bound on the settled objects as last corrected (never a walk)."""
+    return {
+        "rio.gc.settled": float(_GC_WALKS[0]),
+        "rio.gc.whole_walks": float(_GC_WALKS[1]),
+        "rio.gc.settled_objects": float(_GC_HEAP[1]),
+    }
+
+
 def watch_gc() -> None:
     """Install the collection callback (the first caller does; counted)."""
     global _GC_WATCHERS
@@ -388,7 +442,8 @@ def watch_gc() -> None:
 
 
 def unwatch_gc() -> None:
-    """Undo one :func:`watch_gc`; the last caller removes the callback."""
+    """Undo one :func:`watch_gc`; the last caller removes the callback and
+    thaws what it set aside: the process has the collector it started with."""
     global _GC_WATCHERS
     with _STAGE_LOCK:
         if _GC_WATCHERS == 0:
@@ -396,3 +451,5 @@ def unwatch_gc() -> None:
         _GC_WATCHERS -= 1
         if _GC_WATCHERS == 0 and _on_gc in gc.callbacks:
             gc.callbacks.remove(_on_gc)
+            gc.unfreeze()
+            _GC_HEAP[:] = [0, 0, 0]

@@ -719,3 +719,37 @@ def test_activated_objects_keep_serving_while_shedding():
     asyncio.run(
         run_integration_test(body, registry_builder=build_registry, num_servers=2)
     )
+
+
+def test_a_server_without_a_load_monitor_leaves_the_collector_alone():
+    """``load_monitor=False``: no watcher, so no full collection's survivors
+    are set aside (the switch an operator has for the interpreter's own
+    behaviour)."""
+    import gc
+
+    from rio_tpu import tracing
+
+    async def body(cluster: Cluster):
+        assert all(s.load_monitor is None for s in cluster.servers)
+        assert tracing._on_gc not in gc.callbacks
+        client = cluster.client()
+        try:
+            await client.send(Echo, "e0", Ping(), returns=Pong)
+            gc.collect()
+            # What the interpreter keeps there itself (its immortal objects).
+            own = gc.get_freeze_count()
+            assert own < 1_000
+            await client.send(Echo, "e1", Ping(), returns=Pong)
+            gc.collect()
+            assert gc.get_freeze_count() == own
+            listed = gc.get_objects()
+            assert all(any(o is s for o in listed) for s in cluster.servers)
+        finally:
+            client.close()
+
+    asyncio.run(
+        run_integration_test(
+            body, registry_builder=build_registry, num_servers=2,
+            server_kwargs={"load_monitor": False},
+        )
+    )

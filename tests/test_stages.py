@@ -7,10 +7,12 @@ on, and none of it starts a task, a thread or a timer.
 """
 
 import asyncio
+import contextlib
 import gc
 import threading
 import time
 import types
+import weakref
 
 import pytest
 
@@ -327,6 +329,155 @@ def test_a_full_collection_is_a_stage_and_young_ones_only_count():
     assert len([r for r in tracing.stage_log() if r[NAME] == "gc.gen2"]) == len(full)
 
 
+class _Node:
+    pass
+
+
+def _cycle() -> "_Node":
+    a, b = _Node(), _Node()
+    a.other, b.other = b, a
+    return a
+
+
+@contextlib.contextmanager
+def _settling():
+    """The watcher on, and no collection but the test's own."""
+    assert tracing._GC_WATCHERS == 0
+    # (The interpreter keeps its own immortal objects there: a full
+    # collection moves them back after an ``unfreeze``. 375 in 3.12.12.)
+    assert gc.get_freeze_count() < 1_000
+    gc.disable()
+    tracing.watch_gc()
+    try:
+        yield
+    finally:
+        tracing.unwatch_gc()
+        gc.enable()
+
+
+def _walks() -> tuple[int, int]:
+    g = tracing.gc_gauges()
+    return int(g["rio.gc.settled"]), int(g["rio.gc.whole_walks"])
+
+
+def test_a_full_collection_sets_its_survivors_aside_for_the_next():
+    with _settling():
+        settled, whole = _walks()
+        gc.collect()  # nothing is settled yet: a whole walk
+        assert _walks() == (settled, whole + 1)
+        kept = gc.get_freeze_count()
+        assert kept > 10_000  # the interpreter's modules, at the least
+        assert len(gc.get_objects()) < kept // 100
+        made = [_Node() for _ in range(500)]
+        gc.collect()  # walks the 500 and what else is new, not the kept
+        assert _walks() == (settled + 1, whole + 1)
+        assert kept + 500 <= gc.get_freeze_count() <= kept + 1_000
+        assert len(gc.get_objects()) < kept // 100
+        g = tracing.gc_gauges()
+        assert kept + 500 <= g["rio.gc.settled_objects"] <= kept + 1_000
+        assert len(made) == 500
+
+
+def test_what_only_a_settled_object_holds_survives_every_collection():
+    holder = _Node()
+    with _settling():
+        gc.collect()
+        holder.child = _cycle()  # young, and reachable from the settled heap only
+        ref = weakref.ref(holder.child)
+        gc.collect(0)
+        gc.collect(1)
+        gc.collect()
+        assert ref() is holder.child
+        gc.collect()  # the child is settled itself now
+        assert ref() is holder.child
+        del holder.child
+        assert ref() is not None  # a cycle: not freed by reference count
+
+
+def test_a_cycle_that_dies_among_settled_objects_is_found_by_the_next_whole_walk():
+    with _settling():
+        gc.collect()
+        kept = gc.get_freeze_count()
+        ring = _cycle()
+        ref = weakref.ref(ring)
+        gc.collect()  # the ring is settled
+        del ring
+        gc.collect()
+        assert ref() is not None  # dead, and beyond a settled collection's reach
+        settled, whole = _walks()
+        grown = [[] for _ in range(kept + 1_000)]
+        gc.collect()  # settles what was grown: the heap has doubled
+        assert ref() is not None and _walks() == (settled + 1, whole)
+        gc.collect()  # thaws first, walks everything
+        assert ref() is None
+        assert _walks() == (settled + 1, whole + 1)
+        assert gc.get_freeze_count() >= 2 * kept  # and set aside again
+        gc.collect()
+        assert _walks() == (settled + 2, whole + 1)  # geometric: not again soon
+        assert len(grown) == kept + 1_000
+
+
+def test_a_heap_that_shrank_is_the_one_that_has_to_double():
+    with _settling():
+        gc.collect()
+        small = gc.get_freeze_count()
+        peak = [[] for _ in range(small + 1_000)]
+        gc.collect()
+        gc.collect()  # a whole walk at the peak: it kept twice ``small``
+        settled, whole = _walks()
+        assert gc.get_freeze_count() >= 2 * small
+        del peak  # freed by reference count: the settled heap is small again
+        passing = [[] for _ in range(2 * small + 4_000)]
+        gc.collect()  # the bound passes twice the peak ...
+        del passing
+        gc.collect()  # ... so this one counts: not doubled, and smaller than kept
+        assert _walks() == (settled + 2, whole)
+        assert small <= gc.get_freeze_count() < small + 4_000
+        ring = _cycle()
+        ref = weakref.ref(ring)
+        grown = [[] for _ in range(small + 4_000)]
+        gc.collect()
+        del ring
+        gc.collect()  # twice ``small`` is settled, far under twice the peak: a whole walk
+        assert ref() is None and _walks() == (settled + 3, whole + 1)
+        assert len(grown) == small + 4_000
+
+
+def test_every_full_collection_settled_or_whole_is_one_gc_gen2_record():
+    with _settling():
+        gc.collect()
+        gc.collect()
+        gc.collect(1)
+        gc.collect()
+        settled, whole = _walks()
+    assert whole >= 1 and settled >= 2
+    full = [r for r in tracing.stage_log() if r[NAME] == "gc.gen2"]
+    assert len(full) == 3 == tracing.stage_totals()["gc.gen2"][0]
+    assert all(r[T1] >= r[T0] and r[PARENT] is None for r in full)
+
+
+def test_the_last_unwatch_gives_the_collector_back_as_it_was():
+    found = list(gc.callbacks)
+    tracing.watch_gc()
+    tracing.watch_gc()
+    try:
+        assert _gc_entries() == 1
+        gc.collect()
+        assert gc.get_freeze_count() > 10_000
+        tracing.unwatch_gc()
+        assert _gc_entries() == 1 and gc.get_freeze_count() > 10_000  # still held
+        gc.collect()
+    finally:
+        tracing.unwatch_gc()
+    assert _gc_entries() == 0 and gc.callbacks == found
+    assert gc.get_freeze_count() == 0
+    assert tracing.gc_gauges()["rio.gc.settled_objects"] == 0.0
+    tracing.unwatch_gc()  # one too many: counted, not raised
+    assert tracing._GC_WATCHERS == 0
+    gc.collect()  # no watcher: nothing of the program's is set aside
+    assert gc.get_freeze_count() < 1_000
+
+
 async def test_the_monitor_keeps_raw_lag_samples_and_times_stalls():
     m = LoadMonitor(interval=0.02, stall_threshold_ms=100.0, stall_cooldown=0.0)
     task = asyncio.ensure_future(m.run())
@@ -374,6 +525,9 @@ async def test_server_gauges_carry_the_stages_and_the_loop_counters():
     assert gauges["rio.stage.gc.gen2.count"] >= 1.0
     for field in ("stalls", "stall_max_ms", "stall_total_ms", "loop_lag_ms", "samples"):
         assert f"rio.load.{field}" in gauges
+    for field in ("settled", "whole_walks", "settled_objects"):
+        assert f"rio.gc.{field}" in gauges
+    assert gauges["rio.gc.settled"] + gauges["rio.gc.whole_walks"] >= 1.0
     assert all(isinstance(v, float) for v in gauges.values())
 
 
