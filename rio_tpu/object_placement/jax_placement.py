@@ -35,6 +35,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass, field, replace
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -279,31 +280,73 @@ class AffinityTracker:
 # Hierarchical solves chunk the object axis above this row count (power
 # of two, so it divides every larger po2 bucket): the TPU backend's
 # compile time is superlinear in the flat row count while the chunked
-# lax.map body compiles once at the chunk shape. On a mesh the bound
-# applies PER DEVICE — devices divide the rows first, chunks divide each
-# device's slice (parallel/hierarchical.py mesh_chunked_hierarchical_
-# assign); on a single chip it bounds the lax.map chunk directly. One
-# 524,288-row chunk compiles cold in 44 s on a v5e under jax 0.9.0
-# (chip_smoke.py, PR 21; 46 s for the mesh x chunk cell on four chips).
+# lax.map body compiles once at the chunk shape. Devices divide the rows
+# first, chunks divide each device's slice (parallel/hierarchical.py
+# mesh_chunked_hierarchical_assign); on a single chip the bound is the
+# lax.map chunk's. One 524,288-row chunk compiles cold in 44 s on a v5e
+# under jax 0.9.0 (chip_smoke.py, PR 21; 46 s for the mesh x chunk cell on
+# four chips).
 # RIO_TPU_HIER_CHUNK_ROWS overrides (po2; CI smokes use a tiny value to
 # exercise the composed dispatch at test shapes in seconds).
 _HIER_CHUNK_ROWS = int(os.environ.get("RIO_TPU_HIER_CHUNK_ROWS") or 524_288)
 
-# Flat (collapsed) OT rebalances above this many padded rows route through
-# the hierarchical solve instead: the TPU backend's compile time for the
-# flat O(N) expansion pipeline is superlinear in the row count — neither
-# 10.5M nor 4.2M rows finished a 900 s compile budget (v5e, r5 capture
-# of 2026-07-31, not re-measured) while 1,048,576 rows compile cold in
-# 150 s (v5e, jax 0.9.0, chip_smoke.py, PR 21; r5: ~80 s) — and the
-# chunked two-level solve compiles in ~45 s per chunk shape. The
-# threshold is the largest flat bucket proven on hardware, and still
-# inside the smoke's budget, so it stands; on a mesh it applies to the
-# per-shard row count, and the routed re-solve lands on the mesh x chunk
-# composed path (never a giant flat compile per shard).
-# RIO_TPU_FLAT_REBALANCE_MAX_ROWS overrides (CI smoke knob).
+# Flat OT rebalances of more than this many padded rows IN THE WHOLE
+# DIRECTORY route through the two-level solve (solve_route): the TPU
+# backend's compile time for a flat pipeline is superlinear in its row
+# count (neither 10.5M nor 4.2M rows finished a 900 s compile budget, v5e,
+# r5 capture of 2026-07-31, not re-measured; 1,048,576 rows compile cold in
+# 150 s, chip_smoke.py, PR 21), and the dense sharded solve a mesh would
+# run instead builds the whole rows x nodes cost on the host first (17 GB
+# at 4,194,304 x 1,024). The rule counts total rows, not rows a device,
+# for that reason: dividing by the devices sent 4,194,304 rows on four
+# chips to the dense branch. The threshold is the largest flat bucket
+# proven on hardware. RIO_TPU_FLAT_REBALANCE_MAX_ROWS overrides (CI smoke
+# knob).
 _FLAT_REBALANCE_MAX_ROWS = int(
     os.environ.get("RIO_TPU_FLAT_REBALANCE_MAX_ROWS") or 1_048_576
 )
+
+
+class SolveRoute(NamedTuple):
+    """What a full re-solve runs as (:func:`solve_route`)."""
+
+    path: str  # "hierarchical" | "collapsed" | "dense" | "greedy"
+    # ``SolveStats.mode``; a two-level solve that is sharded AND chunked
+    # appends "+mesh_chunk" (``_hierarchical_solve``).
+    solved_as: str
+    shard: bool  # over the mesh: the one given, else the local devices'
+
+
+def solve_route(
+    mode: str, rows: int, devices: int, backend: str, priced: bool,
+    mesh_given: bool = False,
+) -> SolveRoute:
+    """The route of a full re-solve, from what the program can observe.
+
+    ``rows`` are the directory's padded rows (all of them, not a device's
+    share), ``devices`` the given mesh's or else the backend's local ones,
+    ``priced`` whether per-object move prices differ. ``"auto"`` without a
+    locality signal is ``sinkhorn`` on a TPU and ``greedy`` elsewhere
+    (``_solver_mode`` says why). A two-level solve past
+    ``_FLAT_REBALANCE_MAX_ROWS`` shards over whatever devices there are;
+    under it only a mesh that was given shards anything, so a directory one
+    chip's flat solve takes runs the same program on any host.
+    """
+    if mode == "auto":
+        mode = "sinkhorn" if backend == "tpu" else "greedy"
+    at_scale = rows > _FLAT_REBALANCE_MAX_ROWS
+    if mode == "hierarchical":
+        return SolveRoute("hierarchical", mode, mesh_given or (at_scale and devices > 1))
+    if mode not in ("sinkhorn", "scaling"):
+        return SolveRoute("greedy", mode, False)
+    if at_scale:
+        return SolveRoute("hierarchical", f"{mode}+hier_at_scale", devices > 1)
+    # Per-object prices and a mesh's per-shard capacity split both break
+    # the identical-cost-rows precondition of the O(M^2) class collapse.
+    if priced or mesh_given:
+        return SolveRoute("dense", mode, mesh_given)
+    return SolveRoute("collapsed", f"{mode}+collapsed", False)
+
 
 # Row cap for the affinity refine's subset solve: the communication graph
 # is top-K bounded per node (EdgeSampler), so the edge-touching object set
@@ -428,42 +471,39 @@ def _cancel_transit(assignment: np.ndarray, cur_idx: np.ndarray) -> np.ndarray:
     the per-row rounding turns that into a row or so sent to the emptiest
     columns (the rejoiners), and the leavers' rows fill the survivors back
     up — two moves where one would do, a thousand single-row hand-offs a
-    churn event at 1,048,576 x 1,024 (PERF.md PR 27). Where every row costs
+    churn event at 1,048,576 x 1,024 (PERF.md PR 27). The two-level route
+    deals worse: its stay-put is a pull of 0.5 in a feature space whose
+    hashed identities score every node with a standard deviation of 1, so
+    a re-plan over unchanged members re-deals a quarter of the rows (94% of
+    a directory the waterfill seated; PERF.md PR 34). Where every row costs
     the same to move and every destination the same to reach, a node that
-    both loses and receives rows can keep what it loses: the row that would
-    have entered goes where the kept row was headed. Every node's final
-    load is unchanged; no row moves that did not, and ``min(out, in)`` fewer
-    move at every node. O(movers).
+    both loses and receives rows can keep what it loses: of the rows the
+    plan takes off it, as many stay as it takes in, and only the rest go,
+    in node order, to the nodes still short. Every node's final load is
+    unchanged; no row moves that did not, and ``min(out, in)`` fewer move
+    at every node. O(movers log movers), in NumPy: a two-level plan hands in
+    millions of movers, and this runs beside the servers' loop.
     """
     cur = np.asarray(cur_idx, assignment.dtype)
     movers = np.flatnonzero(assignment != cur)
     if movers.size == 0:
         return assignment
     m = int(max(assignment.max(), cur.max())) + 1
-    src, dst = cur[movers], assignment[movers]
-    transit = np.minimum(np.bincount(src, minlength=m), np.bincount(dst, minlength=m))
+    src = cur[movers]
+    lose = np.bincount(src, minlength=m)
+    gain = np.bincount(assignment[movers], minlength=m)
+    transit = np.minimum(lose, gain)
     if not transit.any():
         return assignment
+    order = np.argsort(src, kind="stable")
+    src = src[order]
+    rank = np.arange(movers.size) - (np.cumsum(lose) - lose)[src]
+    stays = rank < transit[src]
     out = assignment.copy()
-    src_l, dst_l = src.tolist(), dst.tolist()
-    leave: dict[int, set] = {}
-    enter: dict[int, set] = {}
-    for i, (a, b) in enumerate(zip(src_l, dst_l)):
-        leave.setdefault(a, set()).add(i)
-        enter.setdefault(b, set()).add(i)
-    for j in np.flatnonzero(transit).tolist():
-        leaving, entering = leave.get(j, set()), enter.get(j, set())
-        while leaving and entering:
-            a, b = leaving.pop(), entering.pop()
-            d = dst_l[a]
-            enter[d].discard(a)
-            dst_l[a] = j  # a stays where it is
-            dst_l[b] = d
-            if src_l[b] == d:  # b was coming from where a was going: it stays too
-                leave[d].discard(b)
-            else:
-                enter[d].add(b)
-    out[movers] = np.asarray(dst_l, out.dtype)
+    out[movers[order[stays]]] = src[stays]
+    out[movers[order[~stays]]] = np.repeat(
+        np.arange(m, dtype=out.dtype), gain - transit
+    )
     return out
 
 
@@ -889,7 +929,12 @@ class JaxObjectPlacement(ObjectPlacement):
         # (multihost.initialize has to run before any backend touch), and
         # the first actual solve initializes it anyway.
         self._mode = mode
+        # A mesh that is given shards every solve it can. With none, a
+        # two-level solve past _FLAT_REBALANCE_MAX_ROWS shards over the
+        # backend's local devices (solve_route); that mesh is built at the
+        # first such solve (_route_mesh), for the reason "auto" waits.
         self._mesh = mesh
+        self._local_mesh = None
         # Stay-put discount applied to each object's CURRENT seat during a
         # full re-solve: a move costs a state reload + cold cache at the
         # application layer, so the objective must price it. With
@@ -992,6 +1037,11 @@ class JaxObjectPlacement(ObjectPlacement):
         # Rows the delta route found displaced and moved (``place_gauges``).
         self._delta_displaced = 0
         self._delta_moved = 0
+        # Committed solves that were sharded over a mesh, the devices of the
+        # last and the cells (devices x chunks) of all (``place_gauges``).
+        self._mesh_solves = 0
+        self._mesh_devices = 0
+        self._mesh_cells = 0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1048,6 +1098,26 @@ class JaxObjectPlacement(ObjectPlacement):
                     "sinkhorn" if jax.default_backend() == "tpu" else "greedy"
                 )
         return self._mode
+
+    def _route_devices(self) -> int:
+        """Devices a solve may shard over: the given mesh's, else the
+        backend's local ones (a backend touch, like ``_solver_mode``)."""
+        if self._mesh is not None:
+            return int(self._mesh.devices.size)
+        return jax.local_device_count()
+
+    def _route_mesh(self, route: SolveRoute):
+        """The mesh ``route`` shards over, or None: the one given, else one
+        over the local devices, built the first time a route asks for it."""
+        if not route.shard:
+            return None
+        if self._mesh is not None:
+            return self._mesh
+        if self._local_mesh is None:
+            from ..parallel import make_mesh
+
+            self._local_mesh = make_mesh(jax.local_devices())
+        return self._local_mesh
 
     def _archived_history(self) -> list:
         """Current stats (if any solve/attempt happened) appended to its
@@ -1158,6 +1228,9 @@ class JaxObjectPlacement(ObjectPlacement):
             "rio.load.derate_steps": float(self._derate_steps),
             "rio.place.delta.displaced": float(self._delta_displaced),
             "rio.place.delta.moved": float(self._delta_moved),
+            "rio.solve.mesh.solves": float(self._mesh_solves),
+            "rio.solve.mesh.devices": float(self._mesh_devices),
+            "rio.solve.mesh.cells": float(self._mesh_cells),
             "rio.place.index_tracked_rows": float(
                 sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
             ),
@@ -1731,12 +1804,13 @@ class JaxObjectPlacement(ObjectPlacement):
         seat = None
         if move_cost > 0.0 and cur_idx is not None and node_order:
             # Stay-put pull for routed flat-mode solves (see
-            # _hierarchical_solve docstring). Node embeddings are unit
-            # vectors; cross-affinities of random unit vectors are
-            # ~1/sqrt(d) noise, so adding move_cost of the current seat's
-            # embedding raises the seat's affinity by ~move_cost relative
-            # to everywhere else — the feature-space analog of the flat
-            # path's stay-put diagonal discount.
+            # _hierarchical_solve docstring): adding move_cost of the
+            # current seat's unit embedding raises the seat's affinity by
+            # move_cost. A BIAS, not a fence: a hashed identity scores every
+            # node with a standard deviation of 1 (16 standard normals
+            # against a unit vector), so at move_cost 0.5 most rows still
+            # follow their hash. What keeps a routed re-plan's rows in
+            # place is _cancel_transit on the plan's loads (rebalance).
             node_emb = np.asarray(self._node_features(node_order), np.float32)
             seat = np.asarray(cur_idx, np.int64)
         out: np.ndarray | None = None
@@ -1774,7 +1848,7 @@ class JaxObjectPlacement(ObjectPlacement):
     def _hierarchical_solve(
         self, keys: list[str], node_order: list[str], cap, alive,
         cur_idx=None, move_cost: float = 0.0, move_w=None,
-        coarse_g_init=None,
+        coarse_g_init=None, mesh=None,
     ):
         """Two-level OT re-solve over hashed identity features.
 
@@ -1788,9 +1862,11 @@ class JaxObjectPlacement(ObjectPlacement):
         into feature space when a sinkhorn/scaling rebalance is routed here
         at scale: each seated object's feature is pulled ``move_cost``
         toward its current node's embedding (the same cache-warmth encoding
-        AffinityTracker learns from traffic), so only capacity pressure —
-        dead nodes, skew — moves anything, instead of every quota ripple
-        reshuffling millions of actors. Native ``mode="hierarchical"``
+        AffinityTracker learns from traffic). The pull biases the plan and
+        fences nothing (``_build_obj_feat``); that only capacity pressure —
+        dead nodes, skew — moves anything is the caller's doing, which
+        keeps the plan's loads and re-expresses them with the fewest moves
+        (``_cancel_transit``). Native ``mode="hierarchical"``
         solves don't use it: there the tracker's learned features are the
         stickiness mechanism and double-counting would over-stick.
 
@@ -1804,10 +1880,11 @@ class JaxObjectPlacement(ObjectPlacement):
         capacity proportions, so the mean is a valid seed) — and ``conv``
         is the convergence record (iterations, residual, warm ratio,
         chunk/device fan-out, per-chunk timings) SolveStats surfaces.
-        Dispatch composes both scale mechanisms: mesh devices divide the
-        rows first, then per-device chunking bounds what one body
-        compiles (conv gains ``mode_suffix="+mesh_chunk"`` when both are
-        active, surfaced in ``SolveStats.mode``).
+        Dispatch composes both scale mechanisms: ``mesh``'s devices (the
+        caller's route says which mesh, if any) divide the rows first, then
+        per-device chunking bounds what one body compiles (conv gains
+        ``mode_suffix="+mesh_chunk"`` when both are active, surfaced in
+        ``SolveStats.mode``).
         """
         from ..parallel.hierarchical import hierarchical_assign
 
@@ -1856,7 +1933,7 @@ class JaxObjectPlacement(ObjectPlacement):
         # (mesh_chunked_hierarchical_assign) instead of excluding each
         # other. Doubling n_chunks while halves stay exact keeps every
         # shape static for any po2 bucket and chunk-row override.
-        n_shards = 1 if self._mesh is None else int(self._mesh.devices.size)
+        n_shards = 1 if mesh is None else int(mesh.devices.size)
         n_pad = -(-bucket_n // n_shards) * n_shards
         per_dev = n_pad // n_shards
         n_chunks = 1
@@ -1916,7 +1993,7 @@ class JaxObjectPlacement(ObjectPlacement):
             "chunks": n_chunks,
             "devices": n_shards,
         }
-        if self._mesh is not None:
+        if mesh is not None:
             # Shard the object axis across the mesh (the tier this mode is
             # for); obj_feat was built at n_pad (a shard multiple) so every
             # device gets per_dev rows, and the caller's [:n] slice drops
@@ -1934,7 +2011,7 @@ class JaxObjectPlacement(ObjectPlacement):
                 conv["mode_suffix"] = "+mesh_chunk"
                 if os.environ.get("RIO_TPU_CHUNK_TIMING", "1") != "0":
                     res, chunk_ms = _hier.mesh_chunked_hierarchical_assign_timed(
-                        self._mesh, obj_feat,
+                        mesh, obj_feat,
                         jnp.asarray(node_feat),
                         jnp.asarray(cap_np), jnp.asarray(alive_np),
                         n_chunks=n_chunks,
@@ -1944,7 +2021,7 @@ class JaxObjectPlacement(ObjectPlacement):
                     conv["chunk_ms"] = chunk_ms
                 else:
                     res = _hier.mesh_chunked_hierarchical_assign(
-                        self._mesh, obj_feat,
+                        mesh, obj_feat,
                         jnp.asarray(node_feat),
                         jnp.asarray(cap_np), jnp.asarray(alive_np),
                         n_chunks=n_chunks,
@@ -1953,7 +2030,7 @@ class JaxObjectPlacement(ObjectPlacement):
                     )
             else:
                 res = _hier.sharded_hierarchical_assign(
-                    self._mesh, obj_feat, jnp.asarray(node_feat),
+                    mesh, obj_feat, jnp.asarray(node_feat),
                     jnp.asarray(cap_np), jnp.asarray(alive_np),
                     coarse_g_init=jnp.asarray(coarse_g_init),
                     **kw,
@@ -2156,6 +2233,7 @@ class JaxObjectPlacement(ObjectPlacement):
                     fill, _, coarse_new, conv = self._hierarchical_solve(
                         [k for k, _ in disp], node_order, res_cap,
                         res_alive, coarse_g_init=plan.coarse_g,
+                        mesh=self._mesh,
                     )
                     fill = _route_unseatable(
                         np.asarray(fill, np.int32), len(node_order), load,
@@ -2342,7 +2420,7 @@ class JaxObjectPlacement(ObjectPlacement):
             res_alive = (residual > 0).astype(np.float32)
             fill, _, coarse_new, conv = self._hierarchical_solve(
                 disp_keys, node_order, res_cap, res_alive,
-                coarse_g_init=plan.coarse_g,
+                coarse_g_init=plan.coarse_g, mesh=self._mesh,
             )
             fill = _route_unseatable(
                 np.asarray(fill, np.int32), n_real, load, res_alive, res_cap
@@ -2770,45 +2848,18 @@ class JaxObjectPlacement(ObjectPlacement):
                             f"{mode}+delta", displaced, stale, conv,
                         )
             # Decide the actual code path up front so traces, profiler
-            # labels, and SolveStats.mode all agree on what ran.
-            # Non-uniform per-object prices break the identical-cost-rows
-            # precondition of the O(M^2) class collapse, so priced solves
-            # take the dense (or at scale, hierarchical) pipeline.
-            collapse = (
-                mode in ("sinkhorn", "scaling")
-                and self._mesh is None
-                and obj_w is None
+            # labels, and SolveStats.mode all agree on what ran: one pure
+            # rule (solve_route) over the directory's TOTAL padded rows.
+            # Counting rows a device instead sent 4,194,304 rows on four
+            # chips to the dense sharded branch, whose rows x nodes cost is
+            # built whole on the host first.
+            route = solve_route(
+                mode, bucket, self._route_devices(), jax.default_backend(),
+                obj_w is not None, self._mesh is not None,
             )
-            # Above _FLAT_REBALANCE_MAX_ROWS the flat collapsed pipeline is
-            # compile-infeasible on the TPU backend (superlinear compile:
-            # the 10.5M-row expansion never finished a 900 s budget on
-            # v5e, while 1M compiles in ~80 s) — route the re-solve
-            # through the two-level solve, whose chunked form pins compile
-            # to the 655k chunk shape (measured 48 s at 10.5M, 2.6 s
-            # chained execution). Hashed-identity features are the
-            # default, so this needs no user hooks; balance/liveness
-            # quality parity is pinned by tests/test_hierarchical.py.
-            # Per-shard rows are what the backend actually compiles: a
-            # mesh divides the flat shape across devices, a single chip
-            # does not. On a mesh the routed solve lands on the composed
-            # mesh x chunk dispatch inside _hierarchical_solve — devices
-            # divide the rows, then per-device chunking re-bounds the
-            # compile — so routing never trades the flat wall for a
-            # per-shard one.
-            flat_rows = bucket if self._mesh is None else (
-                -(-bucket // int(self._mesh.devices.size))
-            )
-            route_hier = (
-                mode in ("sinkhorn", "scaling")
-                and flat_rows > _FLAT_REBALANCE_MAX_ROWS
-            )
-            if route_hier:
-                collapse = False
-            solved_as = (
-                f"{mode}+hier_at_scale"
-                if route_hier
-                else f"{mode}+collapsed" if collapse else mode
-            )
+            route_hier = route.path == "hierarchical" and mode != "hierarchical"
+            collapse = route.path == "collapsed"
+            solved_as = route.solved_as
             with span("placement_solve", mode=solved_as, n=n):
                 def _repair_exact(assignment_padded):
                     """Exact integer quotas at bucket shape (trace reuse);
@@ -2847,7 +2898,7 @@ class JaxObjectPlacement(ObjectPlacement):
 
                 coarse_g = None
                 conv = {}
-                if mode == "hierarchical" or route_hier:
+                if route.path == "hierarchical":
                     # Never materializes the flat (bucket x node_axis) cost.
                     assignment, g, coarse_g, conv = self._hierarchical_solve(
                         keys, node_order, cap, alive,
@@ -2855,6 +2906,7 @@ class JaxObjectPlacement(ObjectPlacement):
                         move_cost=self._move_cost if route_hier else 0.0,
                         move_w=obj_w if route_hier else None,
                         coarse_g_init=plan.coarse_g if plan is not None else None,
+                        mesh=self._route_mesh(route),
                     )
                     # Mesh x chunk composed dispatch stamps its suffix so
                     # SolveStats.mode attributes the actual executable
@@ -3056,10 +3108,18 @@ class JaxObjectPlacement(ObjectPlacement):
             out = _route_unseatable(
                 np.asarray(assignment)[:n], len(node_order), load, alive, cap
             )
-            if obj_w is None and not route_hier and mode != "hierarchical":
+            if (
+                obj_w is None
+                and mode != "hierarchical"
+                and not (route_hier and self._move_cost <= 0.0)
+            ):
                 # One price for every object and every destination: the
                 # plan's per-node loads are what the solve decided, which
-                # rows carry them is not.
+                # rows carry them is not. That holds for a flat mode routed
+                # through the two-level solve too: its hashed identities
+                # are a balancing proxy, nobody's preference (a move_cost
+                # of 0 says moves are free there, and the rows then go
+                # where the proxy sends them).
                 out = _cancel_transit(out, cur_idx)
             # Communication-graph refinement (full solves only: the delta
             # path returned above, and its warm potentials price pure
@@ -3141,6 +3201,10 @@ class JaxObjectPlacement(ObjectPlacement):
                 if solved_as.endswith("+delta"):
                     self._delta_displaced += displaced
                     self._delta_moved += moved
+                if conv.get("devices", 0) > 1:
+                    self._mesh_solves += 1
+                    self._mesh_devices = conv["devices"]
+                    self._mesh_cells += conv["devices"] * max(1, conv.get("chunks", 1))
                 if g is not None:
                     self._g = g
                     self._g_fp = self._sched_fp()

@@ -6,10 +6,11 @@ hierarchical solve replaces it with two bounded stages over a *factorized*
 affinity (object features x node features, the MXU-friendly form):
 
 1. **Coarse**: nodes are partitioned into ``G`` groups (racks/hosts or
-   contiguous slices); each group gets capacity-weighted mean features and
-   the summed capacity of its live members. One (N x G) Sinkhorn solve +
-   capacity-aware rounding assigns every object a group, with per-group
-   quotas following group capacity.
+   contiguous slices); a group's affinity for an object is that of its
+   best live member (a capacity-weighted mean dilutes one warm node by
+   1/S) and its capacity the sum of its live members'. One (N x G)
+   Sinkhorn solve + capacity-aware rounding assigns every object a group,
+   with per-group quotas following group capacity.
 2. **Fine**: objects are bucketed by group (static bucket size with slack,
    scatter by rank-in-group), and ``G`` independent (B x S) solves run
    batched under ``vmap`` — batched matmuls and batched Sinkhorn, ideal
@@ -37,19 +38,25 @@ the TPU-native redesign.
 from __future__ import annotations
 
 import functools
+from importlib import import_module
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.scaling import scaling_sinkhorn
-from ..ops.sinkhorn import (
-    exact_quota_repair,
-    plan_rounded_assign,
-    route_sentinel_spill,
-)
+# The three solve steps are reached through their modules when a body is
+# TRACED, not bound here at import: whoever replaces one in its module (the
+# benchmark's bfloat16 control does) then lowers this route as it lowers the
+# flat ones. (By module path: ``rio_tpu.ops`` re-exports a function under
+# each module's name.)
+from ..ops.sinkhorn import route_sentinel_spill
+from ..tracing import stage
+
+_scaling = import_module("..ops.scaling", __package__)
+_sinkhorn = import_module("..ops.sinkhorn", __package__)
 
 __all__ = [
     "HierarchicalResult",
@@ -78,6 +85,15 @@ class HierarchicalResult(NamedTuple):
 
 
 _HIER_STATIC = ("n_groups", "bucket", "eps", "coarse_iters", "fine_iters")
+
+# The affinities (features x node embeddings, a contraction over d = 16) are
+# float32 products on every backend. A TPU's default would round both operands
+# to bfloat16 first, and a cost that is then divided by eps = 0.05 carries that
+# into the plan: 1.0e-2 in a coarse potential on four v5e chips against 7e-4
+# in float32 (PERF.md, PR 34), more than a member more or less in a group
+# moves one. Six bfloat16 passes of a 16-deep contraction cost nothing beside
+# the thirty-iteration solves that follow.
+_AFFINITY_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _hierarchical_assign_impl(
@@ -138,7 +154,7 @@ def _hierarchical_assign_impl(
 
     def _group_best(args):
         nf_g, alive_g = args  # (d, S), (S,)
-        scores = obj_feat @ nf_g  # (N, S)
+        scores = jnp.matmul(obj_feat, nf_g, precision=_AFFINITY_PRECISION)  # (N, S)
         scores = jnp.where(alive_g[None, :], scores, -jnp.inf)
         return jnp.max(scores, axis=1)  # (N,)
 
@@ -155,16 +171,16 @@ def _hierarchical_assign_impl(
         live_group[None, :], raw_cost / jnp.maximum(std, 1e-6), 1e6
     )
     mass = jnp.ones((n,), jnp.float32)
-    res_c = scaling_sinkhorn(
+    res_c = _scaling.scaling_sinkhorn(
         coarse_cost, mass, group_cap, eps=eps, n_iters=coarse_iters,
         g_init=coarse_g_init,
     )
-    group = plan_rounded_assign(coarse_cost, res_c.f, res_c.g, eps)  # (N,)
+    group = _sinkhorn.plan_rounded_assign(coarse_cost, res_c.f, res_c.g, eps)  # (N,)
     # Exact group quotas: CDF rounding matches group capacities only in
     # expectation; the repair pins every group to its largest-remainder
     # quota, so a bucket sized >= max quota makes overflow structurally
     # impossible (instead of merely improbable).
-    group = exact_quota_repair(
+    group = _sinkhorn.exact_quota_repair(
         group, group_cap / jnp.maximum(jnp.sum(group_cap), 1e-30) * n
     )
 
@@ -190,14 +206,16 @@ def _hierarchical_assign_impl(
     obj_feat_pad = jnp.concatenate([obj_feat, jnp.zeros((1, d), jnp.float32)], 0)
     feat_b = obj_feat_pad[idx]  # (G, B, d)
     node_feat_g = node_feat.reshape(d, n_groups, s).transpose(1, 0, 2)  # (G, d, S)
-    fine_cost = -jnp.einsum("gbd,gds->gbs", feat_b, node_feat_g)  # (G, B, S)
+    fine_cost = -jnp.einsum(
+        "gbd,gds->gbs", feat_b, node_feat_g, precision=_AFFINITY_PRECISION
+    )  # (G, B, S)
     fine_cost = fine_cost / jnp.maximum(jnp.std(fine_cost), 1e-6)
     fine_mass = (idx < n).astype(jnp.float32)  # (G, B)
     cap_g = cap.reshape(n_groups, s)  # (G, S)
 
     def solve_one(c, a, b):
-        r = scaling_sinkhorn(c, a, b, eps=eps, n_iters=fine_iters)
-        local = plan_rounded_assign(c, r.f, r.g, eps)
+        r = _scaling.scaling_sinkhorn(c, a, b, eps=eps, n_iters=fine_iters)
+        local = _sinkhorn.plan_rounded_assign(c, r.f, r.g, eps)
         # Exact per-node quotas within the group (same largest-remainder
         # repair as the coarse stage): padding rows go to a sentinel slot
         # sized to their count, so real rows land exactly on capacity
@@ -208,7 +226,7 @@ def _hierarchical_assign_impl(
         expected = jnp.concatenate(
             [b / jnp.maximum(jnp.sum(b), 1e-30) * n_real, pad_count]
         )
-        repaired = exact_quota_repair(local, expected)
+        repaired = _sinkhorn.exact_quota_repair(local, expected)
         # Real rows spilled onto the sentinel column (quota drift / refill
         # clip) would be take_along_axis-clamped onto member s-1, which may
         # be dead — route them to the group's best live member instead.
@@ -528,14 +546,20 @@ def _mesh_cell_solver(mesh: Mesh, scale: int, n_groups: int, kw_key: tuple):
             coarse_err=jax.lax.pmean(res.coarse_err, axes),
         )
 
-    fn = shard_map(
+    sharded = shard_map(
         local_solve,
         mesh=mesh,
         in_specs=(P(axes, None), P(), P(), P(), P()),
         out_specs=_hier_out_specs(axes),
         check_vma=False,
     )
-    return jax.jit(fn)
+
+    # A name of its own: the device trace shows ``jit_mesh_cell_solve`` on
+    # every device's plane, one execution a chunk step.
+    def mesh_cell_solve(of, nf, cap, al, g0):
+        return sharded(of, nf, cap, al, g0)
+
+    return jax.jit(mesh_cell_solve)
 
 
 def mesh_chunked_hierarchical_assign_timed(
@@ -588,33 +612,43 @@ def mesh_chunked_hierarchical_assign_timed(
     jax.block_until_ready((of, node_feat, node_capacity, alive))
     shard_spec = NamedSharding(mesh, P(mesh.axis_names, None))
     rep_inputs = None
-    assignments: list[jax.Array] = []
-    groups: list[jax.Array] = []
-    overflow = jnp.zeros((), jnp.int32)
+    assignments: list = []
+    groups: list = []
+    overflow = 0
     chunk_ms: list[float] = []
     res = None
     for c in range(n_chunks):
         t0 = _time.perf_counter()
-        slab = of[:, c].reshape(n_shards * cell, d)
-        if rep_inputs is None:
-            slab, nf, cap, al, g0 = _mesh_inputs(
-                mesh, slab, node_feat, node_capacity, alive,
-                coarse_g_init, n_groups,
-            )
-            rep_inputs = (nf, cap, al, g0)
-        else:
-            slab = jax.device_put(slab, shard_spec)
-            nf, cap, al, g0 = rep_inputs
-        res = solve(slab, nf, cap, al, g0)
-        jax.block_until_ready(res.assignment)
+        with stage("solve.mesh.inputs"):
+            slab = of[:, c].reshape(n_shards * cell, d)
+            if rep_inputs is None:
+                slab, *rep_inputs = _mesh_inputs(
+                    mesh, slab, node_feat, node_capacity, alive,
+                    coarse_g_init, n_groups,
+                )
+            else:
+                slab = jax.device_put(slab, shard_spec)
+            # The transfer is the inputs' time, not the first cell's.
+            jax.block_until_ready(slab)
+        with stage("solve.mesh.cells"):
+            res = solve(slab, *rep_inputs)
+            jax.block_until_ready(res.assignment)
         chunk_ms.append(round((_time.perf_counter() - t0) * 1e3, 3))
-        assignments.append(res.assignment.reshape(n_shards, cell))
-        groups.append(res.group.reshape(n_shards, cell))
+        assignments.append(res.assignment)
+        groups.append(res.group)
         overflow = overflow + res.overflow
     # Chunk results stack to (shard, chunk, cell) when interleaved back on
     # axis 1 — the shard-major global row order the input was reshaped from.
-    asn = jnp.stack(assignments, axis=1).reshape(-1)
-    grp = jnp.stack(groups, axis=1).reshape(-1)
+    # Each result is pulled shard by shard to the host and interleaved there:
+    # stacked on the devices it would be one more program across all of them
+    # for an array the caller reads on the host anyway.
+    def interleaved(parts):
+        return np.stack(
+            [np.asarray(x).reshape(n_shards, cell) for x in parts], axis=1
+        ).reshape(-1)
+
+    with stage("solve.mesh.gather"):
+        asn, grp = interleaved(assignments), interleaved(groups)
     return (
         HierarchicalResult(
             assignment=asn,

@@ -229,10 +229,10 @@ def test_a_plan_over_interchangeable_rows_is_re_expressed_with_the_fewest_moves(
         assert moved.sum() == np.maximum(net, 0).sum()
 
 
-def _cell(args, cwd=REPO, timeout=420):
+def _cell(args, cwd=REPO, timeout=420, **more_env):
     cmd = [sys.executable, str(Path(cwd) / "benchmark" / "run.py"), *args, "--rehearse-on-cpu"]
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    p = subprocess.run(cmd, cwd=cwd, env={**env, "JAX_PLATFORMS": "cpu"},
+    p = subprocess.run(cmd, cwd=cwd, env={**env, "JAX_PLATFORMS": "cpu", **more_env},
                        capture_output=True, text=True, timeout=timeout)
     assert p.returncode == 0, p.stderr[-2000:]
     return [json.loads(x) for x in p.stdout.strip().splitlines()]
@@ -257,6 +257,34 @@ def test_the_churn_cell_rehearses_end_to_end():
     assert m["solves_discarded_per_event"] <= 1 and m["derate_steps_per_s"] < 0.5
     summary = next(x["summary"] for x in lines if "summary" in x)
     assert summary["daemon_rebalances_in_window"]["rebalances"] >= 3  # one an event
+
+
+def test_the_four_chip_cell_rehearses_on_the_mesh_route():
+    """``presence-4m-1k-mesh.resolve`` (PR 34) binds its members at the churn
+    cell's addresses, so its rehearsal lives in this file too. The
+    environment makes 8,192 rows take the chip's route: 4 devices x 2 chunks;
+    the directory is built with no ``mesh=``."""
+    lines = _cell(
+        ["--workload", "presence-4m-1k-mesh.resolve", "--seed", "2147484001", "--seconds", "8",
+         "--trace", "1"],
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+        RIO_TPU_FLAT_REBALANCE_MAX_ROWS="1024", RIO_TPU_HIER_CHUNK_ROWS="1024",
+    )
+    last, checks = lines[-1], _served(lines)
+    assert last["correct"] is True, [x for x in lines if x.get("ok") is False]
+    assert last["attempted"] > 0 and last["failed"] == 0
+    replans = [x["replan"] for x in lines if "replan" in x]
+    assert sum(r["in_window"] for r in replans) == 3 and len(replans) == 5
+    assert all((r["mode"], r["devices"], r["chunks"]) ==
+               ("sinkhorn+hier_at_scale+mesh_chunk", 4, 2) for r in replans)
+    assert all(r["moved"] <= 0.01 * 8192 for r in replans)
+    assert checks["compiles_in_window"]["value"] == 0
+    assert checks["after_window.resolve.moves_over_least"]["value"] == 0
+    assert checks["after_window.resolve.rows_off_reference_loads"]["value"] == 0
+    assert checks["after_window.quota_miss_max_seats"]["ok"]
+    m = {k: v["value"] for k, v in last["metrics"].items()}
+    assert {"resolve_ms", "solve_features_ms.mesh", "solve_exec_ms.mesh",
+            "solve_apply_ms.mesh"} <= set(m)
 
 
 @pytest.mark.parametrize("objects, nodes, live, leave", [
