@@ -1042,6 +1042,15 @@ class JaxObjectPlacement(ObjectPlacement):
         self._mesh_solves = 0
         self._mesh_devices = 0
         self._mesh_cells = 0
+        # The default hook's hashed identities of the last whole-directory
+        # two-level solve: ``(keys, rows)``, row i the float32 feature of
+        # keys[i] before any pull. ONE atomically swapped field, neither
+        # half ever mutated (two ``to_thread`` solves may overlap); see
+        # ``_hashed_identities``. The counters are rows of committed solves
+        # that came from it and rows that were hashed (``place_gauges``).
+        self._kept_identities: tuple[list[str], np.ndarray] | None = None
+        self._feat_rows_reused = 0
+        self._feat_rows_hashed = 0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1231,6 +1240,8 @@ class JaxObjectPlacement(ObjectPlacement):
             "rio.solve.mesh.solves": float(self._mesh_solves),
             "rio.solve.mesh.devices": float(self._mesh_devices),
             "rio.solve.mesh.cells": float(self._mesh_cells),
+            "rio.solve.features.rows_reused": float(self._feat_rows_reused),
+            "rio.solve.features.rows_hashed": float(self._feat_rows_hashed),
             "rio.place.index_tracked_rows": float(
                 sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
             ),
@@ -1769,20 +1780,8 @@ class JaxObjectPlacement(ObjectPlacement):
             self._nodes[self._node_order[j]].load += float(counts[j])
         self._epoch += 1
 
-    def _build_obj_feat(
-        self, keys: list[str], n_pad: int, node_order: list[str],
-        cur_idx, move_cost: float, move_w,
-    ) -> np.ndarray:
-        """Streamed (n_pad, d) object-feature block for a hierarchical solve.
-
-        The old pipeline materialized three full-size intermediates at once
-        (raw features, the stay-put pull, and the padded concat) — 1.9 GB
-        of throwaway peak at 10M x 16 fp32. This builder preallocates the
-        FINAL block once and fills it in bounded key-chunks
-        (``_OBJ_FEAT_STREAM_ROWS``): per chunk it calls the feature hook,
-        sanitizes, applies the stay-put pull, and writes rows in place —
-        peak is the output plus one chunk. ``RIO_TPU_HIER_FEAT_BF16=1``
-        stores the block in bfloat16 (:func:`_hier_feature_dtype`).
+    def _hook_rows(self, keys: list[str]) -> np.ndarray:
+        """The feature hook's float32 rows for one bounded slice of keys.
 
         Sanitize is load-bearing, not belt-and-braces: measured load
         vectors reach the solver only through ``ClusterLoadView``'s
@@ -1792,6 +1791,74 @@ class JaxObjectPlacement(ObjectPlacement):
         0.0 (a zero feature row still spreads correctly under the
         capacity marginals; copy-on-write, since the hook may hand us its
         internal buffer).
+        """
+        feats = np.asarray(self._obj_features(keys), np.float32)
+        if not np.isfinite(feats).all():
+            feats = np.nan_to_num(feats, nan=0.0, posinf=0.0, neginf=0.0)
+        return feats
+
+    def _hashed_identities(self, keys: list[str]) -> tuple[np.ndarray, int]:
+        """The default hook's rows for a whole-directory snapshot, from the
+        block the last such solve kept where the keys are where they were.
+
+        A hashed identity is a pure function of its key string, so the
+        rows are kept positionally beside the key list they were made
+        from. Validity is checked here, in the solver thread, and promised
+        by nobody: the kept list must equal the first ``len(kept)`` keys of
+        this snapshot (one list comparison in C that compares pointers
+        first, and a snapshot hands out the directory's own key objects:
+        tens of ms at 4M rows, nothing per key in Python). Then only the
+        tail is hashed — a dict keeps insertion order and ``update()``
+        keeps a key's place, so a directory that grows appends. Anything
+        else (a key removed, ``clean_server`` un-seating rows, another
+        order) hashes every row as before. Returns ``(rows, reused)``;
+        ``rows`` is read-only and shared with later solves. Costs 64 B a
+        row of host memory while the directory solves this way.
+        """
+        n = len(keys)  # never 0: an empty directory makes no solve
+        kept_keys, old = self._kept_identities or ([], None)
+        m = len(kept_keys)
+        reused = m if 0 < m <= n and kept_keys == (keys if m == n else keys[:m]) else 0
+        if reused == n:
+            return old, n
+        rows: np.ndarray | None = None
+        step = max(1, _OBJ_FEAT_STREAM_ROWS)
+        for start in range(reused, n, step):
+            feats = self._hook_rows(keys[start : start + step])
+            if rows is None:
+                rows = np.empty((n, feats.shape[1]), np.float32)
+                if reused:
+                    rows[:reused] = old
+            rows[start : start + len(feats)] = feats
+        rows.flags.writeable = False
+        self._kept_identities = (keys, rows)  # atomic swap
+        return rows, reused
+
+    def _build_obj_feat(
+        self, keys: list[str], n_pad: int, node_order: list[str],
+        cur_idx, move_cost: float, move_w, whole: bool = False,
+    ) -> tuple[np.ndarray, int, int]:
+        """Streamed (n_pad, d) object-feature block for a hierarchical solve.
+
+        The old pipeline materialized three full-size intermediates at once
+        (raw features, the stay-put pull, and the padded concat) — 1.9 GB
+        of throwaway peak at 10M x 16 fp32. This builder preallocates the
+        FINAL block once and fills it in bounded key-chunks
+        (``_OBJ_FEAT_STREAM_ROWS``): per chunk it takes the feature rows,
+        applies the stay-put pull, and writes rows in place —
+        peak is the output plus one chunk. ``RIO_TPU_HIER_FEAT_BF16=1``
+        stores the block in bfloat16 (:func:`_hier_feature_dtype`).
+
+        Where the rows come from. ``whole`` says the keys are the
+        directory's whole snapshot in its order (a full re-solve, not a
+        delta's displaced subset). With the default hook such a solve takes
+        its rows from :meth:`_hashed_identities`, which hashes only keys it
+        has not seen at their place; the block is the same to the last bit
+        either way, since the pull, the pad rows and the dtype are applied
+        here, per solve. A user hook depends on traffic: it is called on
+        every key at every solve (:meth:`_hook_rows`) and nothing is kept.
+        Returns ``(block, rows_reused, rows_hashed)``; both counts are 0
+        under a user hook and for a subset.
 
         Pad rows (``n_pad - n``: po2 bucket padding plus the mesh's
         shard-multiple round-up) come from the cached deterministic block
@@ -1800,6 +1867,13 @@ class JaxObjectPlacement(ObjectPlacement):
         """
         n = len(keys)
         dtype = _hier_feature_dtype()
+        identities = None
+        reused = 0
+        if whole:
+            if self._obj_features is _hash_features and n:
+                identities, reused = self._hashed_identities(keys)
+            else:
+                self._kept_identities = None
         node_emb = None
         seat = None
         if move_cost > 0.0 and cur_idx is not None and node_order:
@@ -1816,39 +1890,42 @@ class JaxObjectPlacement(ObjectPlacement):
         out: np.ndarray | None = None
         step = max(1, _OBJ_FEAT_STREAM_ROWS)
         for start in range(0, n, step):
-            chunk_keys = keys[start : start + step]
-            feats = np.asarray(self._obj_features(chunk_keys), np.float32)
-            if not np.isfinite(feats).all():
-                feats = np.nan_to_num(feats, nan=0.0, posinf=0.0, neginf=0.0)
+            end = min(n, start + step)
+            if identities is not None:
+                feats = identities[start:end]
+            else:
+                feats = self._hook_rows(keys[start:end])
             if out is None:
                 out = np.empty((n_pad, feats.shape[1]), dtype)
             if node_emb is not None:
-                s = seat[start : start + len(chunk_keys)]
+                s = seat[start:end]
                 seated = (s >= 0) & (s < len(node_order))
-                pull = np.zeros_like(feats)
-                pull[seated] = node_emb[s[seated]]
+                if seated.all():
+                    pull = node_emb.take(s, axis=0)
+                else:
+                    pull = np.zeros_like(feats)
+                    pull[seated] = node_emb[s[seated]]
                 if move_w is not None:
                     # Per-object move prices (object_costs): a hot/heavy
                     # actor's pull toward its current seat scales with its
                     # measured weight, mirroring the dense path's scaled
                     # stay-put discount.
-                    pull *= np.asarray(
-                        move_w[start : start + len(chunk_keys)], np.float32
-                    )[:, None]
-                feats = feats + np.float32(move_cost) * pull
-            out[start : start + len(chunk_keys)] = feats
+                    pull *= np.asarray(move_w[start:end], np.float32)[:, None]
+                pull *= np.float32(move_cost)
+                feats = np.add(feats, pull, out=pull)
+            out[start:end] = feats
         if out is None:  # empty directory: shape from the hook's contract
             probe = np.asarray(self._obj_features([]), np.float32)
             d = probe.shape[1] if probe.ndim == 2 else _FEAT_DIM
             out = np.empty((n_pad, d), dtype)
         if n_pad > n:
             out[n:] = _pad_feature_block(n_pad - n, out.shape[1])
-        return out
+        return out, reused, (n - reused if identities is not None else 0)
 
     def _hierarchical_solve(
         self, keys: list[str], node_order: list[str], cap, alive,
         cur_idx=None, move_cost: float = 0.0, move_w=None,
-        coarse_g_init=None, mesh=None,
+        coarse_g_init=None, mesh=None, whole: bool = False,
     ):
         """Two-level OT re-solve over hashed identity features.
 
@@ -1884,7 +1961,8 @@ class JaxObjectPlacement(ObjectPlacement):
         caller's route says which mesh, if any) divide the rows first, then
         per-device chunking bounds what one body compiles (conv gains
         ``mode_suffix="+mesh_chunk"`` when both are active, surfaced in
-        ``SolveStats.mode``).
+        ``SolveStats.mode``). ``whole`` is the full re-solve's word that
+        ``keys`` is the directory's snapshot (``_build_obj_feat``).
         """
         from ..parallel.hierarchical import hierarchical_assign
 
@@ -1951,8 +2029,8 @@ class JaxObjectPlacement(ObjectPlacement):
         )
 
         with stage("solve.features"):
-            obj_feat = self._build_obj_feat(
-                keys, n_pad, node_order, cur_idx, move_cost, move_w
+            obj_feat, rows_reused, rows_hashed = self._build_obj_feat(
+                keys, n_pad, node_order, cur_idx, move_cost, move_w, whole
             )
         d_feat = obj_feat.shape[1]
         node_feat = np.zeros((d_feat, m), np.float32)
@@ -1992,6 +2070,8 @@ class JaxObjectPlacement(ObjectPlacement):
             "warm_ratio": warm_ratio,
             "chunks": n_chunks,
             "devices": n_shards,
+            "feat_rows_reused": rows_reused,
+            "feat_rows_hashed": rows_hashed,
         }
         if mesh is not None:
             # Shard the object axis across the mesh (the tier this mode is
@@ -2132,7 +2212,8 @@ class JaxObjectPlacement(ObjectPlacement):
 
         The dominant per-event host cost of a churn rebalance at directory
         scale is not the solve — it is materializing the O(N) key/seat
-        array snapshot (~0.35 s per million objects). For the dominant
+        array snapshot (~0.045 s per million objects on the chip host, on
+        the loop and under the lock). For the dominant
         churn shape — nodes LEAVING the schedulable set with every
         survivor at or under its integer fair quota — the displaced set is
         exactly the departed nodes' seats, which ``_by_node`` already
@@ -2776,12 +2857,14 @@ class JaxObjectPlacement(ObjectPlacement):
                 if fast is None and n:
                     keys = list(self._placements.keys())
                     # values() iterates in keys() order (insertion order) and
-                    # skips the per-key hash lookup a genexpr would pay — the
-                    # snapshot was ~0.35 s/1M objects as a genexpr.
+                    # skips the per-key hash lookup a genexpr would pay: the
+                    # snapshot reads ~0.045 s a million rows on the chip host
+                    # (150-200 ms at 4,194,304; ~0.35 s/1M as a genexpr).
                     cur_idx = np.fromiter(
                         self._placements.values(), np.int32, count=n
                     )
         if not n:
+            self._kept_identities = None  # an empty directory keeps no rows
             return 0
         if fast is not None:
             return await self._delta_fast_rebalance(
@@ -2907,6 +2990,7 @@ class JaxObjectPlacement(ObjectPlacement):
                         move_w=obj_w if route_hier else None,
                         coarse_g_init=plan.coarse_g if plan is not None else None,
                         mesh=self._route_mesh(route),
+                        whole=True,  # the snapshot: hashed identities are kept
                     )
                     # Mesh x chunk composed dispatch stamps its suffix so
                     # SolveStats.mode attributes the actual executable
@@ -3205,6 +3289,8 @@ class JaxObjectPlacement(ObjectPlacement):
                     self._mesh_solves += 1
                     self._mesh_devices = conv["devices"]
                     self._mesh_cells += conv["devices"] * max(1, conv.get("chunks", 1))
+                self._feat_rows_reused += conv.get("feat_rows_reused", 0)
+                self._feat_rows_hashed += conv.get("feat_rows_hashed", 0)
                 if g is not None:
                     self._g = g
                     self._g_fp = self._sched_fp()

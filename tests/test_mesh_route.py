@@ -303,3 +303,207 @@ def test_the_affinities_are_contracted_in_float32_on_every_backend():
     assert len(affinities) == 2
     for eqn in affinities:
         assert "HIGHEST" in str(eqn.params["precision"]), eqn
+
+
+# -- the hashed identities kept between re-plans (PR 36) ----------------------
+
+N_KEPT = 3000
+
+
+def _spy_on_the_feature_build(p, monkeypatch):
+    """Every ``_build_obj_feat`` call's arguments and what it returned."""
+    calls: list = []
+    build = p._build_obj_feat
+
+    def spy(*args):
+        calls.append((args, build(*args)))
+        return calls[-1][1]
+
+    monkeypatch.setattr(p, "_build_obj_feat", spy)
+    return calls, build
+
+
+async def _nothing(p, ids):
+    return N_KEPT, 0
+
+
+async def _seats_changed(p, ids):
+    for i in range(0, N_KEPT, 7):  # a hand-off's commit: the key keeps its place
+        p._set_placement(str(ids[i]), (p._placements[str(ids[i])] + 1) % 13)
+    return N_KEPT, 0
+
+
+async def _keys_appended(p, ids):
+    await p.assign_batch([ObjectId("R", f"late{i}") for i in range(90)])
+    return N_KEPT, 90
+
+
+async def _a_key_deleted(p, ids):
+    await p.remove(ids[1234])
+    return 0, N_KEPT - 1
+
+
+async def _a_key_deleted_and_seated_again(p, ids):
+    await p.remove(ids[1234])
+    await p.assign_batch([ids[1234]])  # the same key, now last in the order
+    return 0, N_KEPT
+
+
+async def _a_member_cleaned(p, ids):
+    victim = p._node_order[5]
+    gone = sum(1 for i in p._placements.values() if i == 5)
+    await p.clean_server(victim)
+    return 0, N_KEPT - gone
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "RIO_TPU_HIER_FEAT_BF16"])
+@pytest.mark.parametrize("between", [
+    _nothing, _seats_changed, _keys_appended, _a_key_deleted,
+    _a_key_deleted_and_seated_again, _a_member_cleaned,
+], ids=lambda f: f.__name__.strip("_"))
+async def test_a_second_solves_block_is_a_fresh_builds_to_the_last_bit(
+    monkeypatch, between, bf16,
+):
+    """Rows whose key is where it was come from the kept block, the rest
+    are hashed; the block handed to the mesh is byte for byte what a build
+    from the key strings gives, and the counters say which rows were which."""
+    monkeypatch.setattr(jp, "_FLAT_REBALANCE_MAX_ROWS", 1024)
+    monkeypatch.setattr(jp, "_HIER_CHUNK_ROWS", 256)
+    monkeypatch.setattr(jp, "_OBJ_FEAT_STREAM_ROWS", 700)  # several chunks, a ragged last
+    if bf16:
+        monkeypatch.setenv("RIO_TPU_HIER_FEAT_BF16", "1")
+    p = JaxObjectPlacement(mode="sinkhorn", n_iters=10)
+    ids = await _seated(p, N_KEPT, _nodes(16))
+    calls, build = _spy_on_the_feature_build(p, monkeypatch)
+    await p.rebalance(delta=False)
+    assert p.stats.mode == "sinkhorn+hier_at_scale+mesh_chunk"
+    assert calls[0][1][1:] == (0, N_KEPT)  # the first solve hashes every row
+    g0 = p.place_gauges()
+    want = await between(p, ids)
+    await p.rebalance(delta=False)
+    (args, (block, reused, hashed)), = calls[1:]
+    assert (reused, hashed) == want and reused + hashed == len(p._placements)
+    g1 = p.place_gauges()
+    assert g1["rio.solve.features.rows_reused"] - g0["rio.solve.features.rows_reused"] == reused
+    assert g1["rio.solve.features.rows_hashed"] - g0["rio.solve.features.rows_hashed"] == hashed
+    assert block.dtype == jp._hier_feature_dtype() and block.dtype.itemsize == (2 if bf16 else 4)
+    # The same snapshot, built from the key strings with nothing kept.
+    kept_keys, kept_rows = p._kept_identities
+    assert kept_keys == args[0] and kept_rows.shape == (len(args[0]), 16)
+    assert not kept_rows.flags.writeable
+    p._kept_identities = None
+    fresh, fresh_reused, fresh_hashed = build(*args)
+    assert (fresh_reused, fresh_hashed) == (0, len(args[0]))
+    assert fresh.tobytes() == block.tobytes()
+    assert p._kept_identities[1].tobytes() == kept_rows.tobytes()
+    if between is _seats_changed:  # the pull differed, the identities did not
+        assert calls[0][1][0].tobytes() != block.tobytes()
+
+
+async def test_a_user_hook_is_called_on_every_key_at_every_solve_and_nothing_is_kept(
+    monkeypatch,
+):
+    monkeypatch.setattr(jp, "_FLAT_REBALANCE_MAX_ROWS", 1024)
+    monkeypatch.setattr(jp, "_HIER_CHUNK_ROWS", 256)
+    seen: list = []
+
+    def hook(keys):
+        seen.extend(keys)
+        return np.asarray(jp._hash_features(keys), np.float32)
+
+    p = JaxObjectPlacement(mode="hierarchical", n_iters=10, obj_features=hook)
+    ids = await _seated(p, N_KEPT, _nodes(16))
+    for _ in range(2):
+        seen.clear()
+        await p.rebalance(delta=False)
+        assert p.stats.mode == "hierarchical+mesh_chunk"
+        assert seen == [str(i) for i in ids]
+        assert p._kept_identities is None
+    g = p.place_gauges()
+    assert g["rio.solve.features.rows_reused"] == g["rio.solve.features.rows_hashed"] == 0
+
+
+async def test_a_delta_s_subset_and_an_emptied_directory_leave_nothing_wrong_behind(
+    monkeypatch,
+):
+    """A subset of the keys (what a two-level delta solves) neither reads
+    nor replaces the kept block; a directory that emptied drops it."""
+    monkeypatch.setattr(jp, "_FLAT_REBALANCE_MAX_ROWS", 1024)
+    monkeypatch.setattr(jp, "_HIER_CHUNK_ROWS", 256)
+    p = JaxObjectPlacement(mode="sinkhorn", n_iters=10)
+    ids = await _seated(p, N_KEPT, _nodes(16))
+    await p.rebalance(delta=False)
+    kept = p._kept_identities
+    some = [str(i) for i in ids[100:400]]
+    block, reused, hashed = p._build_obj_feat(some, 512, list(p._node_order), None, 0.0, None)
+    assert (reused, hashed) == (0, 0) and p._kept_identities is kept
+    assert block[:300].tobytes() == kept[1][100:400].tobytes()
+    for i in ids:
+        await p.remove(i)
+    assert await p.rebalance(delta=False) == 0
+    assert p._kept_identities is None
+
+
+async def test_two_overlapping_re_plans_leave_a_consistent_block(monkeypatch):
+    """``rebalance`` has no solve lock: two ``to_thread`` solves over two
+    snapshots may finish in either order, and whichever swaps last leaves
+    keys and rows that belong together."""
+    monkeypatch.setattr(jp, "_FLAT_REBALANCE_MAX_ROWS", 1024)
+    monkeypatch.setattr(jp, "_HIER_CHUNK_ROWS", 256)
+    p = JaxObjectPlacement(mode="sinkhorn", n_iters=10)
+    await _seated(p, N_KEPT, _nodes(16))
+
+    async def grown():
+        await asyncio.sleep(0)  # after the first solve's snapshot
+        await p.assign_batch([ObjectId("R", f"late{i}") for i in range(50)])
+        return await p.rebalance(delta=False)
+
+    await asyncio.gather(p.rebalance(delta=False), grown())
+    keys, rows = p._kept_identities
+    assert len(keys) in (N_KEPT, N_KEPT + 50) and rows.shape == (len(keys), 16)
+    assert keys == list(p._placements)[: len(keys)]
+    assert rows.tobytes() == np.asarray(jp._hash_features(keys), np.float32).tobytes()
+    await p.rebalance(delta=False)  # and the next solve is sound from either
+    keys, rows = p._kept_identities
+    assert keys == list(p._placements)
+    assert rows.tobytes() == np.asarray(jp._hash_features(keys), np.float32).tobytes()
+
+
+def test_many_solver_threads_over_many_snapshots_never_pair_rows_with_other_keys(monkeypatch):
+    """More threads than cores, a short switch interval, snapshots that grow,
+    shrink and reorder: whatever a thread reads or swaps, the rows it gets are
+    its own keys' and the field's two halves belong together."""
+    import sys
+    import threading
+
+    monkeypatch.setattr(jp, "_OBJ_FEAT_STREAM_ROWS", 128)
+    p = JaxObjectPlacement(mode="sinkhorn")
+    base = [f"R.{i}" for i in range(200)]
+    want = np.asarray(jp._hash_features(base), np.float32)
+    shapes = [base[:150], base, base[:120] + base[130:], base[::-1], base[:199]]
+    picks = [list(range(150)), list(range(200)), [*range(120), *range(130, 200)],
+             list(range(199, -1, -1)), list(range(199))]
+    wrong: list = []
+
+    def solver(seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(8):
+            k = int(rng.integers(len(shapes)))
+            rows, reused = p._hashed_identities(list(shapes[k]))
+            if rows.tobytes() != want[picks[k]].tobytes() or not 0 <= reused <= len(shapes[k]):
+                wrong.append((k, reused))
+            kept_keys, kept_rows = p._kept_identities
+            if kept_rows.shape != (len(kept_keys), 16):
+                wrong.append(("field", len(kept_keys), kept_rows.shape))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=solver, args=(s,)) for s in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and not wrong, wrong[:3]
