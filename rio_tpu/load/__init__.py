@@ -599,20 +599,6 @@ class LoadMonitor:
         if placement is not None and hasattr(placement, "sync_load"):
             placement.sync_load(view)
 
-    def stall_gauges(self) -> dict[str, float]:
-        """``rio.load.stall_max_ms`` / ``stall_total_ms``, made at scrape time
-        from the kept lag samples: every tick that woke later than the
-        watchdog's threshold, timed by the loop itself (``stalls`` counts the
-        watchdog's captures, which are cooldown-limited; these are not)."""
-        late = [
-            ms for _, ms in list(self.stats.lag_samples)
-            if 0.0 < self.stall_threshold_ms <= ms
-        ]
-        return {
-            "rio.load.stall_max_ms": max(late, default=0.0),
-            "rio.load.stall_total_ms": float(sum(late)),
-        }
-
     def _drain_pending_stall(self) -> None:
         """Journal a watchdog capture from the loop thread (ring discipline:
         only the loop appends; the watchdog merely parks the evidence)."""
@@ -645,11 +631,14 @@ class LoadMonitor:
             watchdog = _StallWatchdog(self, threading.get_ident(), self.interval)
             watchdog.start()
         # Full collections stop the loop too: while any monitor runs, the
-        # process logs each as a ``gc.gen2`` stage (one callback a process).
+        # process logs each as a ``gc.gen2`` stage (one callback a process),
+        # and the loop keeps its own account of every hold (one tick a loop).
         tracing.watch_gc()
+        tracing.watch_loop()
         try:
             await self._run(loop, last_view)
         finally:
+            tracing.unwatch_loop()
             tracing.unwatch_gc()
             if watchdog is not None:
                 watchdog.stop_event.set()

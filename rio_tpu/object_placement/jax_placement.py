@@ -1591,7 +1591,7 @@ class JaxObjectPlacement(ObjectPlacement):
     @contextlib.asynccontextmanager
     async def _lock_staged(self):
         """``async with self._lock``, the wait logged as ``place.lock_wait``."""
-        with stage("place.lock_wait"):
+        with stage("place.lock_wait", wait=True):
             await self._lock.acquire()
         try:
             yield
@@ -1769,7 +1769,7 @@ class JaxObjectPlacement(ObjectPlacement):
                 [jnp.ones((n,), jnp.float32), jnp.zeros((bucket - n,), jnp.float32)]
             )
             seats = greedy_balanced_assign(rows, mass, cap * alive, load)
-        with stage("place.solve.wait"):  # the device, and the transfer back
+        with stage("place.solve.wait", wait=True):  # the device, and the transfer back
             seats = np.asarray(seats)[:n]
         with stage("place.solve.route"):
             return _route_unseatable(seats, n_real, load, alive, cap)
@@ -2413,7 +2413,8 @@ class JaxObjectPlacement(ObjectPlacement):
                 **_conv_fields(conv),
             )
         if planned:
-            planned.sort(key=lambda mv: (mv[1], mv[2]))
+            with stage("solve.moves"):
+                planned.sort(key=lambda mv: (mv[1], mv[2]))
             # Outside the lock on purpose: handoffs call back into
             # update()/lookup(), which take it.
             await move_sink(planned)
@@ -2824,8 +2825,11 @@ class JaxObjectPlacement(ObjectPlacement):
 
         One call is one ``solve.full`` stage (whichever path it takes) with
         children ``solve.snapshot``, ``solve.device`` (and in it
-        ``solve.features``) and ``solve.apply``; ``SolveStats.solve_ms`` and
-        ``apply_ms`` are the device and apply stages' own stamps.
+        ``solve.features`` and ``solve.transit``: the plan's rows routed and
+        committed with the fewest moves), ``solve.apply`` and, where a sink
+        takes a plan,
+        ``solve.moves`` (the move list ordered for it); ``SolveStats.solve_ms``
+        and ``apply_ms`` are the device and apply stages' own stamps.
         """
         with stage("solve.full"):
             return await self._rebalance(mode, move_sink, delta)
@@ -3189,22 +3193,25 @@ class JaxObjectPlacement(ObjectPlacement):
                         )
                         assignment = jnp.where(keep, cur, refill)
                         g = None
-            out = _route_unseatable(
-                np.asarray(assignment)[:n], len(node_order), load, alive, cap
-            )
-            if (
-                obj_w is None
-                and mode != "hierarchical"
-                and not (route_hier and self._move_cost <= 0.0)
-            ):
-                # One price for every object and every destination: the
-                # plan's per-node loads are what the solve decided, which
-                # rows carry them is not. That holds for a flat mode routed
-                # through the two-level solve too: its hashed identities
-                # are a balancing proxy, nobody's preference (a move_cost
-                # of 0 says moves are free there, and the rows then go
-                # where the proxy sends them).
-                out = _cancel_transit(out, cur_idx)
+            # (A stage of its own: NumPy sorts over millions of movers keep
+            # the interpreter lock some 20 ms at a time beside the loop.)
+            with stage("solve.transit"):
+                out = _route_unseatable(
+                    np.asarray(assignment)[:n], len(node_order), load, alive, cap
+                )
+                if (
+                    obj_w is None
+                    and mode != "hierarchical"
+                    and not (route_hier and self._move_cost <= 0.0)
+                ):
+                    # One price for every object and every destination: the
+                    # plan's per-node loads are what the solve decided, which
+                    # rows carry them is not. That holds for a flat mode
+                    # routed through the two-level solve too: its hashed
+                    # identities are a balancing proxy, nobody's preference
+                    # (a move_cost of 0 says moves are free there, and the
+                    # rows then go where the proxy sends them).
+                    out = _cancel_transit(out, cur_idx)
             # Communication-graph refinement (full solves only: the delta
             # path returned above, and its warm potentials price pure
             # balance). Runs on the already-routed assignment so the
@@ -3346,7 +3353,8 @@ class JaxObjectPlacement(ObjectPlacement):
             # Grouped emission: the migration engine batches one burst per
             # (source, target) pair, so hand it the plan already ordered by
             # that pair — contiguous runs become whole MigrateBatch frames.
-            planned.sort(key=lambda m: (m[1], m[2]))
+            with stage("solve.moves"):
+                planned.sort(key=lambda m: (m[1], m[2]))
             # Outside the lock on purpose: each handoff calls back into
             # update()/lookup(), which take it.
             await move_sink(planned)

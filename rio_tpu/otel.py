@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
-from .tracing import Span, gc_gauges, stage_gauges
+from .tracing import Span, gc_gauges, loop_gauges, stage_gauges
 
 
 def stats_gauges(**sources: Any) -> dict[str, float]:
@@ -82,14 +82,14 @@ def server_gauges(server: Any) -> dict[str, float]:
         placement_solve=getattr(placement, "stats", None),
         load=getattr(monitor, "stats", None),
     )
-    stall_gauges = getattr(monitor, "stall_gauges", None)
-    if stall_gauges is not None:
-        gauges.update(stall_gauges())
     # Coarse host stages of this PROCESS (rio.stage.<name>.count/total_ms/
     # max_ms): directory batch calls, solves, full collections.
     gauges.update(stage_gauges())
     # How the old heap settles between whole walks of it (rio.gc.*).
     gauges.update(gc_gauges())
+    # The loops' own clock (rio.loop.*): how late ready callbacks run, every
+    # stretch a loop could not turn, and the stage that held it.
+    gauges.update(loop_gauges())
     place_gauges = getattr(placement, "place_gauges", None)
     if place_gauges is not None:
         # The device-solved directory's host mirror (rio.place.*): rows and

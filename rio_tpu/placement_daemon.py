@@ -155,6 +155,7 @@ class PlacementDaemon:
         # ``daemon.wait`` stage's start).
         self._event_seen_at = float("-inf")
         self._event_seen_ns = 0
+        self._polled_ns = 0  # when the last poll's table arrived
         self._kick_event = asyncio.Event()
 
     # -- storage-outage bookkeeping (one journal event per edge) -------------
@@ -245,6 +246,8 @@ class PlacementDaemon:
 
     async def _liveness(self) -> tuple[frozenset[tuple[str, bool]], list]:
         members = await self.members_storage.members()
+        # (The table is here: what follows of a poll is the loop's own work.)
+        self._polled_ns = time.perf_counter_ns()
         return frozenset((m.address, bool(m.active)) for m in members), members
 
     def _sync_load(self, members: list) -> None:
@@ -363,6 +366,7 @@ class PlacementDaemon:
             poll_failed = False
             try:
                 liveness, members = await self._liveness()
+                t_poll = self._polled_ns
                 self.stats.polls += 1
                 self._note_storage_ok()
                 self._sync_load(members)
@@ -395,6 +399,13 @@ class PlacementDaemon:
                     if changed:  # a pure retry serves an already-counted event
                         self.stats.liveness_changes += 1
                         self._journal_liveness(prev_liveness, liveness)
+                        # The poll that saw the change, from the table's
+                        # arrival (not the wait for it) to the journal: a few
+                        # ms a daemon, and a process's daemons see one event
+                        # in one turn of the loop they share.
+                        tracing.stage_between(
+                            "daemon.liveness", t_poll, time.perf_counter_ns()
+                        )
                     solve_epoch = self._solve_epoch()
                     # Debounce a churn burst into one solve; the random
                     # jitter staggers the daemons of co-located servers

@@ -38,6 +38,7 @@ from rio_tpu.object_placement.jax_placement import JaxObjectPlacement
 
 REPO = Path(__file__).resolve().parents[1]
 CELL = "presence-1m-1k-churn.heartbeat-churn"
+MESH_CELL = "presence-4m-1k-mesh.resolve"
 
 
 @message
@@ -242,8 +243,27 @@ def _served(lines) -> dict:
     return {x["check"]: x for x in lines if "check" in x}
 
 
+_REHEARSED: dict = {}
+
+
+def _rehearsed(cell: str) -> list:
+    """The lines of the cell's one traced rehearsal (both cells bind the same
+    loopback addresses: one run each, one after the other, in this file)."""
+    if cell not in _REHEARSED:
+        if cell == CELL:
+            _REHEARSED[cell] = _cell(
+                ["--workload", CELL, "--seed", "2147483777", "--seconds", "7", "--trace", "1"])
+        else:
+            _REHEARSED[cell] = _cell(
+                ["--workload", cell, "--seed", "2147484001", "--seconds", "8", "--trace", "1"],
+                XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                RIO_TPU_FLAT_REBALANCE_MAX_ROWS="1024", RIO_TPU_HIER_CHUNK_ROWS="1024",
+            )
+    return _REHEARSED[cell]
+
+
 def test_the_churn_cell_rehearses_end_to_end():
-    lines = _cell(["--workload", CELL, "--seed", "2147483777", "--seconds", "7", "--trace", "1"])
+    lines = _rehearsed(CELL)
     last, checks = lines[-1], _served(lines)
     assert last["correct"] is True, [x for x in lines if x.get("ok") is False]
     assert last["attempted"] > 0 and last["failed"] == 0
@@ -264,12 +284,7 @@ def test_the_four_chip_cell_rehearses_on_the_mesh_route():
     cell's addresses, so its rehearsal lives in this file too. The
     environment makes 8,192 rows take the chip's route: 4 devices x 2 chunks;
     the directory is built with no ``mesh=``."""
-    lines = _cell(
-        ["--workload", "presence-4m-1k-mesh.resolve", "--seed", "2147484001", "--seconds", "8",
-         "--trace", "1"],
-        XLA_FLAGS="--xla_force_host_platform_device_count=4",
-        RIO_TPU_FLAT_REBALANCE_MAX_ROWS="1024", RIO_TPU_HIER_CHUNK_ROWS="1024",
-    )
+    lines = _rehearsed(MESH_CELL)
     last, checks = lines[-1], _served(lines)
     assert last["correct"] is True, [x for x in lines if x.get("ok") is False]
     assert last["attempted"] > 0 and last["failed"] == 0
@@ -285,6 +300,20 @@ def test_the_four_chip_cell_rehearses_on_the_mesh_route():
     m = {k: v["value"] for k, v in last["metrics"].items()}
     assert {"resolve_ms", "solve_features_ms.mesh", "solve_exec_ms.mesh",
             "solve_apply_ms.mesh"} <= set(m)
+
+
+@pytest.mark.parametrize("cell", [CELL, MESH_CELL])
+def test_the_hold_readers_read_the_loops_own_clock_on_a_rehearsal(cell):
+    """PR 37's metrics of the loop's holds and ticks are in the last line of
+    a traced rehearsal of a one-chip cell and of the four-chip cell."""
+    m = {k: v["value"] for k, v in _rehearsed(cell)[-1]["metrics"].items()}
+    assert {"loop_hold_ms_per_s", "loop_hold_max_ms", "loop_hold_unnamed_ms_per_s",
+            "tail_due_in_hold_share", "loop_turn_wait_ms"} <= set(m), sorted(m)
+    assert 0.0 <= m["loop_hold_unnamed_ms_per_s"] <= m["loop_hold_ms_per_s"] < 1000.0
+    assert m["loop_hold_max_ms"] >= 0.0 and 0.0 <= m["tail_due_in_hold_share"] <= 1.0
+    # An idle loop's tick runs 0-1 ms late (the selector's rounding); a
+    # rehearsal's loop is held now and then on top of that.
+    assert 0.0 < m["loop_turn_wait_ms"] < 100.0
 
 
 @pytest.mark.parametrize("objects, nodes, live, leave", [

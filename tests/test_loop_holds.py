@@ -1,0 +1,474 @@
+"""The loop's own clock (``rio_tpu.tracing.watch_loop``): a 5 ms tick on every
+event loop a ``LoadMonitor`` runs on, the holds it logs, the stage that names
+each, the roll-up rows and the ``rio.loop.*`` gauges.
+
+What must hold: one chain a loop however many monitors, stopped with the last
+of them; nothing per request or per key; both rings bounded; a hold is named
+ONCE, from what the stage log says at the first read after every stage that
+was open at its end has ended, and a stage that times a wait names none.
+"""
+
+import asyncio
+import functools
+import gc
+import inspect
+import threading
+import time
+import types
+
+import pytest
+
+from rio_tpu import LoadMonitor, tracing
+from rio_tpu.otel import server_gauges
+
+TICK_MS = tracing.LOOP_TICK_NS / 1e6
+
+
+@pytest.fixture(autouse=True)
+def fresh_log():
+    tracing.clear_stages()
+    yield
+    tracing.clear_stages()
+
+
+def ticking(test):
+    """Run an ``async def test(chain, ...)`` with the running loop's tick
+    started before it and stopped after it."""
+    @functools.wraps(test)
+    async def run(**kwargs):
+        tracing.watch_loop()
+        try:
+            await asyncio.sleep(0.03)
+            tracing.clear_stages()  # what the sandbox did to the first ticks
+            await test(tracing._LOOP_TICKS[asyncio.get_running_loop()], **kwargs)
+        finally:
+            tracing.unwatch_loop()
+
+    # (pytest reads the parameters the wrapper takes: not the chain.)
+    params = list(inspect.signature(test).parameters.values())[1:]
+    run.__signature__ = inspect.Signature(params)
+    del run.__wrapped__
+    return run
+
+
+async def _hold(seconds: float, name: str | None) -> None:
+    """Block the loop once, under a stage or bare, then let the tick run."""
+    if name is None:
+        time.sleep(seconds)
+    else:
+        with tracing.stage(name):
+            time.sleep(seconds)
+    await asyncio.sleep(3 * TICK_MS / 1e3)
+
+
+def _longest(holds):
+    return max(holds, key=lambda h: h[1] - h[0])
+
+
+@pytest.mark.parametrize("name, cause", [("x", "x"), (None, "unnamed")])
+@ticking
+async def test_a_sleep_on_the_loop_is_one_hold_named_by_its_stage(chain, name, cause):
+    t_lo = time.perf_counter_ns()
+    await _hold(0.05, name)
+    t_hi = time.perf_counter_ns()
+    holds = [h for h in tracing.hold_log() if (h[1] - h[0]) / 1e6 >= 40.0]
+    assert len(holds) == 1, tracing.hold_log()
+    t0, t1, named_by, named_ns = holds[0]
+    assert t_lo <= t0 < t1 <= t_hi
+    # The tick due inside the sleep ran right after it: 50 ms within one tick
+    # (and what the machine adds to any sleep).
+    assert 50.0 - TICK_MS - 0.5 <= (t1 - t0) / 1e6 <= 150.0
+    assert named_by == cause
+    if name is None:
+        assert named_ns == 0
+    else:
+        assert 0 <= (t1 - t0) - named_ns <= (TICK_MS + 3.0) * 1e6
+
+
+@ticking
+async def test_a_spinning_worker_thread_starves_the_loop_beside_its_stage(chain):
+    """Pure Python in another thread lets the loop in every switch interval
+    (5 ms): the waits show in ``late_ns``; a hold, where one is long enough,
+    is the worker's ``~y`` and never a stage of the loop's own."""
+    def spin() -> None:
+        with tracing.stage("y"):
+            t_end = time.perf_counter() + 0.25
+            while time.perf_counter() < t_end:
+                sum(i * i for i in range(2000))
+
+    await _hold(0.03, "x")  # a stage of the loop's own, over before the worker starts
+    t_spin, late0 = time.perf_counter_ns(), chain.late_ns
+    worker = threading.Thread(target=spin)
+    worker.start()
+    while worker.is_alive():
+        await asyncio.sleep(0.001)
+    worker.join(timeout=5.0)
+    await asyncio.sleep(0.02)
+    assert not worker.is_alive()
+    causes = [h[2] for h in tracing.hold_log()]
+    assert "x" in causes
+    # (``unnamed``: the sandbox's other tenants can stop the process.)
+    assert {h[2] for h in tracing.hold_log() if h[0] >= t_spin} <= {"~y", "unnamed"}
+    assert chain.late_ns > late0
+
+
+@ticking
+async def test_a_call_that_keeps_the_interpreter_lock_in_a_thread_reads_as_starvation(chain):
+    """One C call that never lets the lock go holds the loop from ANOTHER
+    thread: the hold is ``~y``, the tilde saying that the loop was starved
+    beside that code, not held by it."""
+    done = threading.Event()
+
+    def hog() -> None:
+        with tracing.stage("y"):
+            t_end = time.perf_counter() + 0.3
+            while time.perf_counter() < t_end:
+                sum(range(3_000_000))  # tens of ms without a switch
+        done.set()
+
+    worker = threading.Thread(target=hog)
+    worker.start()
+    while not done.is_set():
+        await asyncio.sleep(0.001)
+    worker.join(timeout=5.0)
+    await asyncio.sleep(0.02)
+    starved = [h for h in tracing.hold_log() if h[2] == "~y"]
+    assert starved, tracing.hold_log()
+    t0, t1, _cause, named_ns = _longest(starved)
+    assert named_ns >= (t1 - t0) // 2
+    assert "x" not in {h[2] for h in tracing.hold_log()}
+
+
+@ticking
+async def test_a_full_collection_on_the_loop_names_its_hold(chain):
+    tracing.watch_gc()
+    try:
+        # Enough young containers that walking them takes tens of ms.
+        ballast = [[i] for i in range(600_000)]
+        await asyncio.sleep(0.05)  # (making them held the loop too: let that hold end)
+        gc.collect()
+        await asyncio.sleep(0.03)
+    finally:
+        tracing.unwatch_gc()
+    del ballast
+    full = [r for r in tracing.stage_log() if r[0] == "gc.gen2"]
+    assert full and max(r[2] - r[1] for r in full) >= tracing.HOLD_MIN_NS
+    _name, g0, g1, *_ = max(full, key=lambda r: r[2] - r[1])
+    over = [h for h in tracing.hold_log() if h[1] > g0 and h[0] < g1]
+    assert over, (tracing.hold_log(), full)
+    t0, t1, cause, named_ns = max(over, key=lambda h: min(h[1], g1) - max(h[0], g0))
+    inside = min(t1, g1) - max(t0, g0)
+    assert named_ns >= inside  # the collection counts towards what names the hold
+    # (Under the suite's six workers the machine may stretch the hold far
+    # beyond the collection: it names the hold where it covers half of it.)
+    if 2 * inside >= t1 - t0:
+        assert cause == "gc.gen2"
+
+
+def _tick_handles(loop) -> list:
+    return [h for h in loop._scheduled if not h.cancelled()
+            and getattr(h._callback, "__func__", None) is tracing._LoopTick._tick]
+
+
+async def test_two_monitors_on_one_loop_run_one_chain_and_the_last_stops_it():
+    loop = asyncio.get_running_loop()
+    tasks0 = len(asyncio.all_tasks())
+    a = LoadMonitor(interval=0.01, stall_threshold_ms=0)
+    b = LoadMonitor(interval=0.01, stall_threshold_ms=0)
+    assert loop not in tracing._LOOP_TICKS  # constructing a monitor starts nothing
+    ta, tb = asyncio.ensure_future(a.run()), asyncio.ensure_future(b.run())
+    await asyncio.sleep(0.05)
+    chain = tracing._LOOP_TICKS[loop]
+    assert chain.watchers == 2 and len(_tick_handles(loop)) == 1
+    assert len(asyncio.all_tasks()) == tasks0 + 2  # the chain is no task
+    assert chain.thread_id == threading.get_ident()
+    ta.cancel()
+    await asyncio.gather(ta, return_exceptions=True)
+    ticks = chain.ticks
+    await asyncio.sleep(0.03)
+    assert tracing._LOOP_TICKS[loop] is chain and chain.ticks > ticks  # still ticking
+    tb.cancel()
+    await asyncio.gather(tb, return_exceptions=True)
+    assert loop not in tracing._LOOP_TICKS and chain.handle.cancelled()
+    ticks = chain.ticks
+    await asyncio.sleep(0.03)
+    assert chain.ticks == ticks and _tick_handles(loop) == []  # no callback after the last
+    tracing.unwatch_loop()  # one too many: counted, not raised
+    assert loop not in tracing._LOOP_TICKS
+
+
+@ticking
+async def test_a_second_loop_gets_a_chain_and_a_thread_id_of_its_own(chain):
+    seen = {}
+
+    async def other() -> None:
+        tracing.watch_loop()
+        try:
+            chain = tracing._LOOP_TICKS[asyncio.get_running_loop()]
+            seen["chain"], seen["thread"] = chain, threading.get_ident()
+            await asyncio.sleep(0.02)
+            time.sleep(0.03)  # a hold of this loop alone
+            await asyncio.sleep(0.02)
+        finally:
+            tracing.unwatch_loop()
+
+    worker = threading.Thread(target=asyncio.run, args=(other(),))
+    worker.start()
+    while worker.is_alive():
+        await asyncio.sleep(0.005)
+    worker.join(timeout=5.0)
+    assert seen["chain"] is not chain and seen["chain"].thread_id == seen["thread"]
+    assert seen["thread"] != chain.thread_id == threading.get_ident()
+    assert len(tracing._LOOP_TICKS) == 1  # the other loop's chain went with it
+    theirs = [h for h in tracing._HOLD_NEW if h[2] == seen["thread"]]  # (nobody read yet)
+    assert theirs and max(h[1] - h[0] for h in theirs) >= 20e6
+    # The stopped chain's counts stay in the process's gauges.
+    assert tracing.loop_gauges()["rio.loop.ticks"] >= seen["chain"].ticks + chain.ticks
+
+
+@ticking
+async def test_an_idle_loop_logs_no_hold_and_runs_its_ticks_under_a_millisecond_late(chain):
+    """The selector sleeps in whole milliseconds, rounded up, so an idle
+    loop's tick runs 0-1 ms late: never a hold. (The sandbox's other tenants
+    can stop any process for 10 ms: the best of ten tries decides.)"""
+    best = None
+    for _ in range(10):
+        ticks, late, holds = chain.ticks, chain.late_ns, chain.holds
+        await asyncio.sleep(0.2)
+        n = chain.ticks - ticks
+        best = (chain.holds - holds, (chain.late_ns - late) / max(1, n) / 1e6, n)
+        if best[0] == 0 and best[1] < 1.0:
+            break
+    assert best[0] == 0 and best[1] < 1.0, best
+    assert 25 <= best[2] <= 41  # 5 ms apart, no burst after a late one
+
+
+@ticking
+async def test_a_roll_up_row_lands_every_200_ticks_and_both_rings_are_bounded(chain):
+    assert tracing.tick_log() == []
+    chain.ticks = tracing.TICK_ROLLUP - 2
+    await asyncio.sleep(4 * TICK_MS / 1e3)
+    rows = tracing.tick_log()
+    assert len(rows) == 1
+    now_ns, ticks, late_ns, thread = rows[0]
+    assert ticks == tracing.TICK_ROLLUP and thread == threading.get_ident()
+    assert 0 <= late_ns <= chain.late_ns and now_ns <= time.perf_counter_ns()
+    assert tracing._TICK_LOG.maxlen == tracing.TICK_LOG_SIZE == 1024
+    assert tracing._HOLD_NEW.maxlen == tracing._HOLD_LOG.maxlen == tracing.HOLD_LOG_SIZE == 16384
+    assert (tracing.LOOP_TICK_NS, tracing.HOLD_MIN_NS) == (5_000_000, 10_000_000)
+    for i in range(tracing.HOLD_LOG_SIZE + 10):
+        tracing._HOLD_NEW.append((i, i + 1, 0))  # as the tick logs them: no name yet
+    for i in range(tracing.TICK_LOG_SIZE + 10):
+        tracing._TICK_LOG.append((i, i, 0, 0))
+    assert len(tracing._HOLD_NEW) == tracing.HOLD_LOG_SIZE
+    assert len(tracing.hold_log()) == tracing.HOLD_LOG_SIZE and not tracing._HOLD_NEW
+    assert len(tracing.tick_log()) == tracing.TICK_LOG_SIZE
+    assert tracing.hold_log()[0] == (10, 11, "unnamed", 0)  # the oldest went
+    for i in range(20):
+        tracing._HOLD_NEW.append((i, i + 1, 0))
+    assert len(tracing.hold_log()) == tracing.HOLD_LOG_SIZE  # the named ring too
+
+
+MS = 1_000_000
+LOOP, WORKER = 1, 2
+
+
+def test_the_join_on_a_log_made_by_hand():
+    ms = MS
+    tracing._STAGE_LOG.extend([
+        # A container and its leaves on the loop's thread; a wait; a collection in a thread.
+        ("solve.snapshot", 100 * ms, 160 * ms, "solve.full", 7, LOOP, False),
+        ("solve.apply", 170 * ms, 180 * ms, "solve.full", 7, LOOP, False),
+        ("solve.full", 100 * ms, 200 * ms, None, 7, LOOP, False),
+        ("place.resume", 300 * ms, 400 * ms, "place.assign", 8, LOOP, True),
+        ("gc.gen2", 500 * ms, 530 * ms, None, 0, WORKER, False),
+        ("place.lookup", 600 * ms, 700 * ms, None, 9, WORKER, False),
+        ("y", 810 * ms, 812 * ms, None, 10, WORKER, False),
+        ("place.solve.wait", 900 * ms, 960 * ms, None, 11, WORKER, True),
+    ])
+    tracing._HOLD_NEW.extend([
+        (105 * ms, 185 * ms, LOOP),  # snapshot 55 + apply 10 of 80: named, 15 bare
+        (191 * ms, 199 * ms, LOOP),  # inside the container only: a container names nothing
+        (300 * ms, 350 * ms, LOOP),  # inside a wait only
+        (495 * ms, 535 * ms, LOOP),  # a collection in another thread still HOLDS
+        (540 * ms, 560 * ms, LOOP),  # the very next tick late again, no stage: no name
+        (610 * ms, 650 * ms, LOOP),  # beside a worker's stage: starved
+        (800 * ms, 830 * ms, LOOP),  # a worker's stage covers 2 of 30: not its
+        (900 * ms, 950 * ms, LOOP),  # beside a worker that waits (for the device): not starved
+    ])
+    assert tracing.hold_log() == [
+        (105 * ms, 185 * ms, "solve.snapshot", 65 * ms),
+        (191 * ms, 199 * ms, "unnamed", 0),
+        (300 * ms, 350 * ms, "unnamed", 0),
+        (495 * ms, 535 * ms, "gc.gen2", 30 * ms),
+        (540 * ms, 560 * ms, "unnamed", 0),
+        (610 * ms, 650 * ms, "~place.lookup", 40 * ms),
+        (800 * ms, 830 * ms, "unnamed", 0),
+        (900 * ms, 950 * ms, "unnamed", 0),
+    ]
+    assert not tracing._HOLD_NEW and not tracing._HOLD_WAITING
+    by_cause = {k: v for k, v in tracing.loop_gauges().items() if ".by." in k}
+    assert by_cause == {
+        "rio.loop.hold.by.solve.snapshot.ms": 80.0,
+        "rio.loop.hold.by.unnamed.ms": 8.0 + 50.0 + 20.0 + 30.0 + 50.0,
+        "rio.loop.hold.by.gc.gen2.ms": 40.0,
+        "rio.loop.hold.by.~place.lookup.ms": 40.0,
+    }
+
+
+def test_a_hold_is_named_once_and_keeps_its_name():
+    """A scrape names a hold from what the stage log says then; a record that
+    arrives later (or one that leaves the ring) renames nothing, and no read
+    walks the named holds again."""
+    tracing._HOLD_NEW.append((100 * MS, 150 * MS, LOOP))
+    assert tracing.loop_gauges()["rio.loop.hold.by.unnamed.ms"] == 50.0
+    tracing._STAGE_LOG.append(("late", 100 * MS, 150 * MS, None, 3, LOOP, False))
+    tracing._HOLD_NEW.append((200 * MS, 230 * MS, LOOP))
+    tracing._STAGE_LOG.append(("x", 200 * MS, 230 * MS, None, 4, LOOP, False))
+    assert tracing.hold_log() == [
+        (100 * MS, 150 * MS, "unnamed", 0), (200 * MS, 230 * MS, "x", 30 * MS),
+    ]
+    tracing._STAGE_LOG.clear()  # the stage ring forgot: the holds keep their names
+    gauges = tracing.loop_gauges()
+    assert gauges["rio.loop.hold.by.unnamed.ms"] == 50.0 and gauges["rio.loop.hold.by.x.ms"] == 30.0
+    assert tracing.hold_log()[1][2] == "x"
+    # Nothing new: a read takes the lock, finds nothing, and names nothing.
+    named = []
+    real, tracing._name_hold = tracing._name_hold, lambda *a: named.append(a) or real(*a)
+    try:
+        tracing.loop_gauges(), tracing.hold_log()
+    finally:
+        tracing._name_hold = real
+    assert named == []
+
+
+def test_a_hold_waits_for_the_stage_that_was_open_at_its_end():
+    """A stage that began before the hold's end and has not ended could still
+    name it (or turn its parent into a container): the hold keeps no name,
+    and no share of the gauges, until that stage ends."""
+    with tracing.stage("outer"):
+        t0 = time.perf_counter_ns()
+        time.sleep(0.02)
+        t1 = time.perf_counter_ns()
+        tracing._HOLD_NEW.append((t0, t1, threading.get_ident()))
+        # Read while ``outer`` is open: it looks like the leaf that held the loop.
+        assert [h[2] for h in tracing.hold_log()] == ["outer"]
+        assert not [k for k in tracing.loop_gauges() if ".by." in k]
+        assert len(tracing._HOLD_WAITING) == 1 and not tracing._HOLD_LOG
+        with tracing.stage("inner"):
+            pass
+    # It ended as a container of ``inner``, which ran after the hold.
+    assert tracing.hold_log() == [(t0, t1, "unnamed", 0)]
+    assert tracing.loop_gauges()["rio.loop.hold.by.unnamed.ms"] == (t1 - t0) / 1e6
+    assert not tracing._HOLD_WAITING and not tracing._OPEN_STAGES
+
+
+def test_a_stage_that_times_a_wait_names_no_hold_and_holds_no_naming_back():
+    """The call site says what waits: ``stage(..., wait=True)``, every
+    ``stage_since``, ``stage_between(..., wait=True)``. No list of names."""
+    assert not hasattr(tracing, "WAIT_STAGES")
+    me = threading.get_ident()
+    with tracing.stage("lock_wait", wait=True) as waited:
+        t0 = time.perf_counter_ns()
+        time.sleep(0.02)
+        t1 = time.perf_counter_ns()
+        tracing._HOLD_NEW.append((t0, t1, me))
+        # (Open, and no reason to wait for it.)
+        assert tracing.loop_gauges()["rio.loop.hold.by.unnamed.ms"] == (t1 - t0) / 1e6
+    tracing.stage_since("resume", waited.t0)
+    tracing.stage_between("burst", waited.t0, waited.t1, wait=True)
+    tracing.stage_between("work", waited.t0, waited.t1)
+    assert [(r[0], r[6]) for r in tracing.stage_log()] == [
+        ("lock_wait", True), ("resume", True), ("burst", True), ("work", False),
+    ]
+    tracing._HOLD_NEW.append((t0, t1, me))
+    assert tracing.hold_log()[-1] == (t0, t1, "work", t1 - t0)
+
+
+def test_a_collection_inside_the_naming_may_finalize_a_pending_monitor():
+    """The naming allocates under the module's lock, so a collection can start
+    there; one that frees a monitor's task whose loop was closed under it runs
+    that task's ``finally``, which takes the same lock (``unwatch_gc``, and
+    ``unwatch_loop`` where a loop runs): on the same thread, so it must not
+    wait for itself."""
+    async def monitor():
+        tracing.watch_gc()
+        try:
+            await asyncio.sleep(100)
+        finally:
+            tracing.unwatch_gc()
+
+    def body():
+        loop = asyncio.new_event_loop()
+        task = loop.create_task(monitor())
+        loop.run_until_complete(asyncio.sleep(0.01))
+        task.cycle = task  # only the collector frees it
+        loop.close()
+        del task, loop
+        tracing._HOLD_NEW.append((10**9, 10**9 + 2 * tracing.HOLD_MIN_NS, 1))
+        real = tracing._name_hold
+
+        def collecting(*args):  # (the lock is held here)
+            gc.collect()
+            return real(*args)
+
+        tracing._name_hold = collecting
+        try:
+            named.extend(tracing.hold_log())
+        finally:
+            tracing._name_hold = real
+
+    named: list = []
+    watchers = tracing._GC_WATCHERS
+    gc.collect()
+    gc.disable()
+    try:
+        thread = threading.Thread(target=body, daemon=True)
+        thread.start()
+        thread.join(20)
+    finally:
+        gc.enable()
+    assert not thread.is_alive(), "the naming waits for a lock its own thread holds"
+    assert [h[2] for h in named] == ["unnamed"] and tracing._GC_WATCHERS == watchers
+
+
+@ticking
+async def test_the_loop_gauges_are_cumulative_and_name_the_causes(chain):
+    before = tracing.loop_gauges()
+    assert set(before) >= {
+        "rio.loop.ticks", "rio.loop.late_ms", "rio.loop.hold.count",
+        "rio.loop.hold.total_ms", "rio.loop.hold.max_ms",
+    }
+    await _hold(0.03, "place.apply")
+    after = tracing.loop_gauges()
+    assert after["rio.loop.ticks"] > before["rio.loop.ticks"]
+    assert after["rio.loop.late_ms"] >= before["rio.loop.late_ms"] + 20.0
+    assert after["rio.loop.hold.count"] >= before["rio.loop.hold.count"] + 1
+    assert after["rio.loop.hold.total_ms"] >= before["rio.loop.hold.total_ms"] + 20.0
+    assert after["rio.loop.hold.max_ms"] >= 20.0
+    assert after["rio.loop.hold.by.place.apply.ms"] >= 20.0
+    # The rings forget; the counts do not.
+    tracing.clear_stages()
+    cleared = tracing.loop_gauges()
+    assert not [k for k in cleared if k.startswith("rio.loop.hold.by.")]
+    assert cleared["rio.loop.hold.count"] == after["rio.loop.hold.count"]
+    assert all(isinstance(v, float) for v in cleared.values())
+
+
+async def test_server_gauges_carry_the_loop_and_no_stall_gauges():
+    m = LoadMonitor(interval=0.01, stall_threshold_ms=0)
+    task = asyncio.ensure_future(m.run())
+    try:
+        await asyncio.sleep(0.03)
+        await _hold(0.03, "place.apply")
+    finally:
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+    gauges = server_gauges(types.SimpleNamespace(load_monitor=m))
+    for key in ("ticks", "late_ms", "hold.count", "hold.total_ms", "hold.max_ms",
+                "hold.by.place.apply.ms"):
+        assert f"rio.loop.{key}" in gauges, key
+    assert gauges["rio.loop.hold.count"] >= 1.0 and gauges["rio.loop.hold.max_ms"] >= 20.0
+    assert not [k for k in gauges if k.startswith("rio.load.stall_")]
+    assert "rio.load.stalls" in gauges  # the watchdog's captures stay
+    assert not hasattr(m, "stall_gauges")

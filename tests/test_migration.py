@@ -990,6 +990,52 @@ def test_a_plan_is_one_stage_with_its_slowest_burst_and_counters():
     ))
 
 
+def test_sorting_a_plan_is_a_stage_that_leaves_the_membership_read_out():
+    """``migrate.plan`` times the loop's own pass over the moves; the one
+    membership read in its middle is a wait (a remote store: tens of ms in
+    which other code runs) and lies between its two records, in neither."""
+    from rio_tpu import tracing
+    from rio_tpu.object_placement import ObjectPlacementItem
+
+    async def body(cluster: Cluster):
+        a, b = cluster.servers
+        for i in range(6):
+            await cluster.placement.update(
+                ObjectPlacementItem(ObjectId("Plain", f"p{i}"), a.local_address)
+            )
+        mgr = b.migration_manager
+        real = mgr.members_storage.active_members
+        read = []
+
+        async def slow_read():
+            t0 = time.perf_counter_ns()
+            await asyncio.sleep(0.05)
+            read.append((t0, time.perf_counter_ns()))
+            return await real()
+
+        mgr.members_storage.active_members = slow_read
+        tracing.clear_stages()
+        try:
+            moves = [(f"Plain.p{i}", a.local_address, b.local_address) for i in range(6)]
+            assert await mgr.apply_moves(moves) == 6
+        finally:
+            mgr.members_storage.active_members = real
+        log = tracing.stage_log()
+        plans = [r for r in log if r[0] == "migrate.plan"]
+        assert len(read) == 1 and len(plans) == 2  # before the read, after it
+        assert plans[0][2] <= read[0][0] and read[0][1] <= plans[1][1]
+        assert all(r[3] == "migrate.apply_moves" and not r[6] for r in plans)
+        assert all(r[2] - r[1] < 40e6 for r in plans)
+        # The slowest burst's span is a wait and says so; the plan's root is work.
+        (burst,) = [r for r in log if r[0] == "migrate.burst"]
+        (root,) = [r for r in log if r[0] == "migrate.apply_moves"]
+        assert burst[6] is True and root[6] is False
+
+    asyncio.run(run_integration_test(
+        body, registry_builder=lambda: Registry().add_type(Plain), num_servers=2,
+    ))
+
+
 def test_nothing_is_prefetched_for_a_type_without_volatile_state():
     async def body(cluster: Cluster):
         from rio_tpu.object_placement import ObjectPlacementItem

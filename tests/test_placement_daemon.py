@@ -9,6 +9,7 @@ already re-placed.  The application never touches the solver.
 """
 
 import asyncio
+import time
 
 from rio_tpu import AppData, LocalObjectPlacement, LocalStorage, Registry, ServiceObject, handler, message
 from rio_tpu.commands import AdminCommand, ServerInfo
@@ -550,6 +551,57 @@ def test_eight_daemons_on_one_provider_answer_one_event_with_one_solve():
             [ObjectId("T", str(i)) for i in range(120)]
         )
         assert "10.5.0.6:90" not in seats
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+
+
+def test_the_poll_that_sees_a_change_is_a_stage_from_the_tables_arrival():
+    """``daemon.liveness`` is the loop's own work of the poll that saw the
+    event (fingerprint, ``sync_load``, ``sync_members``, journal): stamped
+    when the table has arrived, so the wait for a slow store is not in it;
+    ``daemon.wait`` is a wait and says so."""
+    from rio_tpu import ObjectId, tracing
+    from rio_tpu.cluster.storage import Member
+
+    async def run():
+        storage = LocalStorage()
+        for i in range(1, 5):
+            await storage.push(Member.from_address(f"10.6.0.{i}:90", active=True))
+        placement = JaxObjectPlacement(mode="greedy")
+        placement.sync_members(await storage.members())
+        await placement.assign_batch([ObjectId("T", str(i)) for i in range(40)])
+        await placement.rebalance(delta=False)
+        real = storage.members
+        reads = []
+
+        async def slow_members():
+            t0 = time.perf_counter_ns()
+            await asyncio.sleep(0.03)
+            reads.append((t0, time.perf_counter_ns()))
+            return await real()
+
+        storage.members = slow_members
+        daemon = PlacementDaemon(storage, placement, PlacementDaemonConfig(
+            poll_interval=0.05, debounce=0.01, min_rebalance_interval=0.05))
+        tracing.clear_stages()
+        task = asyncio.create_task(daemon.run())
+        try:
+            await asyncio.sleep(0.3)  # first sync (no solve, no record)
+            await storage.set_inactive("10.6.0.4", 90)
+            for _ in range(200):
+                if daemon.stats.rebalances:
+                    break
+                await asyncio.sleep(0.05)
+        finally:
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+        assert daemon.stats.liveness_changes == 1 and daemon.stats.rebalances == 1
+        (seen,) = [r for r in tracing.stage_log() if r[0] == "daemon.liveness"]
+        assert seen[6] is False and 0 < seen[2] - seen[1] < 25e6  # not the 30 ms read
+        assert any(t1 <= seen[1] and seen[1] - t1 < 5e6 for _, t1 in reads)
+        assert not any(seen[1] < t1 and t0 < seen[2] for t0, t1 in reads)
+        (waited,) = [r for r in tracing.stage_log() if r[0] == "daemon.wait"]
+        assert waited[6] is True
 
     asyncio.run(asyncio.wait_for(run(), 60))
 

@@ -3,7 +3,8 @@ the load monitors' loop counters, and the saves' RED row.
 
 What must hold: stages are coarse (per batch call, per solve, per full
 collection — nothing per request or per key), the log is bounded and always
-on, and none of it starts a task, a thread or a timer.
+on, and none of it starts a task or a thread (the one timer a loop is the
+loop's own clock: ``tests/test_loop_holds.py``).
 """
 
 import asyncio
@@ -484,7 +485,7 @@ async def test_the_monitor_keeps_raw_lag_samples_and_times_stalls():
     try:
         t_lo = time.perf_counter_ns()
         await asyncio.sleep(0.1)
-        assert set(m.stall_gauges().values()) == {0.0}
+        held0 = tracing.loop_gauges()
         time.sleep(0.3)  # hold the loop: one tick wakes ~0.28 s late
         await asyncio.sleep(0.1)
         t_hi = time.perf_counter_ns()
@@ -497,12 +498,17 @@ async def test_the_monitor_keeps_raw_lag_samples_and_times_stalls():
     assert [t for t, _ in samples] == sorted(t for t, _ in samples)
     worst = max(lag for _, lag in samples)
     assert 200.0 <= worst <= 400.0
-    # The stall gauges are made from those samples when scraped.
-    assert m.stall_gauges() == {
-        "rio.load.stall_max_ms": worst,
-        "rio.load.stall_total_ms": sum(lag for _, lag in samples if lag >= 100.0),
-    }
-    assert not hasattr(m.stats, "stall_max_ms")
+    # The hold itself is the loop's own clock's to time (``rio.loop.hold.*``,
+    # a 5 ms tick): the monitor's tick saw what was left of it when it was due.
+    held = tracing.loop_gauges()
+    assert held["rio.loop.hold.count"] >= held0["rio.loop.hold.count"] + 1
+    assert held["rio.loop.hold.max_ms"] >= 290.0  # (cumulative: the process's longest ever)
+    # (The loop's tick was due up to one tick after the monitor's.)
+    tick_ms = tracing.LOOP_TICK_NS / 1e6
+    assert held["rio.loop.hold.total_ms"] - held0["rio.loop.hold.total_ms"] >= worst - tick_ms
+    longest = max(tracing.hold_log(), key=lambda h: h[1] - h[0])
+    assert t_lo <= longest[0] < longest[1] <= t_hi and longest[2] == "unnamed"
+    assert not hasattr(m.stats, "stall_max_ms") and not hasattr(m, "stall_gauges")
     assert m.stats.lag_samples.maxlen == 1024
 
 
@@ -523,8 +529,12 @@ async def test_server_gauges_carry_the_stages_and_the_loop_counters():
         assert f"rio.stage.gc.gen2.{field}" in gauges
     assert gauges["rio.stage.place.apply.count"] == 1.0
     assert gauges["rio.stage.gc.gen2.count"] >= 1.0
-    for field in ("stalls", "stall_max_ms", "stall_total_ms", "loop_lag_ms", "samples"):
+    for field in ("stalls", "loop_lag_ms", "samples"):
         assert f"rio.load.{field}" in gauges
+    for field in ("ticks", "late_ms", "hold.count", "hold.total_ms", "hold.max_ms"):
+        assert f"rio.loop.{field}" in gauges
+    assert gauges["rio.loop.ticks"] >= 5.0  # 50 ms of a 5 ms tick
+    assert "rio.load.stall_max_ms" not in gauges and "rio.load.stall_total_ms" not in gauges
     for field in ("settled", "whole_walks", "settled_objects"):
         assert f"rio.gc.{field}" in gauges
     assert gauges["rio.gc.settled"] + gauges["rio.gc.whole_walks"] >= 1.0
