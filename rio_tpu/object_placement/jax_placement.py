@@ -17,6 +17,10 @@ SQL round trip (``rio-rs/src/service.rs:220``, named the bottleneck in
   re-solve snapshots the epoch and its result is discarded if the directory
   moved underneath it (single-writer semantics replacing the reference's
   reliance on SQL upsert atomicity, ``object_placement/sqlite.rs:72-85``).
+  The snapshot itself is a cut at that one epoch: a large directory is read
+  in slices with a turn of the event loop between two, and a writer that
+  lands between them (the epoch or the row count moved) restarts the read
+  at the new epoch (:meth:`JaxObjectPlacement._snapshot`).
 
 Liveness flows in from gossip (``MembershipStorage``) via
 :meth:`JaxObjectPlacement.sync_members`, mirroring how the reference's
@@ -374,6 +378,21 @@ import functools as _functools
 _OBJ_FEAT_STREAM_ROWS = int(
     os.environ.get("RIO_TPU_OBJ_FEAT_STREAM_ROWS") or 262_144
 )
+
+
+# Rows of the directory a full solve's snapshot reads in one hold of the
+# servers' loop (``_snapshot``): ~0.8 ms on the chip host at ~0.05 s a
+# million rows. A constant, not an option: a directory of at most two slices
+# is read in one hold, a larger one slice by slice with a turn of the loop
+# between two. Sized by what a request feels, not by the slice alone: a
+# request crosses the loop in ~10 hops and each hop waits for a slice and the
+# turn beside it, and asyncio appends a due timer BEHIND the turn's ready
+# callbacks, so a tick can run two such cycles late. At 65,536 rows (3.3 ms
+# slices, ~7 ms cycles) a request due during the snapshot waited 38-78 ms in
+# the median and the loop's clock logged 19-42 holds of up to 45 ms a window
+# inside it; at 16,384 the median is 17 ms and the wall is the same; at 8,192
+# the turns' own cost stretches the wall by half (PERF.md, Findings PR 38).
+_SNAPSHOT_SLICE_ROWS = 16_384
 
 
 def _hier_feature_dtype() -> np.dtype:
@@ -1051,6 +1070,15 @@ class JaxObjectPlacement(ObjectPlacement):
         self._kept_identities: tuple[list[str], np.ndarray] | None = None
         self._feat_rows_reused = 0
         self._feat_rows_hashed = 0
+        # How the O(N) snapshots of the solves were read (``_snapshot``,
+        # ``place_gauges``): calls read in slices, the slices of all reads,
+        # reads begun again because a writer landed between two slices,
+        # calls that took one hold after two such, and the slices' own time.
+        self._snapshot_sliced = 0
+        self._snapshot_slices = 0
+        self._snapshot_restarts = 0
+        self._snapshot_whole = 0
+        self._snapshot_busy_ns = 0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1242,6 +1270,11 @@ class JaxObjectPlacement(ObjectPlacement):
             "rio.solve.mesh.cells": float(self._mesh_cells),
             "rio.solve.features.rows_reused": float(self._feat_rows_reused),
             "rio.solve.features.rows_hashed": float(self._feat_rows_hashed),
+            "rio.place.snapshot.sliced": float(self._snapshot_sliced),
+            "rio.place.snapshot.slices": float(self._snapshot_slices),
+            "rio.place.snapshot.restarts": float(self._snapshot_restarts),
+            "rio.place.snapshot.whole": float(self._snapshot_whole),
+            "rio.place.snapshot.busy_ms": self._snapshot_busy_ns / 1e6,
             "rio.place.index_tracked_rows": float(
                 sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
             ),
@@ -2790,6 +2823,108 @@ class JaxObjectPlacement(ObjectPlacement):
         self._affinity_history = history  # atomic swap (tests/telemetry)
         return seats if accepted_any else None
 
+    def _snapshot_head(self, delta: bool | None) -> tuple:
+        """What every route of a solve reads first, in O(nodes) (lock
+        held): the row count, the epoch, the node vectors, the plan and the
+        O(displaced) fast path's verdict."""
+        n = len(self._placements)
+        snapshot_epoch = self._epoch
+        self._recount_loads()
+        load, cap, alive = self._node_vectors()
+        node_order = list(self._node_order)  # snapshot for off-lock use
+        no_capacity = self._no_schedulable_capacity_host()
+        plan = self._plan  # immutable snapshot (atomic-swap field)
+        # O(displaced) fast path FIRST: for pure node-departure churn the
+        # displaced keys come straight from _by_node and the O(N) key/seat
+        # snapshot — the dominant per-event host cost at directory scale —
+        # is skipped entirely.
+        fast = None
+        if delta is not False and n and not no_capacity:
+            fast = self._delta_fast_snapshot(
+                plan, n, cap, alive, force=(delta is True)
+            )
+        return (
+            n, snapshot_epoch, load, cap, alive, node_order, no_capacity,
+            plan, fast,
+        )
+
+    def _read_rows(
+        self, rows: tuple, keys: list[str], cur_idx: np.ndarray, a: int, b: int
+    ) -> None:
+        """Rows ``a..b`` of the directory, its own key objects and their
+        seats, from the two running iterators ``rows`` (lock held; the one
+        place that knows how the mirror keeps keys and seats).
+
+        ``values()`` iterates in ``keys()`` order (insertion order) and
+        skips the per-key hash lookup a genexpr would pay: ~0.045 s a
+        million rows on the chip host (~0.35 s as a genexpr), nothing per
+        key in Python. An iterator of a dict that changed size raises
+        ``RuntimeError``.
+        """
+        key_iter, seat_iter = rows
+        keys.extend(itertools.islice(key_iter, b - a))
+        # (``count`` stops the read: the iterator is left at row ``b``.)
+        cur_idx[a:b] = np.fromiter(seat_iter, np.int32, count=b - a)
+
+    async def _snapshot(self, delta: bool | None) -> tuple:
+        """A solve's snapshot of the directory, a consistent cut at ONE
+        epoch: ``_snapshot_head``'s fields, then ``keys`` and ``cur_idx``
+        (both None for an empty directory and for the fast path).
+
+        The O(N) read of a full solve (or of the O(N) delta) was one hold
+        of the servers' loop under the directory's lock, ~190 ms at
+        4,194,304 rows, and every heartbeat due inside it waited for it. A
+        directory of more than two slices is therefore read
+        ``_SNAPSHOT_SLICE_ROWS`` rows a hold, ONE pass of one running
+        iterator each over the keys and the seats; between two slices the
+        lock is released and the loop turns once (due requests, the
+        monitors' ticks, a waiting writer). Every writer of ``_placements``
+        moves ``_epoch`` under the lock, so a slice is read only while the
+        epoch and the row count are the first slice's: then nobody wrote
+        since, and the slices are the cut one hold would have read.
+        Otherwise the read RESTARTS at the new epoch, from the head (a
+        writer may have opened the fast path). After two restarts the third
+        read takes the whole directory in one hold: a directory under
+        steady writes still gets its plan, at what it paid before.
+        """
+        step = _SNAPSHOT_SLICE_ROWS
+        restarts = 0
+        while True:
+            async with self._lock:
+                t0 = time.perf_counter_ns()
+                head = self._snapshot_head(delta)
+                n, snapshot_epoch, *_, fast = head
+                if fast is not None or not n:
+                    return (*head, None, None)
+                rows = iter(self._placements), iter(self._placements.values())
+                keys: list[str] = []
+                cur_idx = np.empty((n,), np.int32)
+                if n <= 2 * step or restarts == 2:
+                    self._read_rows(rows, keys, cur_idx, 0, n)
+                    if restarts == 2:
+                        self._snapshot_whole += 1
+                    return (*head, keys, cur_idx)
+                self._read_rows(rows, keys, cur_idx, 0, step)
+                self._snapshot_slices += 1
+                self._snapshot_busy_ns += time.perf_counter_ns() - t0
+            for a in range(step, n, step):
+                await asyncio.sleep(0)  # one turn of the loop, lock released
+                async with self._lock:
+                    if self._epoch != snapshot_epoch or len(self._placements) != n:
+                        break
+                    t0 = time.perf_counter_ns()
+                    try:
+                        self._read_rows(rows, keys, cur_idx, a, min(n, a + step))
+                    except RuntimeError:  # the dict changed size under its iterator
+                        break
+                    self._snapshot_slices += 1
+                    self._snapshot_busy_ns += time.perf_counter_ns() - t0
+            else:
+                self._snapshot_sliced += 1
+                return (*head, keys, cur_idx)
+            restarts += 1
+            self._snapshot_restarts += 1
+
     async def rebalance(
         self,
         *,
@@ -2813,7 +2948,15 @@ class JaxObjectPlacement(ObjectPlacement):
         Snapshots the epoch before the (async-yielding) device solve and
         discards the result if the directory changed underneath — the
         single-writer/versioned-epoch consistency design from ``SURVEY.md``
-        §7 "hard parts".
+        §7 "hard parts". The snapshot (:meth:`_snapshot`) is a cut of keys
+        and seats at that one epoch. A directory of more than two slices of
+        ``_SNAPSHOT_SLICE_ROWS`` rows is read a slice a lock hold, and
+        between two slices the event loop turns once, so requests, ticks
+        and writers wait for a slice and not for the directory. A RESTART
+        is what a writer between two slices costs: the epoch or the row
+        count moved, what was read is dropped, and the read begins again
+        at the new epoch; the third read of a call takes one hold.
+        ``place_gauges()`` counts them (``rio.place.snapshot.*``).
 
         ``move_sink`` (``async (list[(key, from_addr, to_addr)]) -> int``)
         turns the apply phase from raw directory writes into *planned*
@@ -2840,33 +2983,14 @@ class JaxObjectPlacement(ObjectPlacement):
         # default (it would otherwise fall through every dispatch check
         # and silently run the greedy branch).
         mode = self._solver_mode() if mode in (None, "auto") else mode
+        # ONE record a call, whatever the number of slices: its wall spans
+        # the requests served between them (``rio.place.snapshot.busy_ms``
+        # is the work).
         with stage("solve.snapshot"):
-            async with self._lock:
-                n = len(self._placements)
-                snapshot_epoch = self._epoch
-                self._recount_loads()
-                load, cap, alive = self._node_vectors()
-                node_order = list(self._node_order)  # snapshot for off-lock use
-                no_capacity = self._no_schedulable_capacity_host()
-                plan = self._plan  # immutable snapshot (atomic-swap field)
-                # O(displaced) fast path FIRST: for pure node-departure churn
-                # the displaced keys come straight from _by_node and the O(N)
-                # key/seat snapshot below — the dominant per-event host cost
-                # at directory scale — is skipped entirely.
-                fast = None
-                if delta is not False and n and not no_capacity:
-                    fast = self._delta_fast_snapshot(
-                        plan, n, cap, alive, force=(delta is True)
-                    )
-                if fast is None and n:
-                    keys = list(self._placements.keys())
-                    # values() iterates in keys() order (insertion order) and
-                    # skips the per-key hash lookup a genexpr would pay: the
-                    # snapshot reads ~0.045 s a million rows on the chip host
-                    # (150-200 ms at 4,194,304; ~0.35 s/1M as a genexpr).
-                    cur_idx = np.fromiter(
-                        self._placements.values(), np.int32, count=n
-                    )
+            (
+                n, snapshot_epoch, load, cap, alive, node_order, no_capacity,
+                plan, fast, keys, cur_idx,
+            ) = await self._snapshot(delta)
         if not n:
             self._kept_identities = None  # an empty directory keeps no rows
             return 0
