@@ -394,6 +394,16 @@ _OBJ_FEAT_STREAM_ROWS = int(
 # the turns' own cost stretches the wall by half (PERF.md, Findings PR 38).
 _SNAPSHOT_SLICE_ROWS = 16_384
 
+# Rows a call of the worker thread's commit diff (``_commit_diff``) passes
+# over: the comparison of the solve's output with the snapshot's seats, and
+# ``np.bincount`` over the movers, which keeps the interpreter lock for its
+# whole call. A constant, not an option: a solve of at most two slices is
+# diffed in one call, a larger one slice by slice, and between two calls the
+# interpreter can hand the lock to the loop's thread. A slice of the
+# comparison reads 0.09 ms on the chip host and one of ``bincount`` 0.36 ms,
+# under the ~2 ms a slice may keep the lock (PERF.md, Findings PR 41).
+_DIFF_SLICE_ROWS = 262_144
+
 
 def _hier_feature_dtype() -> np.dtype:
     """Host dtype for the streamed feature block.
@@ -524,6 +534,39 @@ def _cancel_transit(assignment: np.ndarray, cur_idx: np.ndarray) -> np.ndarray:
         np.arange(m, dtype=out.dtype), gain - transit
     )
     return out
+
+
+def _commit_diff(
+    assignment: np.ndarray, cur_idx: np.ndarray, seats: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """What a full solve's commit needs of its O(N) output, computed in the
+    worker thread from snapshots only: the movers' positions (what
+    ``np.nonzero(assignment != cur_idx)[0]`` gives), the plan's seats a node
+    (what ``np.bincount(assignment, minlength=len(seats))`` gives) and the
+    calls it took over the rows.
+
+    ``seats`` are the snapshot's seats a node (``bincount(cur_idx)``, which
+    the directory's per-node index has in O(nodes)): the plan's are those
+    less what the movers leave plus what they take, so the one pass over
+    the directory is the comparison, which lets go of the interpreter lock
+    while it runs; ``np.bincount`` keeps it, and passes over movers only.
+    More than two slices of ``_DIFF_SLICE_ROWS`` rows are passed slice by
+    slice: no call runs for longer than a slice."""
+    step = _DIFF_SLICE_ROWS
+    n = max(len(assignment), 1)  # (no row: one empty call, as for one slice)
+    row_step = step if n > 2 * step else n
+    found = [
+        np.flatnonzero(assignment[a : a + row_step] != cur_idx[a : a + row_step]) + a
+        for a in range(0, n, row_step)
+    ]
+    movers = np.concatenate(found)
+    seat_counts = seats.copy()
+    for a in range(0, len(movers), step):
+        rows = movers[a : a + step]
+        gain = np.bincount(assignment[rows], minlength=len(seat_counts))
+        gain[: len(seat_counts)] += seat_counts
+        seat_counts = gain - np.bincount(cur_idx[rows], minlength=len(gain))
+    return movers, seat_counts, len(found)
 
 
 def _guard_sentinel_spill(repaired, real, m_axis: int, cap_alive):
@@ -1079,6 +1122,14 @@ class JaxObjectPlacement(ObjectPlacement):
         self._snapshot_restarts = 0
         self._snapshot_whole = 0
         self._snapshot_busy_ns = 0
+        # The commits that took their diff from the worker thread
+        # (``_commit_diff``; added in the commit's lock hold, so a discarded
+        # attempt counts nowhere): commits, rows compared off the loop, the
+        # calls that passed over them, the worker's ``solve.diff`` time.
+        self._diff_commits = 0
+        self._diff_rows = 0
+        self._diff_slices = 0
+        self._diff_busy_ms = 0.0
         self._nodes: dict[str, _NodeSlot] = {}
         self._node_order: list[str] = []  # index -> address (never shrinks)
         self._node_axis = node_axis_size  # static node axis (padded)
@@ -1275,6 +1326,10 @@ class JaxObjectPlacement(ObjectPlacement):
             "rio.place.snapshot.restarts": float(self._snapshot_restarts),
             "rio.place.snapshot.whole": float(self._snapshot_whole),
             "rio.place.snapshot.busy_ms": self._snapshot_busy_ns / 1e6,
+            "rio.solve.diff.commits": float(self._diff_commits),
+            "rio.solve.diff.rows": float(self._diff_rows),
+            "rio.solve.diff.slices": float(self._diff_slices),
+            "rio.solve.diff.busy_ms": self._diff_busy_ms,
             "rio.place.index_tracked_rows": float(
                 sum(len(c) for c in self._by_node.values() if gc.is_tracked(c))
             ),
@@ -2825,8 +2880,8 @@ class JaxObjectPlacement(ObjectPlacement):
 
     def _snapshot_head(self, delta: bool | None) -> tuple:
         """What every route of a solve reads first, in O(nodes) (lock
-        held): the row count, the epoch, the node vectors, the plan and the
-        O(displaced) fast path's verdict."""
+        held): the row count, the epoch, the node vectors, the plan, the
+        seats a node and the O(displaced) fast path's verdict."""
         n = len(self._placements)
         snapshot_epoch = self._epoch
         self._recount_loads()
@@ -2843,9 +2898,16 @@ class JaxObjectPlacement(ObjectPlacement):
             fast = self._delta_fast_snapshot(
                 plan, n, cap, alive, force=(delta is True)
             )
+        # The seats a node, exact (``_recount_loads`` just read them off
+        # the per-node index): what an O(N) solve's commit diff starts from.
+        seats = None
+        if fast is None and n:
+            seats = np.zeros((self._node_axis,), np.intp)
+            for s in self._nodes.values():
+                seats[s.index] = int(s.load)
         return (
             n, snapshot_epoch, load, cap, alive, node_order, no_capacity,
-            plan, fast,
+            plan, seats, fast,
         )
 
     def _read_rows(
@@ -2969,10 +3031,15 @@ class JaxObjectPlacement(ObjectPlacement):
         One call is one ``solve.full`` stage (whichever path it takes) with
         children ``solve.snapshot``, ``solve.device`` (and in it
         ``solve.features`` and ``solve.transit``: the plan's rows routed and
-        committed with the fewest moves), ``solve.apply`` and, where a sink
-        takes a plan,
-        ``solve.moves`` (the move list ordered for it); ``SolveStats.solve_ms``
-        and ``apply_ms`` are the device and apply stages' own stamps.
+        committed with the fewest moves), ``solve.diff`` (the worker thread
+        still: which rows move, the plan's seats a node and, where a sink
+        takes a plan, the move list ordered for it, ``_commit_diff``) and
+        ``solve.apply`` (the commit: the epoch check, then one lock hold of
+        O(movers) and O(nodes); the O(displaced) route's own commit orders
+        its moves under ``solve.moves``); ``SolveStats.solve_ms`` and
+        ``apply_ms`` are the device and apply stages' own stamps.
+        ``place_gauges()`` counts the diffs that were committed
+        (``rio.solve.diff.*``).
         """
         with stage("solve.full"):
             return await self._rebalance(mode, move_sink, delta)
@@ -2989,7 +3056,7 @@ class JaxObjectPlacement(ObjectPlacement):
         with stage("solve.snapshot"):
             (
                 n, snapshot_epoch, load, cap, alive, node_order, no_capacity,
-                plan, fast, keys, cur_idx,
+                plan, seats, fast, keys, cur_idx,
             ) = await self._snapshot(delta)
         if not n:
             self._kept_identities = None  # an empty directory keeps no rows
@@ -3361,10 +3428,33 @@ class JaxObjectPlacement(ObjectPlacement):
                 out, g, coarse_g, solved_as, displaced, stale, conv = _solve()
             # No solve ran without capacity: its record carries no split.
             conv = {} if conv is None else _conv_timing(conv, st.ms, c0)
-            return out, g, coarse_g, st.ms, solved_as, displaced, stale, conv
+            # What the commit needs of the O(N) output, taken here: it reads
+            # the solve's output and the snapshot only, and the commit's
+            # epoch check says whether that snapshot is still the directory.
+            # (The node axis grows only with the epoch: ``_node_index``.)
+            with stage("solve.diff") as st_diff:
+                mover_pos, seat_counts, slices = _commit_diff(out, cur_idx, seats)
+                planned = None
+                if move_sink is not None:
+                    # Grouped emission: the migration engine batches one
+                    # burst per (source, target) pair, so hand it the plan
+                    # already ordered by that pair — contiguous runs become
+                    # whole MigrateBatch frames.
+                    planned = [
+                        (keys[p], node_order[s], node_order[t])
+                        for p, s, t in zip(
+                            mover_pos.tolist(),
+                            cur_idx[mover_pos].tolist(),
+                            out[mover_pos].tolist(),
+                        )
+                    ]
+                    planned.sort(key=lambda m: (m[1], m[2]))
+            diff = (mover_pos, seat_counts, planned, slices, st_diff.ms)
+            return out, g, coarse_g, st.ms, solved_as, displaced, stale, conv, diff
 
         (
-            assignment, g, coarse_g, solve_ms, solved_as, displaced, stale, conv
+            assignment, g, coarse_g, solve_ms, solved_as, displaced, stale, conv,
+            (mover_pos, seat_counts, planned, diff_slices, diff_ms),
         ) = await asyncio.to_thread(_solve_staged)
 
         async with self._lock:
@@ -3388,31 +3478,25 @@ class JaxObjectPlacement(ObjectPlacement):
                 return 0
             # Touch only the movers: non-movers are _set_placement no-ops
             # by definition (epoch unchanged => directory equals the
-            # cur_idx snapshot), and the vectorized compare turns the
-            # apply from an O(N) Python loop under the lock (~0.3 s/1M,
-            # the dominant host cost of a churn rebalance) into
-            # O(movers) — typically the displaced few percent.
+            # cur_idx snapshot). Which rows move and the plan's seat counts
+            # came with the solve (``solve.diff``, the worker thread): what
+            # is left under the lock is O(movers) and O(nodes).
             hist = self._archived_history()
             with stage("solve.apply") as st_apply:
-                mover_pos = np.nonzero(assignment != cur_idx)[0]
-                moved = 0
-                planned: list[tuple[str, str, str]] = []
-                for p in mover_pos.tolist():
-                    if move_sink is not None:
-                        # Plan, don't apply: the row flips when the sink's
-                        # handoff commits (or never, if it aborts — the lazy
-                        # request path and the next churn solve cover it).
-                        planned.append(
-                            (
-                                keys[p],
-                                node_order[int(cur_idx[p])],
-                                node_order[int(assignment[p])],
-                            )
-                        )
-                    elif self._set_placement(keys[p], int(assignment[p])):
-                        moved += 1
-                if move_sink is not None:
+                if planned is not None:
+                    # Plan, don't apply: a row flips when the sink's handoff
+                    # commits (or never, if it aborts — the lazy request
+                    # path and the next churn solve cover it).
                     moved = len(planned)
+                else:
+                    moved = 0
+                    for p in mover_pos.tolist():
+                        if self._set_placement(keys[p], int(assignment[p])):
+                            moved += 1
+                self._diff_commits += 1
+                self._diff_rows += n
+                self._diff_slices += diff_slices
+                self._diff_busy_ms += diff_ms
                 if solved_as.endswith("+delta"):
                     self._delta_displaced += displaced
                     self._delta_moved += moved
@@ -3448,9 +3532,7 @@ class JaxObjectPlacement(ObjectPlacement):
                                 else None
                             )
                         ),
-                        seat_counts=np.bincount(
-                            assignment, minlength=self._node_axis
-                        ),
+                        seat_counts=seat_counts,
                         epoch=self._epoch,
                         liveness_fp=self._sched_fp(),
                         delta_solves=(
@@ -3474,11 +3556,6 @@ class JaxObjectPlacement(ObjectPlacement):
                 **_conv_fields(conv),
             )
         if planned:
-            # Grouped emission: the migration engine batches one burst per
-            # (source, target) pair, so hand it the plan already ordered by
-            # that pair — contiguous runs become whole MigrateBatch frames.
-            with stage("solve.moves"):
-                planned.sort(key=lambda m: (m[1], m[2]))
             # Outside the lock on purpose: each handoff calls back into
             # update()/lookup(), which take it.
             await move_sink(planned)
